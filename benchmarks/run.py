@@ -244,4 +244,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()          # before the first jit of any suite
     sys.exit(main())

@@ -109,38 +109,59 @@ _I32_MAX = np.iinfo(np.int32).max
 
 def _encode_bitmaps(rows: np.ndarray) -> tuple[np.ndarray, np.ndarray,
                                                np.ndarray]:
-    """(flags u8 (n,), lens u32 (n,), data u32 (sum lens,)) for (n, W)."""
-    flags = np.zeros((rows.shape[0],), np.uint8)
-    lens = np.zeros((rows.shape[0],), np.uint32)
-    chunks = []
-    for i, row in enumerate(rows):
-        rle = bm.rle_compress(row)
-        if rle.size < row.size:
-            flags[i], lens[i] = 1, rle.size
-            chunks.append(rle)
-        else:
-            flags[i], lens[i] = 0, row.size
-            chunks.append(row.astype(np.uint32))
-    data = np.concatenate(chunks) if chunks else np.zeros((0,), np.uint32)
-    return flags, lens, data
+    """(flags u8 (n,), lens u32 (n,), data u32 (sum lens,)) for (n, W).
+
+    Entry i stores ``core.bitmap.rle_compress(rows[i])`` — (count, word)
+    pairs, one per run of equal words — when that is shorter than the W raw
+    words, else the raw words. Vectorized over entries: a full snapshot of
+    an SF 10 index encodes ~600 k of them.
+    """
+    rows = np.asarray(rows, np.uint32)
+    n, w = rows.shape
+    starts = np.ones((n, w), bool)                   # run starts per row
+    starts[:, 1:] = rows[:, 1:] != rows[:, :-1]
+    runs = starts.sum(axis=1)
+    use_rle = 2 * runs < w
+    lens = np.where(use_rle, 2 * runs, w).astype(np.uint32)
+    r, c = np.nonzero(starts & use_rle[:, None])     # row-major: row, then col
+    nxt = np.full_like(c, w)                         # a row's last run ends at W
+    nxt[:-1] = np.where(r[1:] == r[:-1], c[1:], w)
+    pairs = np.stack([(nxt - c).astype(np.uint32), rows[r, c]], axis=1)
+    owner = np.repeat(np.arange(n), lens)
+    data = np.empty((int(lens.sum()),), np.uint32)
+    data[use_rle[owner]] = pairs.ravel()
+    data[~use_rle[owner]] = rows[~use_rle].ravel()
+    return use_rle.astype(np.uint8), lens, data
 
 
 def _decode_bitmaps(flags: np.ndarray, lens: np.ndarray, data: np.ndarray,
                     words: int) -> np.ndarray:
-    out = np.zeros((flags.shape[0], words), np.uint32)
-    off = 0
-    for i, (f, ln) in enumerate(zip(flags, lens)):
-        chunk = data[off: off + int(ln)]
-        if chunk.size != int(ln):
-            raise CorruptSnapshotError(
-                "bitmap section shorter than its per-entry lengths claim")
-        row = bm.rle_decompress(chunk) if f else chunk
-        if row.size != words:
-            raise CorruptSnapshotError(
-                f"entry bitmap decodes to {row.size} words, index resolution "
-                f"wants {words}")
-        out[i] = row
-        off += int(ln)
+    """Inverse of ``_encode_bitmaps``; refuses sections whose lengths, run
+    counts or decoded widths do not add up."""
+    rle = np.asarray(flags).astype(bool)
+    lens = np.asarray(lens, np.int64)
+    n = rle.shape[0]
+    out = np.zeros((n, words), np.uint32)
+    total = int(lens.sum())
+    if data.size < total:
+        raise CorruptSnapshotError(
+            "bitmap section shorter than its per-entry lengths claim")
+    owner = np.repeat(np.arange(n), lens)
+    data = np.asarray(data[:total], np.uint32)
+    odd = rle & (lens % 2 == 1)                      # not whole (count, word) pairs
+    widths = lens.copy()                             # decoded words per entry
+    if not odd.any():
+        pairs = data[rle[owner]].reshape(-1, 2)
+        widths[rle] = np.bincount(owner[rle[owner]][::2], weights=pairs[:, 0],
+                                  minlength=n)[rle].astype(np.int64)
+    bad = odd | (widths != words)
+    if bad.any():
+        i = int(np.flatnonzero(bad)[0])
+        raise CorruptSnapshotError(
+            f"entry bitmap decodes to {int(widths[i])} words, index "
+            f"resolution wants {words}")
+    out[~rle] = data[~rle[owner]].reshape(-1, words)
+    out[rle] = np.repeat(pairs[:, 1], pairs[:, 0]).reshape(-1, words)
     return out
 
 

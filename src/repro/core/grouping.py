@@ -29,15 +29,16 @@ def page_bucket_bits(hist: Histogram, keys: jnp.ndarray, valid: jnp.ndarray,
     """Per-page bucket membership: (num_pages, H) bool.
 
     keys/valid: (num_pages, page_card). Invalid tuples hit no bucket.
-    A single scatter covers all tuples — the vectorized form of the paper's
-    per-tuple binary search + bucket-set accumulation (§4.2).
+    Every tuple's bucket id (the paper's per-tuple binary search, §4.2) is
+    compared against every bucket and OR-reduced over the page's slots — a
+    fused compare-and-reduce rather than a scatter of all N tuples, which
+    the TPU compiler takes ~10x longer to build at SF 10 shard sizes.
     """
     num_pages, page_card = keys.shape
-    ids = bucketize(hist, keys.reshape(-1))                     # (N,)
-    ids = jnp.where(valid.reshape(-1), ids, -1)                 # dropped by mode=drop
-    page_idx = jnp.repeat(jnp.arange(num_pages, dtype=jnp.int32), page_card)
-    bits = jnp.zeros((num_pages, resolution), dtype=bool)
-    return bits.at[page_idx, ids].set(True, mode="drop")
+    ids = bucketize(hist, keys.reshape(-1)).reshape(num_pages, page_card)
+    ids = jnp.where(valid, ids, -1)                             # hits no bucket
+    buckets = jnp.arange(resolution, dtype=jnp.int32)
+    return (ids[:, :, None] == buckets[None, None, :]).any(axis=1)
 
 
 @partial(jax.jit, static_argnames=("resolution", "density_threshold"))

@@ -223,9 +223,9 @@ class HippoIndex:
             return 0
         s = self.cfg.max_slots
         affected = np.zeros((s,), bool)
-        for p in dirty_pages:
-            slot, _ = hix.locate_slot(self.state, jnp.int32(int(p)))
-            affected[int(slot)] = True
+        slots, _ = hix.locate_slot(self.state,
+                                   jnp.asarray(dirty_pages, jnp.int32))
+        affected[np.asarray(slots)] = True
         self.state = hix.resummarize_slots(
             self.cfg, self.state, self.table.device_keys(),
             self.table.device_valid(), jnp.asarray(affected))

@@ -181,7 +181,8 @@ def locate_slot(state: HippoState, page_id) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Binary search the sorted list for the entry owning ``page_id`` (§5.3).
 
     Returns (physical_slot, logical_pos). Caller guarantees the page is
-    summarized (page_id <= summarized_until).
+    summarized (page_id <= summarized_until). ``page_id`` may be a scalar or
+    an array of page ids; the results take its shape.
     """
     ls = _logical_starts(state)
     pos = jnp.searchsorted(ls, page_id, side="right").astype(jnp.int32) - 1
@@ -189,29 +190,35 @@ def locate_slot(state: HippoState, page_id) -> tuple[jnp.ndarray, jnp.ndarray]:
     return state.sorted_order[pos], pos
 
 
-def _expand_page_mask(state: HippoState, match: jnp.ndarray,
+def _expand_page_mask(state: HippoState, query_bitmaps: jnp.ndarray,
                       num_pages: int) -> jnp.ndarray:
-    """Expand matched entry page-ranges to a page bitmap (Bitmap b, Alg. 1).
+    """Possible-qualified pages of each query (Bitmap b, Alg. 1).
 
     Live entries partition the summarized page space contiguously in logical
     (sorted-list) order — the §5.3 invariant ``locate_slot``'s binary search
-    already relies on — so each page belongs to at most one entry, and the
-    mask is a *gather* of the owning entry's match bit: binary-search every
-    page's logical position once, then look its match up per query. (The
-    previous boundary-delta scatter + prefix sum computed the same mask but
-    XLA:CPU scatters made it the most expensive fixed cost of a batch.)
-    Pages past the last entry's ``end`` — and everything in an empty index —
-    resolve to no entry and stay False. ``match`` is (S,) or (Q, S); the
-    result matches with shape (num_pages,) or (Q, num_pages).
+    already relies on — so each page belongs to at most one entry: binary-
+    search every page's logical position once, gather the owning entry's
+    bitmap, and run the joint-bucket test per (query, page). This equals
+    testing every entry and expanding the matches to their page ranges, but
+    gathers (P, W) packed words instead of a (Q, P) slice of the (Q, S)
+    match matrix — a column gather the TPU compiler is slow to build once
+    the shard axis is vmapped. Pages past the last entry's ``end`` — and
+    everything in an empty index — resolve to no entry and stay False.
+    ``query_bitmaps`` is (W,) or (Q, W); the result is (num_pages,) or
+    (Q, num_pages).
     """
-    squeeze = match.ndim == 1
-    m = match[None] if squeeze else match
+    squeeze = query_bitmaps.ndim == 1
+    qbm = query_bitmaps[None] if squeeze else query_bitmaps
+    s = state.bitmaps.shape[0]
+    live = state.slot_live & (jnp.arange(s) < state.num_slots)
     ls = _logical_starts(state)                        # (S,), INT32_MAX pads
     pages = jnp.arange(num_pages, dtype=jnp.int32)
     pos = jnp.searchsorted(ls, pages, side="right").astype(jnp.int32) - 1
     slot = state.sorted_order[jnp.clip(pos, 0, None)]  # owning physical slot
-    in_range = (pos >= 0) & (pages <= state.ends[slot])
-    page_mask = m[:, slot] & in_range[None, :]
+    owned = (pos >= 0) & (pages <= state.ends[slot]) & live[slot]
+    page_bits = state.bitmaps[slot]                    # (P, W)
+    page_mask = (bm.any_joint(qbm[:, None, :], page_bits[None, :, :])
+                 & owned[None, :])
     return page_mask[0] if squeeze else page_mask
 
 
@@ -228,7 +235,7 @@ def search(state: HippoState, query_bitmap: jnp.ndarray, keys: jnp.ndarray,
     live = state.slot_live & (jnp.arange(s) < state.num_slots)
     # Step 2 — bit-level parallel joint-bucket test (Fig. 3).
     match = bm.any_joint(state.bitmaps, query_bitmap[None, :]) & live
-    page_mask = _expand_page_mask(state, match, num_pages)
+    page_mask = _expand_page_mask(state, query_bitmap, num_pages)
     # Step 3 — inspect possible qualified pages tuple-by-tuple (vectorized).
     v = keys.astype(jnp.float32)
     qualified = page_mask[:, None] & valid & (v >= lo) & (v <= hi)
@@ -248,10 +255,10 @@ def search_many(state: HippoState, query_bitmaps: jnp.ndarray, keys: jnp.ndarray
     """Algorithm 1 over a batch of Q predicates in one device program.
 
     query_bitmaps: (Q, W) packed query bitmaps; los/his: (Q,) predicate
-    intervals. The entry-match and range-expand steps of ``search`` gain a
-    leading query axis — one (Q, S) joint-bucket AND, one batched scatter into
-    (Q, P+1) boundary deltas, one row-wise prefix sum — so Q queries cost one
-    dispatch instead of Q. Row q of every output is bit-identical to the
+    intervals. The entry-match and page-mask steps of ``search`` gain a
+    leading query axis — one (Q, S) joint-bucket AND, one (Q, P) joint-bucket
+    test against each page's owning entry — so Q queries cost one dispatch
+    instead of Q. Row q of every output is bit-identical to the
     scalars ``search`` returns for predicate q.
     """
     num_pages = keys.shape[0]
@@ -260,7 +267,7 @@ def search_many(state: HippoState, query_bitmaps: jnp.ndarray, keys: jnp.ndarray
     # Step 2, batched: joint-bucket test of every query against every entry.
     match = bm.any_joint(query_bitmaps[:, None, :], state.bitmaps[None, :, :])
     match = match & live[None, :]                                   # (Q, S)
-    page_mask = _expand_page_mask(state, match, num_pages)          # (Q, P)
+    page_mask = _expand_page_mask(state, query_bitmaps, num_pages)  # (Q, P)
     # Step 3, batched: inspect possible qualified pages for every query.
     v = keys.astype(jnp.float32)[None]
     qualified = (page_mask[:, :, None] & valid[None]
@@ -382,7 +389,7 @@ def search_compact(state: HippoState, query_bitmap: jnp.ndarray, keys: jnp.ndarr
     s = state.bitmaps.shape[0]
     live = state.slot_live & (jnp.arange(s) < state.num_slots)
     match = bm.any_joint(state.bitmaps, query_bitmap[None, :]) & live
-    page_mask = _expand_page_mask(state, match, num_pages)
+    page_mask = _expand_page_mask(state, query_bitmap, num_pages)
     n_sel = page_mask.sum(dtype=jnp.int32)
     sel = jnp.nonzero(page_mask, size=max_selected, fill_value=num_pages)[0]
     in_range = sel < num_pages
@@ -421,13 +428,13 @@ def search_compact_many(state: HippoState, query_bitmaps: jnp.ndarray,
         raise ValueError(f"max_selected must be >= 1, got {max_selected}")
     if top_k < 0:
         raise ValueError(f"top_k must be >= 0, got {top_k}")
-    num_pages, card = keys.shape
+    num_pages = keys.shape[0]
     s = state.bitmaps.shape[0]
     live = state.slot_live & (jnp.arange(s) < state.num_slots)
     # Step 2, batched: joint-bucket test + page-range expansion per query.
     match = bm.any_joint(query_bitmaps[:, None, :], state.bitmaps[None, :, :])
     match = match & live[None, :]                                   # (Q, S)
-    page_mask = _expand_page_mask(state, match, num_pages)          # (Q, P)
+    page_mask = _expand_page_mask(state, query_bitmaps, num_pages)  # (Q, P)
     # Union across the batch: one gather serves every query's inspection.
     union = jnp.any(page_mask, axis=0)                              # (P,)
     n_union = union.sum(dtype=jnp.int32)
@@ -445,27 +452,14 @@ def search_compact_many(state: HippoState, query_bitmaps: jnp.ndarray,
             & (v >= los[:, None, None]) & (v <= his[:, None, None]))
     pages_inspected = page_mask.sum(axis=1, dtype=jnp.int32)
     covered = sel_mask.sum(axis=1, dtype=jnp.int32)
+    page_counts = qual.sum(axis=2, dtype=jnp.int32)                 # (Q, M)
     if top_k:
-        # First top_k qualifying rows per query, in slab order == ascending
-        # global row id order (sel is ascending, slots are row-ordered).
-        flat = qual.reshape(qual.shape[0], -1)                      # (Q, M*C)
-        gids = (sel[:, None] * card
-                + jnp.arange(card, dtype=jnp.int32)[None, :]).reshape(-1)
-        npos = flat.shape[1]
-        pos = jnp.where(flat, jnp.arange(npos, dtype=jnp.int32)[None, :], npos)
-        k_eff = min(top_k, npos)   # a slab of M*C rows can yield at most M*C ids
-        # smallest k_eff positions per row in ascending order: top_k of the
-        # negated positions selects them at O(n log k) instead of a full sort
-        first = -jax.lax.top_k(-pos, k_eff)[0]                      # (Q, K)
-        row_ids = jnp.where(first < npos,
-                            gids.at[first].get(mode="fill", fill_value=-1), -1)
-        if k_eff < top_k:
-            row_ids = jnp.pad(row_ids, ((0, 0), (0, top_k - k_eff)),
-                              constant_values=-1)
+        row_ids = _first_row_ids(page_counts, sel, slab_keys, slab_valid,
+                                 los, his, top_k)
     else:
         row_ids = jnp.zeros((qual.shape[0], 0), jnp.int32)
     return CompactBatchResult(
-        counts=qual.sum(axis=(1, 2), dtype=jnp.int32),
+        counts=page_counts.sum(axis=1, dtype=jnp.int32),
         pages_inspected=pages_inspected,
         entries_matched=match.sum(axis=1, dtype=jnp.int32),
         truncated=covered < pages_inspected,
@@ -474,6 +468,38 @@ def search_compact_many(state: HippoState, query_bitmaps: jnp.ndarray,
         pages_gathered=jnp.minimum(n_union, max_selected),
         row_ids=row_ids,
     )
+
+
+def _first_row_ids(page_counts, sel, slab_keys, slab_valid, los, his,
+                   top_k: int) -> jnp.ndarray:
+    """First ``top_k`` qualifying global row ids per query, ascending, -1
+    padded: slab order is ascending global row order (``sel`` ascends and
+    slots are row-ordered).
+
+    A prefix sum of the per-page qualifying counts finds, for each k, the
+    slab page holding the query's k-th row and its rank inside that page;
+    only those (Q, top_k) pages are re-inspected. Memory is O(Q x M) plus
+    O(Q x top_k x C) — a (Q, M*C) position array is never built. A page
+    holding a qualifying row is one the query selected, so the re-inspection
+    needs no selection mask; invalid slots read as NaN, which fails every
+    interval test (one f32 gather instead of bool gathers, which the TPU
+    compiler is slow to build under the shard vmap).
+    """
+    m, card = slab_keys.shape
+    ks = jnp.arange(top_k, dtype=jnp.int32)
+    incl = jnp.cumsum(page_counts, axis=1, dtype=jnp.int32)         # (Q, M)
+    # the first page whose inclusive count exceeds k holds the k-th row
+    page = jax.vmap(lambda c: jnp.searchsorted(c, ks, side="right"))(incl)
+    found = ks[None, :] < incl[:, -1:]                              # (Q, K)
+    page = jnp.minimum(page, m - 1).astype(jnp.int32)
+    rank = ks[None, :] - jnp.take_along_axis(incl - page_counts, page, axis=1)
+    live_keys = jnp.where(slab_valid, slab_keys.astype(jnp.float32), jnp.nan)
+    pk = live_keys[page]                                            # (Q, K, C)
+    hit = (pk >= los[:, None, None]) & (pk <= his[:, None, None])
+    # slot of the rank-th hit: the number of prefix counts not past rank
+    seen = jnp.cumsum(hit, axis=2, dtype=jnp.int32)
+    slot = (seen <= rank[:, :, None]).sum(axis=2, dtype=jnp.int32)
+    return jnp.where(found, sel[page] * card + slot, -1)
 
 
 _I32_PAD = jnp.int32(_INT32_MAX)

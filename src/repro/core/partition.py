@@ -94,6 +94,19 @@ class ShardSpec:
         return page_id - self.page_lo(self.owner(page_id))
 
 
+def default_pages_per_shard(num_pages: int, num_shards: int) -> int:
+    """Slab width ``ShardedHippoIndex.create`` picks for a table: 25% growth
+    room plus a fixed floor so tiny tables can still insert (mirroring
+    ``HippoIndex.create``'s slot headroom), split over the shards."""
+    return -(-(int(num_pages * 1.25) + 64) // num_shards)
+
+
+def default_max_slots(pages_per_shard: int) -> int:
+    """Per-shard entry capacity ``create`` picks: worst case one entry per
+    slab page, plus ``HippoIndex.create``'s fixed update budget."""
+    return int(pages_per_shard * 1.25) + 1024
+
+
 class ShardedHippoState(NamedTuple):
     shards: hix.HippoState     # stacked per hix.SHARD_AXES (incl. per-shard bounds)
     summaries: jnp.ndarray     # (S, W) u32 — OR of live entry bitmaps per shard
@@ -224,19 +237,15 @@ class ShardedHippoIndex:
             raise ValueError(f"summary must be one of {SUMMARY_POLICIES}, "
                              f"got {summary!r}")
         if pages_per_shard is None:
-            # slab headroom mirrors HippoIndex.create's slot headroom: 25%
-            # growth room plus a fixed floor so tiny tables can still insert
-            target = int(table.num_pages * 1.25) + 64
-            pages_per_shard = -(-target // num_shards)
+            pages_per_shard = default_pages_per_shard(table.num_pages,
+                                                      num_shards)
         spec = ShardSpec(num_shards=num_shards, pages_per_shard=pages_per_shard)
         if spec.total_pages < table.num_pages:
             raise ValueError(
                 f"shard layout {num_shards}x{pages_per_shard} covers "
                 f"{spec.total_pages} pages < table's {table.num_pages}")
         if max_slots is None:
-            # per-shard mirror of HippoIndex.create's default: worst case one
-            # entry per slab page, plus the same fixed update budget
-            max_slots = int(pages_per_shard * 1.25) + 1024
+            max_slots = default_max_slots(pages_per_shard)
         cfg = hix.HippoConfig(resolution=resolution, density=density,
                               page_card=table.page_card, max_slots=max_slots,
                               relocate_on_update=relocate_on_update)
@@ -540,10 +549,10 @@ class ShardedHippoIndex:
         keys, valid = self._slabs()
         st = shard_state(self.state.shards, s)
         affected = np.zeros((self.cfg.max_slots,), bool)
-        lo = self.spec.page_lo(s)
-        for p in dirty_pages:
-            slot, _ = hix.locate_slot(st, jnp.int32(int(p) - lo))
-            affected[int(slot)] = True
+        # one sorted-list search for every dirty page of the shard
+        slots, _ = hix.locate_slot(st, jnp.asarray(
+            dirty_pages - self.spec.page_lo(s), jnp.int32))
+        affected[np.asarray(slots)] = True
         st = hix.resummarize_slots(self.cfg, st, keys[s], valid[s],
                                    jnp.asarray(affected))
         self._apply_shard(s, st)

@@ -2,8 +2,9 @@
 
 Each kernel directory contains:
   kernel.py — pl.pallas_call with explicit BlockSpec VMEM tiling (TPU target)
-  ops.py    — jit'd public wrapper (padding, interpret-mode fallback on CPU)
-  ref.py    — pure-jnp oracle used by tests and as the CPU execution path
+  ops.py    — jit'd public wrapper (padding; compiled for the TPU unless the
+              caller passes interpret=True)
+  ref.py    — pure-jnp oracle used by tests and the chip smoke's parity check
 
 Kernels:
   bitmap_and      — §3.2 joint-bucket filter: AND query bitmap against all

@@ -8,9 +8,9 @@ W = ceil(resolution / 32) packed words (``core.bitmap``).
 
 Wrappers pad Q/E to kernel block multiples and W to the 128-lane width
 (zero pads AND to zero, so padding never creates a match), then slice the
-result back. On CPU backends the Pallas kernel runs in interpret mode for
-validation; ``ref.py`` holds the jnp reference twin that is the CPU
-execution path.
+result back. The kernel compiles for the TPU by default; callers on a CPU
+pass ``interpret=True`` explicitly (the interpreter is a validation tool,
+never a silent fallback). ``ref.py`` holds the jnp reference twin.
 
 Equivalence contract: the sharded form is the unsharded form vmapped over
 the shard axis — ``batch_filter_sharded(q, e)[s] == batch_filter(q, e[s])``
@@ -43,16 +43,13 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
 
 @partial(jax.jit, static_argnames=("interpret",))
 def batch_filter(queries: jnp.ndarray, entries: jnp.ndarray,
-                 interpret: bool | None = None) -> jnp.ndarray:
+                 interpret: bool = False) -> jnp.ndarray:
     """Joint-bucket test of every query bitmap against every entry bitmap.
 
     queries: (Q, W) uint32, entries: (E, W) uint32 -> (Q, E) int32 0/1.
-    On CPU backends runs the Pallas kernel in interpret mode.
     """
     q, w = queries.shape
     e, _ = entries.shape
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     qp = _pad_to(queries, 0, BLOCK_Q)
     qp = _pad_to(qp, 1, 128)
     ep = _pad_to(entries, 0, BLOCK_E)
@@ -63,17 +60,14 @@ def batch_filter(queries: jnp.ndarray, entries: jnp.ndarray,
 
 @partial(jax.jit, static_argnames=("interpret",))
 def batch_filter_sharded(queries: jnp.ndarray, entries: jnp.ndarray,
-                         interpret: bool | None = None) -> jnp.ndarray:
+                         interpret: bool = False) -> jnp.ndarray:
     """Joint-bucket test of every query against every shard's entry table.
 
     queries: (Q, W) uint32, entries: (S, E, W) uint32 -> (S, Q, E) int32 0/1
-    — the fused match phase over the whole shard axis. On CPU backends runs
-    the Pallas kernel in interpret mode.
+    — the fused match phase over the whole shard axis.
     """
     q, w = queries.shape
     s, e, _ = entries.shape
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     qp = _pad_to(queries, 0, BLOCK_Q)
     qp = _pad_to(qp, 1, 128)
     ep = _pad_to(entries, 1, BLOCK_E)

@@ -22,14 +22,14 @@ BLOCK_E = 512  # entries per grid step (8-sublane aligned)
 def _kernel(entries_ref, query_ref, out_ref):
     e = entries_ref[...]                # (BLOCK_E, W) uint32
     q = query_ref[...]                  # (1, W) uint32
-    joint = (e & q) != 0                # VPU lane-parallel AND
-    out_ref[...] = jnp.any(joint, axis=1).astype(jnp.int32)
+    joint = ((e & q) != 0).astype(jnp.int32)   # VPU lane-parallel AND
+    out_ref[...] = jnp.max(joint, axis=1, keepdims=True)
 
 
 def bitmap_and_any_kernel(entries: jnp.ndarray, query: jnp.ndarray,
                           *, interpret: bool = False) -> jnp.ndarray:
     """entries: (E, W) uint32 (E % BLOCK_E == 0, W % 128 == 0);
-    query: (1, W) uint32. Returns (E,) int32 0/1."""
+    query: (1, W) uint32. Returns (E, 1) int32 0/1."""
     e, w = entries.shape
     grid = (e // BLOCK_E,)
     return pl.pallas_call(
@@ -39,7 +39,7 @@ def bitmap_and_any_kernel(entries: jnp.ndarray, query: jnp.ndarray,
             pl.BlockSpec((BLOCK_E, w), lambda i: (i, 0)),
             pl.BlockSpec((1, w), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((BLOCK_E,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((e,), jnp.int32),
+        out_specs=pl.BlockSpec((BLOCK_E, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((e, 1), jnp.int32),
         interpret=interpret,
     )(entries, query)

@@ -6,9 +6,9 @@ the query bitmap (the paper's joint-bucket test, Fig. 3). W =
 ceil(resolution / 32) packed words (``core.bitmap``).
 
 The wrapper pads E to the kernel block and W to the 128-lane width (zero
-pads AND to zero, so padding never creates a match) and slices back. On CPU
-backends the Pallas kernel runs in interpret mode for validation;
-``ref.py`` is the jnp reference twin and the CPU execution path. The
+pads AND to zero, so padding never creates a match) and slices back. The
+kernel compiles for the TPU by default; callers on a CPU pass
+``interpret=True`` explicitly. ``ref.py`` is the jnp reference twin. The
 batched engine uses ``kernels.batch_filter`` (a leading query axis, plus a
 sharded grid); per shard and per query all three agree bit-exactly.
 """
@@ -35,20 +35,17 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
 
 @partial(jax.jit, static_argnames=("interpret",))
 def bitmap_and_any(entries: jnp.ndarray, query: jnp.ndarray,
-                   interpret: bool | None = None) -> jnp.ndarray:
+                   interpret: bool = False) -> jnp.ndarray:
     """Joint-bucket test of every entry bitmap against the query bitmap.
 
     entries: (E, W) uint32, query: (W,) uint32 -> (E,) int32 0/1.
-    On CPU backends runs the Pallas kernel in interpret mode.
     """
     e, w = entries.shape
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     ep = _pad_to(entries, 0, BLOCK_E)
     ep = _pad_to(ep, 1, 128)
     qp = _pad_to(query[None, :], 1, 128)
     out = bitmap_and_any_kernel(ep, qp, interpret=interpret)
-    return out[:e]
+    return out[:e, 0]
 
 
 __all__ = ["bitmap_and_any", "bitmap_and_any_ref"]

@@ -6,10 +6,10 @@ domain edges. ``bounds`` are the strictly-increasing equi-depth boundaries
 (``core.histogram``); the kernel binary-searches them per value.
 
 The wrapper pads N to the kernel block and H+1 to the 128-lane width with
-+inf so padding never wins a comparison, then slices back. On CPU backends
-the Pallas kernel runs in interpret mode for validation; ``ref.py`` is the
-jnp reference twin and the CPU execution path — both match bit-exactly for
-strictly-increasing boundaries. Build (Algorithm 2), search (predicate
++inf so padding never wins a comparison, then slices back. The kernel
+compiles for the TPU by default; callers on a CPU pass ``interpret=True``
+explicitly. ``ref.py`` is the jnp reference twin — both match bit-exactly
+for strictly-increasing boundaries. Build (Algorithm 2), search (predicate
 conversion), and maintenance (Algorithm 3) all bucketize through this one
 surface, which is what keeps the unsharded and sharded indexes agreeing:
 shards share the global ``bounds``, so a value buckets identically no
@@ -28,15 +28,13 @@ from repro.kernels.bucketize.ref import bucketize_ref
 
 @partial(jax.jit, static_argnames=("resolution", "interpret"))
 def bucketize_values(values: jnp.ndarray, bounds: jnp.ndarray, resolution: int,
-                     interpret: bool | None = None) -> jnp.ndarray:
+                     interpret: bool = False) -> jnp.ndarray:
     """Bucket ids for ``values`` against ``bounds`` ((H+1,) ascending).
 
     Pads N to the kernel tile and H+1 to lane width (+inf so padding never
     counts). Matches ``bucketize_ref`` bit-exactly for strictly-increasing
     boundaries.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     n = values.shape[0]
     pad_n = (-n) % BLOCK_N
     v = jnp.pad(values.astype(jnp.float32), (0, pad_n))

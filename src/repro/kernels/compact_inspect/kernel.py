@@ -22,18 +22,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLOCK_Q = 8    # queries per grid step (sublane-aligned)
-BLOCK_M = 64   # gathered slab pages per grid step
+BLOCK_M = 128  # gathered slab pages per grid step (lane-aligned)
 
 
 def _kernel(keys_ref, valid_ref, selmask_ref, interval_ref, count_ref):
     k = keys_ref[...]                               # (BLOCK_M, C) f32
-    live = valid_ref[...] != 0                      # (BLOCK_M, C)
-    sel = selmask_ref[...] != 0                     # (BLOCK_Q, BLOCK_M)
-    lo = interval_ref[...][:, 0][:, None, None]     # (BLOCK_Q, 1, 1)
-    hi = interval_ref[...][:, 1][:, None, None]
-    k3 = k[None, :, :]                              # (1, BLOCK_M, C)
-    qual = sel[:, :, None] & live[None] & (k3 >= lo) & (k3 <= hi)
-    count_ref[...] = qual.sum(axis=2).astype(jnp.int32)
+    live = valid_ref[...].astype(jnp.int32) != 0    # (BLOCK_M, C)
+    sel = selmask_ref[...].astype(jnp.int32)        # (BLOCK_Q, BLOCK_M) 0/1
+    ones = jnp.ones((1, k.shape[1]), jnp.bfloat16)
+    for q in range(BLOCK_Q):
+        lo = interval_ref[q, 0]
+        hi = interval_ref[q, 1]
+        qual = (live & (k >= lo) & (k <= hi)).astype(jnp.bfloat16)
+        # Per-page counts as a lane-major (1, BLOCK_M) row: contracting the
+        # slot axis of ones (1, C) against qual (BLOCK_M, C) on the MXU both
+        # sums and transposes. 0/1 operands and f32 accumulation are exact.
+        row = jax.lax.dot_general(ones, qual, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        count_ref[pl.ds(q, 1), :] = row.astype(jnp.int32) * sel[q:q + 1, :]
 
 
 def compact_inspect_kernel(keys: jnp.ndarray, valid: jnp.ndarray,

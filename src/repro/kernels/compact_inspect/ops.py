@@ -14,9 +14,9 @@ of the compact/dense equivalence contract.
 The wrapper pads M to the kernel block (padded slab pages carry valid=False
 and sel_mask=False), Q to the query block (padded queries carry the empty
 interval lo > hi), and C to the 128-lane width (padded slots carry +inf
-keys and valid=False), then slices back. On CPU backends the Pallas kernel
-runs in interpret mode for validation; ``ref.py`` is the jnp reference twin
-and the CPU execution path.
+keys and valid=False), then slices back. The kernel compiles for the TPU
+by default; callers on a CPU pass ``interpret=True`` explicitly. ``ref.py``
+is the jnp reference twin.
 """
 from __future__ import annotations
 
@@ -33,14 +33,12 @@ from repro.kernels.compact_inspect.ref import compact_inspect_ref
 @partial(jax.jit, static_argnames=("interpret",))
 def compact_inspect(keys: jnp.ndarray, valid: jnp.ndarray,
                     sel_mask: jnp.ndarray, los, his,
-                    interpret: bool | None = None) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """Fused selected-mask × interval inspection of a gathered page slab.
 
     keys: (M, C) f32, valid: (M, C) bool, sel_mask: (Q, M) bool,
     los/his: (Q,) f32. Returns counts (Q, M) int32.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     m, c = keys.shape
     q = sel_mask.shape[0]
     pad_m = (-m) % BLOCK_M

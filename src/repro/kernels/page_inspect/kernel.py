@@ -23,18 +23,20 @@ BLOCK_P = 64   # pages per grid step
 
 def _kernel(keys_ref, valid_ref, mask_ref, interval_ref, qual_ref, count_ref):
     k = keys_ref[...]                              # (BLOCK_P, C) f32
-    live = valid_ref[...] != 0                     # (BLOCK_P, C)
-    sel = (mask_ref[...] != 0)                     # (BLOCK_P, 1) page mask
+    # Masks compare as int32: Mosaic cannot relayout the i1 vector of a
+    # uint8 compare against a lane-broadcast (BLOCK_P, 1) page mask.
+    live = valid_ref[...].astype(jnp.int32) != 0   # (BLOCK_P, C)
+    sel = jnp.broadcast_to(mask_ref[...], k.shape) != 0
     lo = interval_ref[0, 0]
     hi = interval_ref[0, 1]
-    qual = sel & live & (k >= lo) & (k <= hi)
+    qual = (sel & live & (k >= lo) & (k <= hi)).astype(jnp.int32)
     qual_ref[...] = qual.astype(jnp.uint8)
-    count_ref[...] = qual.sum(axis=1, keepdims=True).astype(jnp.int32)
+    count_ref[...] = qual.sum(axis=1, keepdims=True)
 
 
 def page_inspect_kernel(keys: jnp.ndarray, valid: jnp.ndarray, mask: jnp.ndarray,
                         interval: jnp.ndarray, *, interpret: bool = False):
-    """keys: (P, C) f32; valid: (P, C) uint8; mask: (P, 1) uint8;
+    """keys: (P, C) f32; valid: (P, C) uint8; mask: (P, 1) int32;
     interval: (1, 2) f32 [lo, hi]. P % BLOCK_P == 0, C % 128 == 0.
     Returns (qual (P, C) uint8, counts (P, 1) int32)."""
     p, c = keys.shape

@@ -14,32 +14,17 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    Newer jax wants explicit ``axis_types`` (Auto for the sharding pass);
-    jax 0.4.x has neither ``jax.sharding.AxisType`` nor the kwarg — there Auto
-    is the only behaviour, so plain ``make_mesh`` is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever local devices exist (tests, examples)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def make_shard_mesh(num_shards: int):
@@ -54,7 +39,8 @@ def make_shard_mesh(num_shards: int):
         raise ValueError(f"num_shards must be >= 1, got {num_shards}")
     n = jax.device_count()
     d = max(k for k in range(1, min(num_shards, n) + 1) if num_shards % k == 0)
-    return make_mesh_compat((d,), ("data",))
+    return jax.make_mesh((d,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def batch_axes(mesh) -> tuple:

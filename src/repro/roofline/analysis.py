@@ -21,7 +21,9 @@ explicit, the model is the classic HBM roofline.
 The hardware table carries the v5e numbers the kernel block shapes were
 sized for plus a measured-STREAM entry for this CPU host, so CPU trajectory
 files are gated against what the machine can actually sustain rather than
-paper numbers.  ``hardware()`` with no argument picks by jax backend.
+paper numbers. ``hardware()`` with no argument reads the first JAX device:
+a TPU is looked up by its ``device_kind`` (a kind missing from the table is
+an error, never a default), anything else gets the measured CPU row.
 """
 from __future__ import annotations
 
@@ -58,6 +60,10 @@ class Hardware:
 TPU_V5E = Hardware("tpu_v5e", mem_bw=819e9, vector_ops=3.9e12,
                    note="v5e chip: HBM 819 GB/s, VPU 8x128 lanes")
 
+# TPU rows keyed by ``jax.devices()[0].device_kind``. Peaks: Google Cloud
+# documentation, "TPU v5e" (819 GB/s of HBM per chip).
+TPU_BY_KIND = {"TPU v5 lite": TPU_V5E}
+
 
 @functools.lru_cache(maxsize=None)
 def measure_cpu_stream(mbytes: int = 64, reps: int = 5) -> float:
@@ -88,10 +94,20 @@ def _cpu_stream_hardware() -> Hardware:
 
 
 def hardware(name: str | None = None) -> Hardware:
-    """Look up a hardware-table row; ``None`` detects by jax backend."""
+    """Look up a hardware-table row by name; ``None`` reads the device.
+
+    On a TPU the row is keyed by ``device_kind``; a kind the table does not
+    hold raises ``KeyError`` instead of borrowing another chip's peaks."""
     if name is None:
         import jax
-        name = "tpu_v5e" if jax.default_backend() == "tpu" else "cpu_stream"
+        dev = jax.devices()[0]
+        if dev.platform == "tpu":
+            if dev.device_kind not in TPU_BY_KIND:
+                raise KeyError(f"no roofline row for TPU kind "
+                               f"{dev.device_kind!r}; have: "
+                               f"{sorted(TPU_BY_KIND)}")
+            return TPU_BY_KIND[dev.device_kind]
+        name = "cpu_stream"
     if name == "tpu_v5e":
         return TPU_V5E
     if name == "cpu_stream":

@@ -17,10 +17,10 @@ _PROG = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.checkpointing import restore_checkpoint, save_checkpoint
-    from repro.launch.mesh import make_mesh_compat
     from repro.runtime.elastic import reshard_for_mesh, validate_divisibility
 
-    mesh = make_mesh_compat(({d}, {m}), ("data", "model"))
+    mesh = jax.make_mesh(({d}, {m}), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     template = {{"w": jnp.zeros((8, 16)), "b": jnp.zeros((16,))}}
     if "{phase}" == "save":
         tree = {{"w": jnp.arange(128, dtype=jnp.float32).reshape(8, 16),

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 
 from repro.core import bitmap as bm
 from repro.core import histogram as hg
+from repro.core import index as hix
 from repro.core.hippo import HippoIndex
 from repro.core.predicate import Predicate
 from repro.storage.table import PagedTable
@@ -36,6 +37,18 @@ def test_build_structure_invariants():
     assert (pops > 0).all()
     dens = pops / idx.cfg.resolution
     assert (dens[:-1] > idx.cfg.density).all()
+
+
+def test_locate_slot_takes_an_array_of_pages():
+    """One sorted-list search over many pages equals one search per page."""
+    rng = np.random.default_rng(3)
+    idx = make_index(rng.uniform(0, 100, 400))
+    pages = np.arange(idx.table.num_pages, dtype=np.int32)
+    slots, pos = hix.locate_slot(idx.state, jnp.asarray(pages))
+    for p in pages:
+        s, q = hix.locate_slot(idx.state, jnp.int32(p))
+        assert (int(slots[p]), int(pos[p])) == (int(s), int(q))
+        assert int(idx.state.starts[s]) <= p <= int(idx.state.ends[s])
 
 
 def test_entry_bitmap_matches_page_contents():

@@ -1,21 +1,33 @@
 """Per-kernel validation: Pallas (interpret mode on CPU) vs pure-jnp oracle,
 swept over shapes and dtypes."""
+from functools import partial
+
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
-from repro.kernels.batch_filter.ops import batch_filter, batch_filter_sharded
+from repro.kernels.batch_filter import ops as batch_filter_ops
 from repro.kernels.batch_filter.ref import (batch_filter_ref,
                                             batch_filter_sharded_ref)
-from repro.kernels.bitmap_and.ops import bitmap_and_any
+from repro.kernels.bitmap_and import ops as bitmap_and_ops
 from repro.kernels.bitmap_and.ref import bitmap_and_any_ref
-from repro.kernels.compact_inspect.ops import compact_inspect
+from repro.kernels.compact_inspect import ops as compact_inspect_ops
 from repro.kernels.compact_inspect.ref import compact_inspect_ref
-from repro.kernels.bucketize.ops import bucketize_values
+from repro.kernels.bucketize import ops as bucketize_ops
 from repro.kernels.bucketize.ref import bucketize_ref
-from repro.kernels.page_inspect.ops import page_inspect
+from repro.kernels.page_inspect import ops as page_inspect_ops
 from repro.kernels.page_inspect.ref import page_inspect_ref
+
+# The wrappers compile for the TPU unless told otherwise; on this CPU every
+# call runs the Pallas interpreter, asked for explicitly.
+batch_filter = partial(batch_filter_ops.batch_filter, interpret=True)
+batch_filter_sharded = partial(batch_filter_ops.batch_filter_sharded,
+                               interpret=True)
+bitmap_and_any = partial(bitmap_and_ops.bitmap_and_any, interpret=True)
+compact_inspect = partial(compact_inspect_ops.compact_inspect, interpret=True)
+bucketize_values = partial(bucketize_ops.bucketize_values, interpret=True)
+page_inspect = partial(page_inspect_ops.page_inspect, interpret=True)
 
 
 # ---------------------------------------------------------------------------

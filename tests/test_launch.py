@@ -47,12 +47,12 @@ _SMALL_MESH_PROG = textwrap.dedent("""
     import jax
     from repro.configs import get_config, SHAPES
     from repro.launch import steps as steps_lib
-    from repro.launch.mesh import make_mesh_compat
     from repro.launch.shardings import (make_opt_shardings,
         make_param_shardings, replicated, train_batch_shardings,
         tree_cache_shardings)
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_config("{arch}").reduced(d_model=128, num_heads=4,
                                        num_kv_heads=4, head_dim=32,
                                        vocab_size=512, d_ff=256)

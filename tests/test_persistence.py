@@ -38,6 +38,7 @@ from repro.checkpointing.snapshot import (delta_chain, disk_usage,
                                           load_index, recover_index,
                                           save_delta, save_index)
 from repro.checkpointing.wal import Journal
+from repro.core import bitmap as bm
 from repro.core.partition import ShardedHippoIndex
 from repro.core.predicate import Predicate
 from repro.runtime.engine import QueryEngine
@@ -383,6 +384,54 @@ def test_layout_refuses_flipped_payload_byte(tmp_path):
     data[-5] ^= 0x40                        # deep in the last payload
     with pytest.raises(CorruptSnapshotError, match="crc|checksum|CRC"):
         unpack_sections(bytes(data), origin="bitflip")
+
+
+def _encode_bitmaps_per_entry(rows):
+    """Reference for the vectorized encoder: one ``rle_compress`` per entry,
+    kept when shorter than the raw words."""
+    flags = np.zeros((rows.shape[0],), np.uint8)
+    lens = np.zeros((rows.shape[0],), np.uint32)
+    chunks = [np.zeros((0,), np.uint32)]
+    for i, row in enumerate(rows):
+        rle = bm.rle_compress(row)
+        use = rle.size < row.size
+        flags[i], lens[i] = use, rle.size if use else row.size
+        chunks.append(rle if use else row.astype(np.uint32))
+    return flags, lens, np.concatenate(chunks)
+
+
+@pytest.mark.parametrize("kind", ["random", "few_words", "one_word", "empty"])
+def test_entry_bitmap_encoding_matches_per_entry_rle(kind):
+    """The vectorized snapshot encoder equals per-entry RLE byte for byte,
+    and decoding gives the bitmaps back."""
+    rng = np.random.default_rng(11)
+    n, w = (0, 13) if kind == "empty" else (257, 13)
+    if kind == "random":
+        rows = rng.integers(0, 2**32, (n, w), dtype=np.uint32)
+    elif kind == "few_words":
+        rows = rng.choice(np.asarray([0, 1, 0xFFFFFFFF], np.uint32), (n, w))
+    else:
+        rows = np.zeros((n, w), np.uint32)
+        rows[np.arange(n), rng.integers(0, w, n)] = 5
+    got = snap_mod._encode_bitmaps(rows)
+    for a, b in zip(got, _encode_bitmaps_per_entry(rows)):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert np.array_equal(snap_mod._decode_bitmaps(*got, w), rows)
+
+
+def test_entry_bitmap_decoding_refuses_inconsistent_lengths():
+    rows = np.zeros((4, 13), np.uint32)
+    rows[:, 3] = 7
+    flags, lens, data = snap_mod._encode_bitmaps(rows)
+    assert flags.all()
+    with pytest.raises(CorruptSnapshotError, match="shorter"):
+        snap_mod._decode_bitmaps(flags, lens, data[:-1], 13)
+    odd = lens.copy()
+    odd[0] += 1                       # not whole (count, word) pairs
+    with pytest.raises(CorruptSnapshotError, match="decodes to"):
+        snap_mod._decode_bitmaps(flags, odd, np.concatenate([data, data]), 13)
+    with pytest.raises(CorruptSnapshotError, match="decodes to"):
+        snap_mod._decode_bitmaps(flags, lens, data, 12)
 
 
 def test_load_index_surfaces_corruption_cleanly(tmp_path):

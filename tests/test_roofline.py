@@ -75,10 +75,37 @@ def test_hardware_table_and_detection():
     # measured STREAM bandwidth is cached and plausible for any host
     assert 1e9 < cpu.mem_bw < 1e12
     assert rl.hardware("cpu_stream") is cpu             # lru-cached
-    assert rl.hardware().name in ("tpu_v5e", "cpu_stream")  # backend detect
     assert rl.TPU_V5E.ridge_ai > 1.0
     with pytest.raises(KeyError):
         rl.hardware("abacus")
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+def _pin_device(monkeypatch, platform, kind):
+    import jax
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a, **k: [_FakeDevice(platform, kind)])
+
+
+def test_hardware_detection_reads_device_kind(monkeypatch):
+    """With no name, the row comes from the first device: a v5e by its
+    ``device_kind``, a CPU by the measured STREAM row."""
+    assert rl.hardware().name == "cpu_stream"          # this host's CPU
+    _pin_device(monkeypatch, "tpu", "TPU v5 lite")
+    assert rl.hardware() is rl.TPU_V5E
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", "TPU v5"])
+def test_hardware_unknown_tpu_kind_raises(monkeypatch, kind):
+    """A TPU the table does not hold is an error, not v5e's peaks."""
+    _pin_device(monkeypatch, "tpu", kind)
+    with pytest.raises(KeyError, match=kind):
+        rl.hardware()
 
 
 def test_measure_cpu_stream_is_positive_and_cached():
