@@ -91,14 +91,23 @@ class Reference:
             return list(ex.map(lambda w: self.scan(w[0], w[1]), windows))
 
 
+def window_days(selectivity: float) -> int:
+    """Whole days a window spans at ``selectivity`` of the date domain.
+    ``l_shipdate`` is a whole number of days, so a window narrower than one
+    day holds no row or a whole day: the Fig. 7 selectivities below one
+    day's share (0.001 % and 0.01 % of 2,555 days) round up to one day."""
+    from repro.storage.tpch import DATE_HI, DATE_LO
+    return max(1, round((DATE_HI - DATE_LO) * selectivity))
+
+
 def query_windows(rng: np.random.Generator, selectivity: float,
                   n: int) -> np.ndarray:
-    """(n, 2) f32 [lo, hi] shipdate windows covering ``selectivity`` of the
-    (uniform) date domain at uniformly drawn positions."""
+    """(n, 2) f32 [lo, hi] shipdate windows of ``window_days(selectivity)``
+    whole days at uniformly drawn first days, so every window holds rows."""
     from repro.storage.tpch import DATE_HI, DATE_LO
-    width = (DATE_HI - DATE_LO) * selectivity
-    lo = rng.uniform(DATE_LO, DATE_HI - width, n)
-    return np.stack([lo, lo + width], axis=1).astype(np.float32)
+    days = window_days(selectivity)
+    lo = rng.integers(DATE_LO, DATE_HI - days + 1, n)
+    return np.stack([lo, lo + days - 1], axis=1).astype(np.float32)
 
 
 def run_queries(engine, ref: Reference, windows_by_sel: dict, phase: str,
@@ -121,9 +130,12 @@ def run_queries(engine, ref: Reference, windows_by_sel: dict, phase: str,
                   f"{phase}: row ids of [{lo}, {hi}] are {t.row_ids[:8]}..., "
                   f"brute force says {ids[:8]}...")
         counts = [c for c, _ in want]
-        log(f"[{phase}] selectivity={sel:.3%} queries={len(tickets)} "
-            f"counts min={min(counts)} max={max(counts)} "
-            f"sum={sum(counts)} row_ids=ok batch_s={dt:.3f}")
+        check(max(counts) > 0, f"{phase}: no {sel:.3%} window holds a row")
+        log(f"[{phase}] selectivity={sel:.3%} days={window_days(sel)} "
+            f"queries={len(tickets)} empty={counts.count(0)} "
+            f"counts min={min(counts)} "
+            f"max={max(counts)} sum={sum(counts)} "
+            f"row_ids=ok batch_s={dt:.3f}")
 
 
 def stage_writes(engine, ref: Reference, values: np.ndarray,

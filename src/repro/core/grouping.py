@@ -32,7 +32,8 @@ def page_bucket_bits(hist: Histogram, keys: jnp.ndarray, valid: jnp.ndarray,
     Every tuple's bucket id (the paper's per-tuple binary search, §4.2) is
     compared against every bucket and OR-reduced over the page's slots — a
     fused compare-and-reduce rather than a scatter of all N tuples, which
-    the TPU compiler takes ~10x longer to build at SF 10 shard sizes.
+    the v5e compiler took ~24 s to build at SF 10 shard sizes (this form:
+    under 2 s).
     """
     num_pages, page_card = keys.shape
     ids = bucketize(hist, keys.reshape(-1)).reshape(num_pages, page_card)

@@ -223,9 +223,7 @@ class HippoIndex:
             return 0
         s = self.cfg.max_slots
         affected = np.zeros((s,), bool)
-        slots, _ = hix.locate_slot(self.state,
-                                   jnp.asarray(dirty_pages, jnp.int32))
-        affected[np.asarray(slots)] = True
+        affected[hix.owning_slots(self.state, dirty_pages)] = True
         self.state = hix.resummarize_slots(
             self.cfg, self.state, self.table.device_keys(),
             self.table.device_valid(), jnp.asarray(affected))

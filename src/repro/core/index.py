@@ -190,36 +190,66 @@ def locate_slot(state: HippoState, page_id) -> tuple[jnp.ndarray, jnp.ndarray]:
     return state.sorted_order[pos], pos
 
 
-def _expand_page_mask(state: HippoState, query_bitmaps: jnp.ndarray,
-                      num_pages: int) -> jnp.ndarray:
-    """Possible-qualified pages of each query (Bitmap b, Alg. 1).
+_locate_slot = jax.jit(locate_slot)
 
-    Live entries partition the summarized page space contiguously in logical
-    (sorted-list) order — the §5.3 invariant ``locate_slot``'s binary search
-    already relies on — so each page belongs to at most one entry: binary-
-    search every page's logical position once, gather the owning entry's
-    bitmap, and run the joint-bucket test per (query, page). This equals
-    testing every entry and expanding the matches to their page ranges, but
-    gathers (P, W) packed words instead of a (Q, P) slice of the (Q, S)
-    match matrix — a column gather the TPU compiler is slow to build once
-    the shard axis is vmapped. Pages past the last entry's ``end`` — and
-    everything in an empty index — resolve to no entry and stay False.
-    ``query_bitmaps`` is (W,) or (Q, W); the result is (num_pages,) or
-    (Q, num_pages).
+
+def owning_slots(state: HippoState, page_ids: np.ndarray) -> np.ndarray:
+    """Physical slots owning each of the (summarized) host ``page_ids``, in
+    one device search. The ids are padded with their first id to a power-of-
+    two length, so maintenance calls with varying numbers of dirty pages
+    reuse a few compiled programs instead of compiling one per count."""
+    ids = np.asarray(page_ids, np.int32).reshape(-1)
+    if ids.size == 0:
+        return np.zeros((0,), np.int32)
+    padded = np.full((1 << (ids.size - 1).bit_length(),), ids[0], np.int32)
+    padded[:ids.size] = ids
+    slots, _ = _locate_slot(state, jnp.asarray(padded))
+    return np.asarray(slots)[:ids.size]
+
+
+def _page_match(state: HippoState, query_bitmaps: jnp.ndarray,
+                num_pages: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Step 2 of Algorithm 1: possible-qualified pages (Bitmap b) and the
+    number of matched entries, per query.
+
+    One bit-level joint-bucket test of every query against every live entry
+    (Fig. 3) gives the (Q, S) match matrix. Live entries partition the
+    summarized page space contiguously in logical (sorted-list) order — the
+    §5.3 invariant ``locate_slot``'s binary search already relies on — so
+    each page belongs to at most one entry: binary-search every page's
+    logical position once and look up the owning entry's match bits. The
+    lookup packs the query axis into uint32 words and gathers one word row
+    per page; gathering a (Q, P) column slice of the bool matrix gives the
+    same mask, but a first full-cap batch at SF 10 shard shapes then took
+    about two minutes on a v5e, nearly all of it compiling, against 8-25 s
+    with this form.
+    Pages past the last entry's ``end`` — and everything in an empty index —
+    resolve to no entry and stay False. ``query_bitmaps`` is (W,) or
+    (Q, W); the results are (num_pages,) and a scalar, or (Q, num_pages)
+    and (Q,).
     """
     squeeze = query_bitmaps.ndim == 1
     qbm = query_bitmaps[None] if squeeze else query_bitmaps
+    q = qbm.shape[0]
     s = state.bitmaps.shape[0]
     live = state.slot_live & (jnp.arange(s) < state.num_slots)
+    match = bm.any_joint(qbm[:, None, :], state.bitmaps[None]) & live[None]
+    matched = match.sum(axis=1, dtype=jnp.int32)                    # (Q,)
     ls = _logical_starts(state)                        # (S,), INT32_MAX pads
     pages = jnp.arange(num_pages, dtype=jnp.int32)
     pos = jnp.searchsorted(ls, pages, side="right").astype(jnp.int32) - 1
     slot = state.sorted_order[jnp.clip(pos, 0, None)]  # owning physical slot
-    owned = (pos >= 0) & (pages <= state.ends[slot]) & live[slot]
-    page_bits = state.bitmaps[slot]                    # (P, W)
-    page_mask = (bm.any_joint(qbm[:, None, :], page_bits[None, :, :])
-                 & owned[None, :])
-    return page_mask[0] if squeeze else page_mask
+    owned = (pos >= 0) & (pages <= state.ends[slot])
+    # bit j of words[w, e]: entry e matched query 32 w + j
+    nw = bm.num_words(q)
+    padded = jnp.zeros((nw * bm.WORD_BITS, s), bool).at[:q].set(match)
+    shifts = jnp.arange(bm.WORD_BITS, dtype=jnp.uint32)
+    words = (padded.reshape(nw, bm.WORD_BITS, s).astype(jnp.uint32)
+             << shifts[None, :, None]).sum(axis=1, dtype=jnp.uint32)
+    page_mask = bm.to_bool(words.T[slot], q).T & owned[None, :]     # (Q, P)
+    if squeeze:
+        return page_mask[0], matched[0]
+    return page_mask, matched
 
 
 @partial(jax.jit, static_argnames=())
@@ -231,11 +261,8 @@ def search(state: HippoState, query_bitmap: jnp.ndarray, keys: jnp.ndarray,
     lo/hi: the predicate interval for exact inspection (step 3).
     """
     num_pages = keys.shape[0]
-    s = state.bitmaps.shape[0]
-    live = state.slot_live & (jnp.arange(s) < state.num_slots)
     # Step 2 — bit-level parallel joint-bucket test (Fig. 3).
-    match = bm.any_joint(state.bitmaps, query_bitmap[None, :]) & live
-    page_mask = _expand_page_mask(state, query_bitmap, num_pages)
+    page_mask, matched = _page_match(state, query_bitmap, num_pages)
     # Step 3 — inspect possible qualified pages tuple-by-tuple (vectorized).
     v = keys.astype(jnp.float32)
     qualified = page_mask[:, None] & valid & (v >= lo) & (v <= hi)
@@ -244,7 +271,7 @@ def search(state: HippoState, query_bitmap: jnp.ndarray, keys: jnp.ndarray,
         qualified=qualified,
         page_mask=page_mask,
         pages_inspected=page_mask.sum(dtype=jnp.int32),
-        entries_matched=match.sum(dtype=jnp.int32),
+        entries_matched=matched,
     )
 
 
@@ -256,18 +283,14 @@ def search_many(state: HippoState, query_bitmaps: jnp.ndarray, keys: jnp.ndarray
 
     query_bitmaps: (Q, W) packed query bitmaps; los/his: (Q,) predicate
     intervals. The entry-match and page-mask steps of ``search`` gain a
-    leading query axis — one (Q, S) joint-bucket AND, one (Q, P) joint-bucket
-    test against each page's owning entry — so Q queries cost one dispatch
-    instead of Q. Row q of every output is bit-identical to the
-    scalars ``search`` returns for predicate q.
+    leading query axis — one (Q, S) joint-bucket AND, one (Q, P) lookup of
+    each page's owning entry — so Q queries cost one dispatch instead of Q.
+    Row q of every output is bit-identical to the scalars ``search`` returns
+    for predicate q.
     """
     num_pages = keys.shape[0]
-    s = state.bitmaps.shape[0]
-    live = state.slot_live & (jnp.arange(s) < state.num_slots)
     # Step 2, batched: joint-bucket test of every query against every entry.
-    match = bm.any_joint(query_bitmaps[:, None, :], state.bitmaps[None, :, :])
-    match = match & live[None, :]                                   # (Q, S)
-    page_mask = _expand_page_mask(state, query_bitmaps, num_pages)  # (Q, P)
+    page_mask, matched = _page_match(state, query_bitmaps, num_pages)
     # Step 3, batched: inspect possible qualified pages for every query.
     v = keys.astype(jnp.float32)[None]
     qualified = (page_mask[:, :, None] & valid[None]
@@ -276,7 +299,7 @@ def search_many(state: HippoState, query_bitmaps: jnp.ndarray, keys: jnp.ndarray
         counts=qualified.sum(axis=(1, 2), dtype=jnp.int32),
         page_mask=page_mask,
         pages_inspected=page_mask.sum(axis=1, dtype=jnp.int32),
-        entries_matched=match.sum(axis=1, dtype=jnp.int32),
+        entries_matched=matched,
     )
 
 
@@ -386,10 +409,7 @@ def search_compact(state: HippoState, query_bitmap: jnp.ndarray, keys: jnp.ndarr
     if max_selected < 1:
         raise ValueError(f"max_selected must be >= 1, got {max_selected}")
     num_pages = keys.shape[0]
-    s = state.bitmaps.shape[0]
-    live = state.slot_live & (jnp.arange(s) < state.num_slots)
-    match = bm.any_joint(state.bitmaps, query_bitmap[None, :]) & live
-    page_mask = _expand_page_mask(state, query_bitmap, num_pages)
+    page_mask, _ = _page_match(state, query_bitmap, num_pages)
     n_sel = page_mask.sum(dtype=jnp.int32)
     sel = jnp.nonzero(page_mask, size=max_selected, fill_value=num_pages)[0]
     in_range = sel < num_pages
@@ -429,12 +449,8 @@ def search_compact_many(state: HippoState, query_bitmaps: jnp.ndarray,
     if top_k < 0:
         raise ValueError(f"top_k must be >= 0, got {top_k}")
     num_pages = keys.shape[0]
-    s = state.bitmaps.shape[0]
-    live = state.slot_live & (jnp.arange(s) < state.num_slots)
     # Step 2, batched: joint-bucket test + page-range expansion per query.
-    match = bm.any_joint(query_bitmaps[:, None, :], state.bitmaps[None, :, :])
-    match = match & live[None, :]                                   # (Q, S)
-    page_mask = _expand_page_mask(state, query_bitmaps, num_pages)  # (Q, P)
+    page_mask, matched = _page_match(state, query_bitmaps, num_pages)
     # Union across the batch: one gather serves every query's inspection.
     union = jnp.any(page_mask, axis=0)                              # (P,)
     n_union = union.sum(dtype=jnp.int32)
@@ -461,7 +477,7 @@ def search_compact_many(state: HippoState, query_bitmaps: jnp.ndarray,
     return CompactBatchResult(
         counts=page_counts.sum(axis=1, dtype=jnp.int32),
         pages_inspected=pages_inspected,
-        entries_matched=match.sum(axis=1, dtype=jnp.int32),
+        entries_matched=matched,
         truncated=covered < pages_inspected,
         bucket_needed=n_union,
         pages_selected=n_union,
@@ -482,8 +498,7 @@ def _first_row_ids(page_counts, sel, slab_keys, slab_valid, los, his,
     O(Q x top_k x C) — a (Q, M*C) position array is never built. A page
     holding a qualifying row is one the query selected, so the re-inspection
     needs no selection mask; invalid slots read as NaN, which fails every
-    interval test (one f32 gather instead of bool gathers, which the TPU
-    compiler is slow to build under the shard vmap).
+    interval test (one f32 gather in place of bool gathers).
     """
     m, card = slab_keys.shape
     ks = jnp.arange(top_k, dtype=jnp.int32)

@@ -550,9 +550,8 @@ class ShardedHippoIndex:
         st = shard_state(self.state.shards, s)
         affected = np.zeros((self.cfg.max_slots,), bool)
         # one sorted-list search for every dirty page of the shard
-        slots, _ = hix.locate_slot(st, jnp.asarray(
-            dirty_pages - self.spec.page_lo(s), jnp.int32))
-        affected[np.asarray(slots)] = True
+        affected[hix.owning_slots(
+            st, dirty_pages - self.spec.page_lo(s))] = True
         st = hix.resummarize_slots(self.cfg, st, keys[s], valid[s],
                                    jnp.asarray(affected))
         self._apply_shard(s, st)

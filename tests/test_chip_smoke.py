@@ -61,6 +61,22 @@ def test_reference_scan_counts_and_first_ids():
     assert count == 2 and list(ids) == [2, 5]
 
 
+@pytest.mark.parametrize("selectivity", [1e-5, 1e-4, 1e-3, 1e-2])
+def test_query_windows_are_whole_days(selectivity):
+    """Windows span whole shipdate days inside the generated domain, at
+    least one, so a window below one day's share still holds rows."""
+    import numpy as np
+    from repro.storage.tpch import DATE_HI, DATE_LO
+    smoke = _load_smoke()
+    days = smoke.window_days(selectivity)
+    assert days == max(1, round((DATE_HI - DATE_LO) * selectivity))
+    w = smoke.query_windows(np.random.default_rng(0), selectivity, 256)
+    assert w.shape == (256, 2) and w.dtype == np.float32
+    assert (w == np.round(w)).all()
+    assert (w[:, 1] - w[:, 0] == days - 1).all()
+    assert w[:, 0].min() >= DATE_LO and w[:, 1].max() <= DATE_HI - 1
+
+
 def test_smoke_refuses_without_a_chip(tmp_path):
     """On the CPU the script exits non-zero and prints no ``ok`` line."""
     proc = subprocess.run([sys.executable, str(SCRIPT)], cwd=tmp_path,

@@ -7,7 +7,7 @@ from repro.core import bitmap as bm
 from repro.core import histogram as hg
 from repro.core import index as hix
 from repro.core.hippo import HippoIndex
-from repro.core.predicate import Predicate
+from repro.core.predicate import Predicate, to_bucket_bitmap
 from repro.storage.table import PagedTable
 
 
@@ -49,6 +49,56 @@ def test_locate_slot_takes_an_array_of_pages():
         s, q = hix.locate_slot(idx.state, jnp.int32(p))
         assert (int(slots[p]), int(pos[p])) == (int(s), int(q))
         assert int(idx.state.starts[s]) <= p <= int(idx.state.ends[s])
+
+
+@pytest.mark.parametrize("n", [1, 3, 4, 5, 8, 9])
+def test_owning_slots_pads_to_a_power_of_two(n):
+    """The host search over a subset of pages equals one search per page,
+    and every count in (2^k, 2^(k+1)] reuses one compiled program."""
+    rng = np.random.default_rng(4)
+    idx = make_index(rng.uniform(0, 100, 400))
+    pages = np.sort(rng.choice(idx.table.num_pages, n, replace=False))
+    slots = hix.owning_slots(idx.state, pages)
+    assert slots.shape == (n,)
+    for p, s in zip(pages, slots):
+        assert int(s) == int(hix.locate_slot(idx.state, jnp.int32(p))[0])
+    before = hix._locate_slot._cache_size()
+    width = 1 << (n - 1).bit_length()
+    for m in range(width // 2 + 1, width + 1):
+        hix.owning_slots(idx.state, pages[:1].repeat(m))
+    assert hix._locate_slot._cache_size() - before <= 1
+
+
+def test_entries_matched_counts_live_entries_after_maintenance():
+    """entries_matched and the page mask equal a direct joint-bucket test of
+    every live slot expanded over its page range — also after relocating
+    inserts and a delete + vacuum have reshuffled slots and the sorted list,
+    and for a batch wider than one 32-query word of the packed lookup."""
+    rng = np.random.default_rng(5)
+    idx = make_index(rng.uniform(0, 100, 800), relocate_on_update=True)
+    for v in rng.uniform(0, 100, 60):
+        idx.insert(float(v))
+    idx.table.delete_where(20.0, 30.0)
+    idx.vacuum()
+    preds = [Predicate.between(float(lo), float(lo) + w)
+             for lo, w in zip(rng.uniform(0, 100, 40), rng.uniform(0, 20, 40))]
+    res = idx.search_batch(preds)
+    st = idx.state
+    s = idx.cfg.max_slots
+    live = np.asarray(st.slot_live) & (np.arange(s) < int(st.num_slots))
+    assert live.sum() < int(st.num_slots)       # relocation left dead slots
+    starts, ends = np.asarray(st.starts), np.asarray(st.ends)
+    for q, p in enumerate(preds):
+        qbm = to_bucket_bitmap(p, st.histogram)
+        match = np.asarray(bm.any_joint(st.bitmaps, qbm[None, :])) & live
+        pages = np.zeros(idx.table.num_pages, bool)
+        for e in np.flatnonzero(match):
+            pages[starts[e]:ends[e] + 1] = True
+        assert int(res.entries_matched[q]) == int(match.sum())
+        assert (np.asarray(res.page_mask[q]) == pages).all()
+        single = idx.search(p)
+        assert int(single.entries_matched) == int(match.sum())
+        assert (np.asarray(single.page_mask) == pages).all()
 
 
 def test_entry_bitmap_matches_page_contents():
