@@ -232,21 +232,23 @@ def _page_match(state: HippoState, query_bitmaps: jnp.ndarray,
     qbm = query_bitmaps[None] if squeeze else query_bitmaps
     q = qbm.shape[0]
     s = state.bitmaps.shape[0]
-    live = state.slot_live & (jnp.arange(s) < state.num_slots)
-    match = bm.any_joint(qbm[:, None, :], state.bitmaps[None]) & live[None]
-    matched = match.sum(axis=1, dtype=jnp.int32)                    # (Q,)
-    ls = _logical_starts(state)                        # (S,), INT32_MAX pads
-    pages = jnp.arange(num_pages, dtype=jnp.int32)
-    pos = jnp.searchsorted(ls, pages, side="right").astype(jnp.int32) - 1
-    slot = state.sorted_order[jnp.clip(pos, 0, None)]  # owning physical slot
-    owned = (pos >= 0) & (pages <= state.ends[slot])
-    # bit j of words[w, e]: entry e matched query 32 w + j
-    nw = bm.num_words(q)
-    padded = jnp.zeros((nw * bm.WORD_BITS, s), bool).at[:q].set(match)
-    shifts = jnp.arange(bm.WORD_BITS, dtype=jnp.uint32)
-    words = (padded.reshape(nw, bm.WORD_BITS, s).astype(jnp.uint32)
-             << shifts[None, :, None]).sum(axis=1, dtype=jnp.uint32)
-    page_mask = bm.to_bool(words.T[slot], q).T & owned[None, :]     # (Q, P)
+    with jax.named_scope("hippo.entry_filter"):
+        live = state.slot_live & (jnp.arange(s) < state.num_slots)
+        match = bm.any_joint(qbm[:, None, :], state.bitmaps[None]) & live[None]
+        matched = match.sum(axis=1, dtype=jnp.int32)                # (Q,)
+        # bit j of words[w, e]: entry e matched query 32 w + j
+        nw = bm.num_words(q)
+        padded = jnp.zeros((nw * bm.WORD_BITS, s), bool).at[:q].set(match)
+        shifts = jnp.arange(bm.WORD_BITS, dtype=jnp.uint32)
+        words = (padded.reshape(nw, bm.WORD_BITS, s).astype(jnp.uint32)
+                 << shifts[None, :, None]).sum(axis=1, dtype=jnp.uint32)
+    with jax.named_scope("hippo.page_expand"):
+        ls = _logical_starts(state)                    # (S,), INT32_MAX pads
+        pages = jnp.arange(num_pages, dtype=jnp.int32)
+        pos = jnp.searchsorted(ls, pages, side="right").astype(jnp.int32) - 1
+        slot = state.sorted_order[jnp.clip(pos, 0, None)]  # owning slot
+        owned = (pos >= 0) & (pages <= state.ends[slot])
+        page_mask = bm.to_bool(words.T[slot], q).T & owned[None, :]  # (Q, P)
     if squeeze:
         return page_mask[0], matched[0]
     return page_mask, matched
@@ -264,13 +266,16 @@ def search(state: HippoState, query_bitmap: jnp.ndarray, keys: jnp.ndarray,
     # Step 2 — bit-level parallel joint-bucket test (Fig. 3).
     page_mask, matched = _page_match(state, query_bitmap, num_pages)
     # Step 3 — inspect possible qualified pages tuple-by-tuple (vectorized).
-    v = keys.astype(jnp.float32)
-    qualified = page_mask[:, None] & valid & (v >= lo) & (v <= hi)
+    with jax.named_scope("hippo.inspect"):
+        v = keys.astype(jnp.float32)
+        qualified = page_mask[:, None] & valid & (v >= lo) & (v <= hi)
+        count = qualified.sum(dtype=jnp.int32)
+        inspected = page_mask.sum(dtype=jnp.int32)
     return SearchResult(
-        count=qualified.sum(dtype=jnp.int32),
+        count=count,
         qualified=qualified,
         page_mask=page_mask,
-        pages_inspected=page_mask.sum(dtype=jnp.int32),
+        pages_inspected=inspected,
         entries_matched=matched,
     )
 
@@ -292,13 +297,16 @@ def search_many(state: HippoState, query_bitmaps: jnp.ndarray, keys: jnp.ndarray
     # Step 2, batched: joint-bucket test of every query against every entry.
     page_mask, matched = _page_match(state, query_bitmaps, num_pages)
     # Step 3, batched: inspect possible qualified pages for every query.
-    v = keys.astype(jnp.float32)[None]
-    qualified = (page_mask[:, :, None] & valid[None]
-                 & (v >= los[:, None, None]) & (v <= his[:, None, None]))
+    with jax.named_scope("hippo.inspect"):
+        v = keys.astype(jnp.float32)[None]
+        qualified = (page_mask[:, :, None] & valid[None]
+                     & (v >= los[:, None, None]) & (v <= his[:, None, None]))
+        counts = qualified.sum(axis=(1, 2), dtype=jnp.int32)
+        inspected = page_mask.sum(axis=1, dtype=jnp.int32)
     return BatchSearchResult(
-        counts=qualified.sum(axis=(1, 2), dtype=jnp.int32),
+        counts=counts,
         page_mask=page_mask,
-        pages_inspected=page_mask.sum(axis=1, dtype=jnp.int32),
+        pages_inspected=inspected,
         entries_matched=matched,
     )
 
@@ -365,10 +373,11 @@ def staged_overlay_counts(staged_vals: jnp.ndarray, staged_live: jnp.ndarray,
     interval test — the device half of the writer's staging-buffer overlay
     (``runtime.writer.MaintenanceWriter``).
     """
-    v = staged_vals[None]                                       # (1, S, B)
-    hit = (staged_live[None] & (v >= los[:, None, None])
-           & (v <= his[:, None, None]))
-    return hit.sum(axis=(1, 2), dtype=jnp.int32)
+    with jax.named_scope("hippo.staged_overlay"):
+        v = staged_vals[None]                                   # (1, S, B)
+        hit = (staged_live[None] & (v >= los[:, None, None])
+               & (v <= his[:, None, None]))
+        return hit.sum(axis=(1, 2), dtype=jnp.int32)
 
 
 def search_many_sharded_staged(shards: HippoState, query_bitmaps: jnp.ndarray,
@@ -410,13 +419,18 @@ def search_compact(state: HippoState, query_bitmap: jnp.ndarray, keys: jnp.ndarr
         raise ValueError(f"max_selected must be >= 1, got {max_selected}")
     num_pages = keys.shape[0]
     page_mask, _ = _page_match(state, query_bitmap, num_pages)
-    n_sel = page_mask.sum(dtype=jnp.int32)
-    sel = jnp.nonzero(page_mask, size=max_selected, fill_value=num_pages)[0]
-    in_range = sel < num_pages
-    pk = jnp.where(in_range[:, None], keys.at[sel].get(mode="fill", fill_value=0.0), 0.0)
-    pv = valid.at[sel].get(mode="fill", fill_value=False) & in_range[:, None]
-    qual = pv & (pk.astype(jnp.float32) >= lo) & (pk.astype(jnp.float32) <= hi)
-    return qual.sum(dtype=jnp.int32), n_sel, n_sel > max_selected
+    with jax.named_scope("hippo.select"):
+        n_sel = page_mask.sum(dtype=jnp.int32)
+        sel = jnp.nonzero(page_mask, size=max_selected, fill_value=num_pages)[0]
+        in_range = sel < num_pages
+    with jax.named_scope("hippo.gather"):
+        pk = jnp.where(in_range[:, None],
+                       keys.at[sel].get(mode="fill", fill_value=0.0), 0.0)
+        pv = valid.at[sel].get(mode="fill", fill_value=False) & in_range[:, None]
+    with jax.named_scope("hippo.inspect"):
+        v = pk.astype(jnp.float32)
+        count = (pv & (v >= lo) & (v <= hi)).sum(dtype=jnp.int32)
+    return count, n_sel, n_sel > max_selected
 
 
 @partial(jax.jit, static_argnames=("max_selected", "top_k"))
@@ -452,30 +466,38 @@ def search_compact_many(state: HippoState, query_bitmaps: jnp.ndarray,
     # Step 2, batched: joint-bucket test + page-range expansion per query.
     page_mask, matched = _page_match(state, query_bitmaps, num_pages)
     # Union across the batch: one gather serves every query's inspection.
-    union = jnp.any(page_mask, axis=0)                              # (P,)
-    n_union = union.sum(dtype=jnp.int32)
-    sel = jnp.nonzero(union, size=max_selected, fill_value=num_pages)[0]
-    in_range = sel < num_pages                                      # (M,)
-    slab_keys = jnp.where(in_range[:, None],
-                          keys.at[sel].get(mode="fill", fill_value=0.0), 0.0)
-    slab_valid = valid.at[sel].get(mode="fill", fill_value=False) & in_range[:, None]
-    # Each query's mask restricted to the gathered slab (filter-match half of
-    # the fused inspect; kernels/compact_inspect is the Pallas twin).
-    sel_mask = (page_mask.at[:, sel].get(mode="fill", fill_value=False)
-                & in_range[None, :])                                # (Q, M)
-    v = slab_keys.astype(jnp.float32)[None]
-    qual = (sel_mask[:, :, None] & slab_valid[None]
-            & (v >= los[:, None, None]) & (v <= his[:, None, None]))
-    pages_inspected = page_mask.sum(axis=1, dtype=jnp.int32)
-    covered = sel_mask.sum(axis=1, dtype=jnp.int32)
-    page_counts = qual.sum(axis=2, dtype=jnp.int32)                 # (Q, M)
+    with jax.named_scope("hippo.select"):
+        union = jnp.any(page_mask, axis=0)                          # (P,)
+        n_union = union.sum(dtype=jnp.int32)
+        sel = jnp.nonzero(union, size=max_selected, fill_value=num_pages)[0]
+        in_range = sel < num_pages                                  # (M,)
+    with jax.named_scope("hippo.gather"):
+        slab_keys = jnp.where(in_range[:, None],
+                              keys.at[sel].get(mode="fill", fill_value=0.0),
+                              0.0)
+        slab_valid = (valid.at[sel].get(mode="fill", fill_value=False)
+                      & in_range[:, None])
+        # Each query's mask restricted to the gathered slab (filter-match
+        # half of the fused inspect; kernels/compact_inspect is the Pallas
+        # twin).
+        sel_mask = (page_mask.at[:, sel].get(mode="fill", fill_value=False)
+                    & in_range[None, :])                            # (Q, M)
+    with jax.named_scope("hippo.inspect"):
+        v = slab_keys.astype(jnp.float32)[None]
+        qual = (sel_mask[:, :, None] & slab_valid[None]
+                & (v >= los[:, None, None]) & (v <= his[:, None, None]))
+        pages_inspected = page_mask.sum(axis=1, dtype=jnp.int32)
+        covered = sel_mask.sum(axis=1, dtype=jnp.int32)
+        page_counts = qual.sum(axis=2, dtype=jnp.int32)             # (Q, M)
+        counts = page_counts.sum(axis=1, dtype=jnp.int32)
     if top_k:
-        row_ids = _first_row_ids(page_counts, sel, slab_keys, slab_valid,
-                                 los, his, top_k)
+        with jax.named_scope("hippo.row_ids"):
+            row_ids = _first_row_ids(page_counts, sel, slab_keys, slab_valid,
+                                     los, his, top_k)
     else:
         row_ids = jnp.zeros((qual.shape[0], 0), jnp.int32)
     return CompactBatchResult(
-        counts=page_counts.sum(axis=1, dtype=jnp.int32),
+        counts=counts,
         pages_inspected=pages_inspected,
         entries_matched=matched,
         truncated=covered < pages_inspected,
@@ -544,14 +566,15 @@ def search_compact_many_sharded(shards: HippoState, query_bitmaps: jnp.ndarray,
     per = jax.vmap(fn, in_axes=(SHARD_AXES, 0, 0, 0, None, None))(
         shards, query_bitmaps, keys, valid, los, his)
     if top_k:
-        s, _, card = keys.shape
-        offs = (jnp.arange(s, dtype=jnp.int32) * keys.shape[1] * card)
-        gids = jnp.where(per.row_ids >= 0,
-                         per.row_ids + offs[:, None, None], _I32_PAD)
-        q = gids.shape[1]
-        merged = jnp.moveaxis(gids, 0, 1).reshape(q, -1)      # (Q, S*K)
-        merged = jax.lax.sort(merged, dimension=1)[:, :top_k]
-        row_ids = jnp.where(merged < _I32_PAD, merged, -1)
+        with jax.named_scope("hippo.row_ids"):
+            s, _, card = keys.shape
+            offs = (jnp.arange(s, dtype=jnp.int32) * keys.shape[1] * card)
+            gids = jnp.where(per.row_ids >= 0,
+                             per.row_ids + offs[:, None, None], _I32_PAD)
+            q = gids.shape[1]
+            merged = jnp.moveaxis(gids, 0, 1).reshape(q, -1)  # (Q, S*K)
+            merged = jax.lax.sort(merged, dimension=1)[:, :top_k]
+            row_ids = jnp.where(merged < _I32_PAD, merged, -1)
     else:
         row_ids = per.row_ids[0]
     return CompactBatchResult(

@@ -53,6 +53,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import bitmap as bm
 from repro.core import histogram as hg
@@ -320,18 +321,22 @@ class ShardedHippoIndex:
         """Fused (Q, S) path: one device program over every shard, counts
         reduced across the shard axis. Bit-identical counts to the unsharded
         ``HippoIndex.search_batch``; with a writer attached, counts also
-        include its staged-but-undrained rows (never-stale contract)."""
+        include its staged-but-undrained rows (never-stale contract). The
+        conversion and search dispatches are the profiler span
+        ``hippo.dispatch``."""
         self._check_swap_guard()
-        qbms = self._query_bitmaps(preds)
-        los, his = intervals(preds)
-        keys, valid = self._slabs()
-        if self.staging is not None and self.staging.staged_rows:
-            vals, live = self.staging.device_buffers()
-            res = hix.search_many_sharded_staged(self.state.shards, qbms, keys,
-                                                 valid, los, his, vals, live)
-        else:
-            res = hix.search_many_sharded(self.state.shards, qbms, keys, valid,
-                                          los, his)
+        with TraceAnnotation("hippo.dispatch"):
+            qbms = self._query_bitmaps(preds)
+            los, his = intervals(preds)
+            keys, valid = self._slabs()
+            if self.staging is not None and self.staging.staged_rows:
+                vals, live = self.staging.device_buffers()
+                res = hix.search_many_sharded_staged(
+                    self.state.shards, qbms, keys, valid, los, his, vals,
+                    live)
+            else:
+                res = hix.search_many_sharded(self.state.shards, qbms, keys,
+                                              valid, los, his)
         return res._replace(page_mask=res.page_mask[:, : self.table.num_pages])
 
     def search_compact_batch(self, preds: list[Predicate], *,
@@ -345,19 +350,22 @@ class ShardedHippoIndex:
         as on the dense path (never-stale contract); staged rows occupy no
         page yet, so they appear in counts only, never in row ids, and cannot
         truncate. Row ids are global (``page_id * page_card + slot``) and
-        bit-identical to the unsharded gather."""
+        bit-identical to the unsharded gather. The conversion and search
+        dispatches are the profiler span ``hippo.dispatch`` (arg ``bucket``,
+        the slab width ``max_selected``)."""
         self._check_swap_guard()
-        qbms = self._query_bitmaps(preds)
-        los, his = intervals(preds)
-        keys, valid = self._slabs()
-        if self.staging is not None and self.staging.staged_rows:
-            vals, live = self.staging.device_buffers()
-            return hix.search_compact_many_sharded_staged(
-                self.state.shards, qbms, keys, valid, los, his, vals, live,
+        with TraceAnnotation("hippo.dispatch", bucket=max_selected):
+            qbms = self._query_bitmaps(preds)
+            los, his = intervals(preds)
+            keys, valid = self._slabs()
+            if self.staging is not None and self.staging.staged_rows:
+                vals, live = self.staging.device_buffers()
+                return hix.search_compact_many_sharded_staged(
+                    self.state.shards, qbms, keys, valid, los, his, vals,
+                    live, max_selected=max_selected, top_k=top_k)
+            return hix.search_compact_many_sharded(
+                self.state.shards, qbms, keys, valid, los, his,
                 max_selected=max_selected, top_k=top_k)
-        return hix.search_compact_many_sharded(
-            self.state.shards, qbms, keys, valid, los, his,
-            max_selected=max_selected, top_k=top_k)
 
     @property
     def gather_cap(self) -> int:

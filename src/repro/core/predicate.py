@@ -104,12 +104,16 @@ def interval_bitmaps(bounds: jnp.ndarray, los: jnp.ndarray, his: jnp.ndarray,
     fuses.
     """
     h = bounds.shape[-1] - 1
-    b_lo = jnp.clip(jnp.searchsorted(bounds, los, side="right") - 1, 0, h - 1)
-    b_hi = jnp.clip(jnp.searchsorted(bounds, his, side="right") - 1, 0, h - 1)
-    idx = jnp.arange(bm.num_words(h) * bm.WORD_BITS, dtype=jnp.int32)
-    bits = ((idx[None, :] >= b_lo[:, None]) & (idx[None, :] <= b_hi[:, None])
-            & (idx[None, :] < h) & nonempty[:, None])
-    return bm.from_bool(bits)
+    with jax.named_scope("hippo.convert"):
+        b_lo = jnp.clip(jnp.searchsorted(bounds, los, side="right") - 1,
+                        0, h - 1)
+        b_hi = jnp.clip(jnp.searchsorted(bounds, his, side="right") - 1,
+                        0, h - 1)
+        idx = jnp.arange(bm.num_words(h) * bm.WORD_BITS, dtype=jnp.int32)
+        bits = ((idx[None, :] >= b_lo[:, None])
+                & (idx[None, :] <= b_hi[:, None])
+                & (idx[None, :] < h) & nonempty[:, None])
+        return bm.from_bool(bits)
 
 
 @jax.jit

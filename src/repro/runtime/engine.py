@@ -105,7 +105,9 @@ import threading
 from collections import deque
 from dataclasses import dataclass, field
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.partition import SUMMARY_POLICIES
 from repro.core.predicate import Predicate
@@ -835,43 +837,51 @@ class QueryEngine:
         (or, in sharded mode, one summary-routed dispatch per matched shard).
 
         Returns the tickets retired by this batch (empty if nothing pending).
+        The call is the profiler span ``hippo.run_batch`` (args ``batch``,
+        the batch's number, and ``active``, its real queries); the drain,
+        dispatch, readback and fallback spans nest inside it.
         """
-        # Drain *before* executing: the drain sits between the previous
-        # batch and this one either way, and a drain refusal (slot capacity)
-        # then raises before any query work instead of discarding a fully
-        # computed batch on the way out.
-        self._maybe_drain_between_batches()
-        self._admit()
-        active = [i for i, t in enumerate(self.slots) if t is not None]
-        if not active:
-            return []
-        row_ids = None
-        if self.mode == "compact":
-            counts, inspected, matched, row_ids = self._execute_compact(active)
-        elif self.sharded:
-            counts, inspected, matched = self._execute_sharded(active)
-        else:
-            counts, inspected, matched = self._execute_dense(active)
-        finished = []
-        for k, i in enumerate(active):
-            t = self.slots[i]
-            t.count = int(counts[k])
-            t.pages_inspected = int(inspected[k])
-            t.entries_matched = int(matched[k])
-            if row_ids is not None:
-                ids = row_ids[k]
-                t.row_ids = ids[ids >= 0].copy()   # strip the -1 pads
-            t.done = True
-            finished.append(t)
-            self.slots[i] = None          # recycle the slot
-        self.stats.batches += 1
-        if not self.sharded:
-            # compact and dense modes dispatch the full batch width; routed
-            # dispatch accounting happens per shard inside _execute_sharded
-            self.stats.slots_filled += len(active)
-            self.stats.pad_slots += self.batch - len(active)
-        self.stats.served += len(finished)
-        return finished
+        with TraceAnnotation("hippo.run_batch",
+                             batch=self.stats.batches) as span:
+            # Drain *before* executing: the drain sits between the previous
+            # batch and this one either way, and a drain refusal (slot
+            # capacity) then raises before any query work instead of
+            # discarding a fully computed batch on the way out.
+            self._maybe_drain_between_batches()
+            self._admit()
+            active = [i for i, t in enumerate(self.slots) if t is not None]
+            span.set_metadata(active=len(active))
+            if not active:
+                return []
+            row_ids = None
+            if self.mode == "compact":
+                counts, inspected, matched, row_ids = \
+                    self._execute_compact(active)
+            elif self.sharded:
+                counts, inspected, matched = self._execute_sharded(active)
+            else:
+                counts, inspected, matched = self._execute_dense(active)
+            finished = []
+            for k, i in enumerate(active):
+                t = self.slots[i]
+                t.count = int(counts[k])
+                t.pages_inspected = int(inspected[k])
+                t.entries_matched = int(matched[k])
+                if row_ids is not None:
+                    ids = row_ids[k]
+                    t.row_ids = ids[ids >= 0].copy()   # strip the -1 pads
+                t.done = True
+                finished.append(t)
+                self.slots[i] = None          # recycle the slot
+            self.stats.batches += 1
+            if not self.sharded:
+                # compact and dense modes dispatch the full batch width;
+                # routed dispatch accounting happens per shard inside
+                # _execute_sharded
+                self.stats.slots_filled += len(active)
+                self.stats.pad_slots += self.batch - len(active)
+            self.stats.served += len(finished)
+            return finished
 
     def _maybe_drain_between_batches(self) -> None:
         """Between-batches drain. A drain refusal (e.g. shard slot capacity)
@@ -883,7 +893,9 @@ class QueryEngine:
                 or not self.writer.pending_units):
             return
         try:
-            self._drain(self.drain_units)
+            with TraceAnnotation("hippo.drain",
+                                 units=self.writer.pending_units):
+                self._drain(self.drain_units)
         except RuntimeError:
             self._auto_drain_suspended = True
             raise
@@ -892,9 +904,10 @@ class QueryEngine:
         """One full-width device program; pads fill the free slots."""
         preds = [t.pred if t is not None else _EMPTY for t in self.slots]
         res = self.index.search_batch(preds)
-        counts = np.asarray(res.counts)[active]
-        inspected = np.asarray(res.pages_inspected)[active]
-        matched = np.asarray(res.entries_matched)[active]
+        with TraceAnnotation("hippo.readback"):
+            counts = np.asarray(res.counts)[active]
+            inspected = np.asarray(res.pages_inspected)[active]
+            matched = np.asarray(res.entries_matched)[active]
         return counts, inspected, matched
 
     def _execute_compact(self, active: list[int]) -> tuple:
@@ -906,17 +919,21 @@ class QueryEngine:
         ``pages_inspected``/``entries_matched`` come from the first run even
         for truncated rows (they are computed before the gather and exact
         regardless); only counts and row ids are patched from the fallback.
+        Each dispatch's result comes to the host in one ``hippo.readback``
+        span; the re-run is the span ``hippo.fallback`` (arg ``width``).
         """
         preds = [t.pred if t is not None else _EMPTY for t in self.slots]
         cap = self.index.gather_cap
         bucket = min(self._compact_bucket, cap)   # never gather past the slab
         res = self.index.search_compact_batch(preds, max_selected=bucket,
                                               top_k=self.top_k)
-        counts = np.asarray(res.counts).copy()
-        inspected = np.asarray(res.pages_inspected)
-        matched = np.asarray(res.entries_matched)
-        trunc = np.asarray(res.truncated)
-        row_ids = np.asarray(res.row_ids).copy() if self.top_k else None
+        with TraceAnnotation("hippo.readback"):
+            res = jax.device_get(res)
+        counts = res.counts.copy()
+        inspected = res.pages_inspected
+        matched = res.entries_matched
+        trunc = res.truncated
+        row_ids = res.row_ids.copy() if self.top_k else None
         st = self.stats
         st.compact_batches += 1
         shards = getattr(self.index, "num_shards", 1)
@@ -931,25 +948,26 @@ class QueryEngine:
             width = _pow2_at_least(max(len(bad), _FALLBACK_Q_MIN))
             fb_preds = [self.slots[i].pred for i in bad]
             fb_preds += [_EMPTY] * (width - len(bad))
-            fb = self.index.search_compact_batch(fb_preds, max_selected=cap,
-                                                 top_k=self.top_k)
+            with TraceAnnotation("hippo.fallback", width=width):
+                fb = self.index.search_compact_batch(
+                    fb_preds, max_selected=cap, top_k=self.top_k)
+                with TraceAnnotation("hippo.readback"):
+                    fb = jax.device_get(fb)
             # the fallback is a real extra dispatch: its slot width and its
             # slab capacity must land in occupancy/gather accounting, or the
             # stats overreport exactly when the engine is doing extra work
             st.slots_filled += len(bad)
             st.pad_slots += width - len(bad)
             self._account_compact_dispatch(fb, cap * shards)
-            if bool(np.asarray(fb.truncated)[: len(bad)].any()):
+            if bool(fb.truncated[: len(bad)].any()):
                 raise RuntimeError(
                     "compact fallback truncated at the full gather cap — "
                     "the slab no longer covers the table (was the index "
                     "mutated mid-batch?)")
-            fb_counts = np.asarray(fb.counts)
-            fb_ids = np.asarray(fb.row_ids) if row_ids is not None else None
             for k, i in enumerate(bad):
-                counts[i] = fb_counts[k]
+                counts[i] = fb.counts[k]
                 if row_ids is not None:
-                    row_ids[i] = fb_ids[k]
+                    row_ids[i] = fb.row_ids[k]
         st.compact_hits += len(active) - len(bad)
         return (counts[active], inspected[active], matched[active],
                 row_ids[active] if row_ids is not None else None)
@@ -997,9 +1015,10 @@ class QueryEngine:
             lo[: hit.size] = los[hit]
             hi[: hit.size] = his[hit]
             res = self.index.search_batch_shard_arrays(s, qb, lo, hi)
-            counts[hit] += np.asarray(res.counts)[: hit.size]
-            inspected[hit] += np.asarray(res.pages_inspected)[: hit.size]
-            matched[hit] += np.asarray(res.entries_matched)[: hit.size]
+            with TraceAnnotation("hippo.readback"):
+                counts[hit] += np.asarray(res.counts)[: hit.size]
+                inspected[hit] += np.asarray(res.pages_inspected)[: hit.size]
+                matched[hit] += np.asarray(res.entries_matched)[: hit.size]
             self.stats.shard_dispatches += 1
             self.stats.slots_filled += int(hit.size)
             self.stats.pad_slots += width - int(hit.size)

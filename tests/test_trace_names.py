@@ -1,0 +1,120 @@
+"""Profiler names of the served search path, checked on the CPU.
+
+The device program names Algorithm 1's stages with
+``jax.named_scope("hippo.<stage>")``; the names must survive ``jit`` and
+``vmap`` into the ``op_name`` metadata of the lowered HLO, which is what a
+device trace's operations carry. The engine names its host work with
+``jax.profiler.TraceAnnotation("hippo.<span>")``; a ``QueryEngine`` run
+under the profiler must record each span, nested in ``hippo.run_batch``,
+with its args.
+"""
+import re
+from functools import partial
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.core import index as hix
+from repro.core.partition import ShardedHippoIndex
+from repro.core.predicate import Predicate, intervals, interval_bitmaps_sharded
+from repro.runtime.engine import QueryEngine
+from repro.storage.table import PagedTable
+
+STEP2 = {"hippo.entry_filter", "hippo.page_expand"}
+COMPACT = STEP2 | {"hippo.select", "hippo.gather", "hippo.inspect",
+                   "hippo.row_ids"}
+
+
+@pytest.fixture(scope="module")
+def index():
+    rng = np.random.default_rng(7)
+    table = PagedTable.from_values(
+        rng.uniform(0, 1000, 3200).astype(np.float32), page_card=50)
+    return ShardedHippoIndex.create(table, num_shards=4, resolution=64,
+                                    density=0.2)
+
+
+def _args(index):
+    preds = [Predicate.between(10.0 * i, 10.0 * i + 40.0) for i in range(8)]
+    los, his = intervals(preds)
+    keys, valid = index._slabs()
+    return index._query_bitmaps(preds), keys, valid, los, his
+
+
+def _lowered(index, name):
+    qbms, keys, valid, los, his = _args(index)
+    shards = index.state.shards
+    staged = (jnp.zeros((4, 8), jnp.float32), jnp.ones((4, 8), bool))
+    if name == "search_compact_many_sharded":
+        return hix.search_compact_many_sharded.lower(
+            shards, qbms, keys, valid, los, his, max_selected=16, top_k=32)
+    if name == "search_many_sharded":
+        return hix.search_many_sharded.lower(shards, qbms, keys, valid, los,
+                                             his)
+    if name == "search_compact_many_sharded_staged":
+        fn = partial(hix.search_compact_many_sharded_staged, max_selected=16,
+                     top_k=32)
+        return jax.jit(fn).lower(shards, qbms, keys, valid, los, his, *staged)
+    return interval_bitmaps_sharded.lower(shards.bounds, los, his,
+                                          jnp.ones(los.shape, bool))
+
+
+@pytest.mark.parametrize("name,scopes", [
+    ("search_compact_many_sharded", COMPACT),
+    ("search_many_sharded", STEP2 | {"hippo.inspect"}),
+    ("search_compact_many_sharded_staged", COMPACT | {"hippo.staged_overlay"}),
+    ("interval_bitmaps_sharded", {"hippo.convert"}),
+])
+def test_lowered_program_names_each_stage_in_op_name(index, name, scopes):
+    text = _lowered(index, name).as_text(dialect="hlo", debug_info=True)
+    found = {s for op in re.findall(r'op_name="([^"]*)"', text)
+             for s in re.findall(r"hippo\.[a-z_]+", op)}
+    assert found == scopes
+
+
+def _host_spans(trace_dir: Path) -> list[tuple[str, int, int, dict]]:
+    from jax.profiler import ProfileData
+    path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    spans = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("hippo."):
+                    spans.append((e.name, int(e.start_ns),
+                                  int(e.start_ns + e.duration_ns),
+                                  dict(e.stats)))
+    return spans
+
+
+def test_engine_records_its_spans_nested_in_run_batch(index, tmp_path):
+    eng = QueryEngine(index, batch=8, mode="compact", top_k=4,
+                      compact_bucket=1)
+    wide = Predicate.between(0.0, 1000.0)
+    eng.run_all([wide, Predicate.between(5.0, 9.0)])       # compile
+    eng.write(500.0)                  # a staged row: the next batch drains it
+    with jax.profiler.trace(str(tmp_path)):
+        eng._compact_bucket = 1       # so the wide query truncates again
+        counts = eng.run_all([wide, Predicate.between(5.0, 9.0)])
+    assert counts[0] == 3201          # the staged row drained before the batch
+
+    spans = _host_spans(tmp_path)
+    names = [s[0] for s in spans]
+    assert set(names) == {"hippo.run_batch", "hippo.drain", "hippo.dispatch",
+                          "hippo.readback", "hippo.fallback"}
+    runs = [s for s in spans if s[0] == "hippo.run_batch"]
+    assert [r[3] for r in runs] == [{"batch": 1, "active": 2}]
+    (_, r0, r1, _), = runs
+    assert all(r0 <= a and b <= r1 for _, a, b, _ in spans)
+    args = {n: st for n, _, _, st in spans if n != "hippo.run_batch"}
+    assert args["hippo.drain"] == {"units": 1}
+    assert args["hippo.fallback"] == {"width": 8}
+    assert sorted(st["bucket"] for n, _, _, st in spans
+                  if n == "hippo.dispatch") == [1, index.gather_cap]
+    (_, f0, f1, _), = [s for s in spans if s[0] == "hippo.fallback"]
+    inside = [n for n, a, b, _ in spans if f0 <= a and b <= f1]
+    assert sorted(inside) == ["hippo.dispatch", "hippo.fallback",
+                              "hippo.readback"]
