@@ -207,6 +207,51 @@ def owning_slots(state: HippoState, page_ids: np.ndarray) -> np.ndarray:
     return np.asarray(slots)[:ids.size]
 
 
+_BLOCK = 128   # pages per block of the page expansion; the TPU's lane width
+
+
+def _page_owners(ls: jnp.ndarray, table: jnp.ndarray, num_pages: int
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Each page's owning logical position and that entry's column of
+    ``table``.
+
+    ``ls`` (S,) holds the logical starts, INT32_MAX-padded, strictly
+    ascending where live (each entry summarizes at least one page);
+    ``table`` (C, S) holds one column per logical entry. Returns ``pos`` =
+    ``searchsorted(ls, arange(num_pages), side="right") - 1`` (-1: no entry)
+    and ``table[:, pos]`` (zeros where ``pos`` is -1), with no search and no
+    gather of every page.
+
+    The pages split into blocks of ``_BLOCK``. A block's first page finds
+    its owner by counting starts at or before it, first over every
+    ``_BLOCK``-th start, then within that group. The block's pages hold at
+    most ``_BLOCK - 1`` further starts, so all its owners lie in the
+    ``_BLOCK`` entries from there: one contiguous slice of ``ls`` and of
+    ``table`` per block, and a dense compare of each page against them.
+    """
+    s, c = ls.shape[0], table.shape[0]
+    b = min(_BLOCK, s)
+    nb = -(-num_pages // b)
+    first = jnp.arange(nb, dtype=jnp.int32) * b                # (NB,)
+    groups = jnp.pad(ls, (0, -s % b),
+                     constant_values=_INT32_MAX).reshape(-1, b)
+    g = jnp.maximum((groups[:, 0] <= first[:, None]).sum(1, dtype=jnp.int32)
+                    - 1, 0)
+    head = g * b + (groups[g] <= first[:, None]).sum(1, dtype=jnp.int32) - 1
+    base = jnp.clip(head, 0, s - b)                            # (NB,)
+    cut = jax.vmap(lambda a, o: jax.lax.dynamic_slice_in_dim(a, o, b, axis=-1),
+                   in_axes=(None, 0))
+    here = cut(ls, base)[:, None, :]                           # (NB, 1, B)
+    after = cut(jnp.append(ls, _INT32_MAX), base + 1)[:, None, :]
+    pages = (first[:, None] + jnp.arange(b, dtype=jnp.int32))[:, :, None]
+    pos = base[:, None] + (here <= pages).sum(2, dtype=jnp.int32) - 1
+    owner = (here <= pages) & (after > pages)                  # (NB, B, B)
+    rows = jnp.where(owner[:, None], cut(table, base)[:, :, None, :], 0
+                     ).sum(3, dtype=table.dtype)               # (NB, C, B)
+    rows = rows.transpose(1, 0, 2).reshape(c, nb * b)
+    return pos.reshape(-1)[:num_pages], rows[:, :num_pages]
+
+
 def _page_match(state: HippoState, query_bitmaps: jnp.ndarray,
                 num_pages: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Step 2 of Algorithm 1: possible-qualified pages (Bitmap b) and the
@@ -215,14 +260,14 @@ def _page_match(state: HippoState, query_bitmaps: jnp.ndarray,
     One bit-level joint-bucket test of every query against every live entry
     (Fig. 3) gives the (Q, S) match matrix. Live entries partition the
     summarized page space contiguously in logical (sorted-list) order — the
-    §5.3 invariant ``locate_slot``'s binary search already relies on — so
-    each page belongs to at most one entry: binary-search every page's
-    logical position once and look up the owning entry's match bits. The
-    lookup packs the query axis into uint32 words and gathers one word row
-    per page; gathering a (Q, P) column slice of the bool matrix gives the
-    same mask, but a first full-cap batch at SF 10 shard shapes then took
-    about two minutes on a v5e, nearly all of it compiling, against 8-25 s
-    with this form.
+    §5.3 invariant ``locate_slot``'s binary search also relies on — so each
+    page belongs to at most one entry. One gather puts every entry's start,
+    end and match bits in logical order, and ``_page_owners`` expands them
+    to the pages block by block, with no search of every page. The match
+    bits travel packed, the query axis in uint32 words: a first full-cap
+    batch at SF 10 shard shapes that gathered a (Q, P) slice of the bool
+    matrix instead took about two minutes on a v5e, nearly all of it
+    compiling, against 8-25 s with packed words.
     Pages past the last entry's ``end`` — and everything in an empty index —
     resolve to no entry and stay False. ``query_bitmaps`` is (W,) or
     (Q, W); the results are (num_pages,) and a scalar, or (Q, num_pages)
@@ -243,12 +288,19 @@ def _page_match(state: HippoState, query_bitmaps: jnp.ndarray,
         words = (padded.reshape(nw, bm.WORD_BITS, s).astype(jnp.uint32)
                  << shifts[None, :, None]).sum(axis=1, dtype=jnp.uint32)
     with jax.named_scope("hippo.page_expand"):
-        ls = _logical_starts(state)                    # (S,), INT32_MAX pads
+        # each entry's start, end and match words, in logical order
+        table = jnp.concatenate(
+            [state.starts[None].astype(jnp.uint32),
+             state.ends[None].astype(jnp.uint32), words],
+            axis=0)[:, state.sorted_order]                          # (2+nw, S)
+        ls = jnp.where(jnp.arange(s) < state.num_entries,
+                       table[0].astype(jnp.int32), _INT32_MAX)
+        pos, cols = _page_owners(ls, table[1:], num_pages)
         pages = jnp.arange(num_pages, dtype=jnp.int32)
-        pos = jnp.searchsorted(ls, pages, side="right").astype(jnp.int32) - 1
-        slot = state.sorted_order[jnp.clip(pos, 0, None)]  # owning slot
-        owned = (pos >= 0) & (pages <= state.ends[slot])
-        page_mask = bm.to_bool(words.T[slot], q).T & owned[None, :]  # (Q, P)
+        owned = (pos >= 0) & (pages <= cols[0].astype(jnp.int32))
+        bits = (cols[1:, None, :] >> shifts[None, :, None]) & 1  # (nw, 32, P)
+        bits = bits.reshape(nw * bm.WORD_BITS, num_pages)[:q].astype(bool)
+        page_mask = bits & owned[None, :]                           # (Q, P)
     if squeeze:
         return page_mask[0], matched[0]
     return page_mask, matched

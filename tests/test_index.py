@@ -1,12 +1,14 @@
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import bitmap as bm
 from repro.core import histogram as hg
 from repro.core import index as hix
 from repro.core.hippo import HippoIndex
+from repro.core.partition import ShardedHippoIndex
 from repro.core.predicate import Predicate, to_bucket_bitmap
 from repro.storage.table import PagedTable
 
@@ -99,6 +101,104 @@ def test_entries_matched_counts_live_entries_after_maintenance():
         single = idx.search(p)
         assert int(single.entries_matched) == int(match.sum())
         assert (np.asarray(single.page_mask) == pages).all()
+
+
+def _expansion_case(name):
+    """(stacked state, (shards, Q, W) query bitmaps, pages) for one case of
+    the page-expansion checks; an unsharded state gains a shard axis of 1."""
+    rng = np.random.default_rng(11)
+    preds = [Predicate.between(float(lo), float(lo) + w)
+             for lo, w in zip(rng.uniform(0, 100, 40), rng.uniform(0, 20, 40))]
+    if name == "sharded":
+        table = PagedTable.from_values(
+            rng.uniform(0, 100, 8000).astype(np.float32), page_card=8)
+        idx = ShardedHippoIndex.create(table, num_shards=4, resolution=32,
+                                       density=0.25)
+        keys, _ = idx._slabs()
+        return idx.state.shards, idx._query_bitmaps(preds), keys.shape[1]
+    if name == "empty":
+        cfg = hix.HippoConfig(resolution=32, density=0.25, page_card=8,
+                              max_slots=64)
+        hist = hg.build(jnp.asarray(rng.uniform(0, 100, 256)), 32)
+        state = hix.build(cfg, hist, jnp.zeros((0, 8), jnp.float32),
+                          jnp.zeros((0, 8), bool))
+        pages = 300
+    else:
+        # "dense": every page its own entry, so every page starts one
+        idx = make_index(rng.uniform(0, 100, 4000),
+                         density=0.0 if name == "dense" else 0.25,
+                         relocate_on_update=name == "relocated")
+        if name == "relocated":
+            for v in rng.uniform(0, 100, 60):
+                idx.insert(float(v))
+            idx.table.delete_where(20.0, 30.0)
+            idx.vacuum()
+        state = idx.state
+        pages = idx.table.num_pages
+        if name == "slack":
+            # the slab's pages past the last entry's end, and barely more
+            # slots than entries (build order is page order)
+            pages += 17
+            k = int(state.num_entries) + 5
+            state = state._replace(**{f: getattr(state, f)[:k] for f in (
+                "bitmaps", "starts", "ends", "sorted_order", "slot_live")},
+                num_slots=jnp.int32(k))
+    qbms = jnp.stack([to_bucket_bitmap(p, state.histogram) for p in preds])
+    return (jax.tree.map(lambda a: jnp.asarray(a)[None], state), qbms[None],
+            pages)
+
+
+def _owner_reference(state, qbms, pages):
+    """Per-query page masks and match counts of one shard, in numpy: every
+    live logical entry's joint-bucket test, spread over its page range."""
+    starts, ends = np.asarray(state.starts), np.asarray(state.ends)
+    order, bits = np.asarray(state.sorted_order), np.asarray(state.bitmaps)
+    slots = np.arange(bits.shape[0])
+    live = np.asarray(state.slot_live) & (slots < int(state.num_slots))
+    match = ((bits[None] & np.asarray(qbms)[:, None]) != 0).any(-1) & live
+    mask = np.zeros((match.shape[0], pages), bool)
+    for e in order[: int(state.num_entries)]:
+        mask[:, starts[e]:ends[e] + 1] = match[:, e, None]
+    return mask, match.sum(axis=1)
+
+
+@pytest.mark.parametrize("case", ["fresh", "relocated", "empty", "slack",
+                                  "sharded", "dense"])
+def test_page_expansion_equals_the_binary_search(case):
+    """The block-wise expansion of the sorted starts gives every page the
+    logical position a binary search gives it and that entry's row, and
+    ``_page_match``'s masks equal each live entry's match bits spread over
+    its own pages."""
+    shards, qbms, pages = _expansion_case(case)
+    ls = jax.vmap(hix._logical_starts)(shards)
+    assert pages > hix._BLOCK            # more than one block of pages
+    every = jnp.arange(pages, dtype=jnp.int32)
+    want = jax.vmap(lambda l: jnp.searchsorted(l, every, side="right") - 1)(ls)
+    table = jnp.stack([jnp.arange(ls.shape[1], dtype=jnp.int32) + 1] * 2)
+    pos, cols = jax.vmap(hix._page_owners, in_axes=(0, None, None))(
+        ls, table, pages)
+    np.testing.assert_array_equal(np.asarray(pos), np.asarray(want))
+    col = np.asarray(want) + 1                    # 0 where there is no entry
+    np.testing.assert_array_equal(np.asarray(cols), np.stack([col] * 2, 1))
+    masks, matched = jax.vmap(hix._page_match, in_axes=(0, 0, None))(
+        shards, qbms, pages)
+    for k in range(qbms.shape[0]):
+        shard = jax.tree.map(lambda a: a[k], shards)
+        mask, count = _owner_reference(shard, qbms[k], pages)
+        np.testing.assert_array_equal(np.asarray(masks[k]), mask)
+        np.testing.assert_array_equal(np.asarray(matched[k]), count)
+    if case == "relocated":
+        order = np.asarray(shards.sorted_order[0])
+        assert (order != np.arange(order.size)).any()
+        live = np.asarray(shards.slot_live[0])
+        assert live.sum() < int(shards.num_slots[0])  # dead slots exist
+    if case in ("empty", "slack"):
+        last = int(shards.summarized_until[0])
+        assert not np.asarray(masks[:, :, last + 1:]).any()
+    if case == "slack":
+        assert shards.starts.shape[1] < int(shards.num_entries[0]) + hix._BLOCK
+    if case == "dense":
+        assert int(shards.num_entries[0]) == pages
 
 
 def test_entry_bitmap_matches_page_contents():

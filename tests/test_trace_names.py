@@ -76,6 +76,45 @@ def test_lowered_program_names_each_stage_in_op_name(index, name, scopes):
     assert found == scopes
 
 
+CALLEES = re.compile(r"\b(?:to_apply|condition|body|calls)=%?([\w.-]+)")
+
+
+def _loop_op_names(hlo_text: str) -> list[str]:
+    """The full ``op_name`` of every ``while`` in an HLO module's text: its
+    own, under those of the instructions that call its computation, as
+    the compiler joins them when it inlines the calls."""
+    callers: dict[str, list[tuple[str, str]]] = {}
+    loops, comp = [], None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            comp = line.split()[-2].lstrip("%")
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        op = op.group(1) if op else ""
+        for callee in CALLEES.findall(line):
+            callers.setdefault(callee, []).append((comp, op))
+        if re.search(r"\bwhile\(", line):
+            loops.append((comp, op))
+
+    def paths(comp: str) -> list[str]:
+        up = callers.get(comp, [])
+        return [f"{p}/{op}" for c, op in up for p in paths(c)] if up \
+            else [""]
+
+    return [f"{p}/{op}" for c, op in loops for p in paths(c)]
+
+
+def test_page_expansion_runs_no_loop(index):
+    """Page expansion is one linear pass: no ``while`` (the rounds of a
+    binary search of every page) runs under ``hippo.page_expand``. The
+    ``row_ids`` stage's own small search may loop, and is seen."""
+    text = _lowered(index, "search_compact_many_sharded").as_text(
+        dialect="hlo", debug_info=True)
+    loops = _loop_op_names(text)
+    assert any("hippo.row_ids" in n for n in loops)
+    assert not [n for n in loops if "hippo.page_expand" in n]
+
+
 def _host_spans(trace_dir: Path) -> list[tuple[str, int, int, dict]]:
     from jax.profiler import ProfileData
     path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
