@@ -23,8 +23,9 @@ from __future__ import annotations
 
 import numpy as np
 
-# independent streams of one seed
-LOAD, QUERIES, SAMPLE = 0, 1, 2
+# independent streams of one seed: the loaded table, the query streams, the
+# checked sample, the refresh stream's new orders
+LOAD, QUERIES, SAMPLE, REFRESH = 0, 1, 2, 3
 
 
 def rng(seed: int, stream: int) -> np.random.Generator:

@@ -19,13 +19,14 @@ import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
-sys.path.insert(0, str(BENCH.parent / "src"))
+sys.path[:0] = [str(BENCH.parent), str(BENCH.parent / "src")]
 
 FIRST_BUCKET = 64
 
 
 def programs(config: dict, spec):
     import jax.numpy as jnp
+    from chipbench.driver import engine_args
     from repro.core import index as hix
     from repro.core.partition import default_max_slots, default_pages_per_shard
 
@@ -33,7 +34,9 @@ def programs(config: dict, spec):
     shards, card, res = ix["num_shards"], ix["page_card"], ix["resolution"]
     words = -(-res // 32)
     pages = -(-config["rows"] // card)
-    pps = default_pages_per_shard(pages, shards)
+    # the shard slabs the cell builds: wide enough for its spare pages
+    pps = (engine_args(config, pages)[1].get("pages_per_shard")
+           or default_pages_per_shard(pages, shards))
     slots = default_max_slots(pps)
     q, top_k = en["batch"], en["top_k"]
 

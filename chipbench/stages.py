@@ -302,7 +302,8 @@ def record(cell, seed: int, seconds: float, out: Path,
     config = config or cell.config
     engine = driver.build_engine(config, datagen.load_column(config, seed))
     capture = tracing.Capture(True)
-    drv = driver.Driver(engine, loadgen.Streams(cell.mix, seed), capture)
+    drv = driver.Driver(engine, loadgen.Streams(cell.mix, seed), capture,
+                        loadgen.refresh_of(cell.mix, config, seed))
     for _ in range(int(cell.mix["warmup_rounds"])):
         drv.round()
     opts = jax.profiler.ProfileOptions()

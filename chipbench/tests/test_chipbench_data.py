@@ -162,13 +162,15 @@ def test_compare_counts_each_kind_of_mismatch():
     got = [(3, np.array([1, 2, 3])), None, (41, np.arange(32))]
     numbers = check.compare(got, want)
     assert numbers == {"unanswered": 1, "count_mismatches": 1,
-                       "rowid_mismatches": 0}
+                       "rowid_mismatches": 0, "refresh_failures": 0}
     assert not check.is_correct(numbers)
     got[1] = (0, np.array([], np.int64))
     got[2] = (40, np.arange(1, 33))
     assert check.compare(got, want) == {"unanswered": 0, "count_mismatches": 0,
-                                        "rowid_mismatches": 1}
+                                        "rowid_mismatches": 1,
+                                        "refresh_failures": 0}
     assert check.is_correct(check.compare(want, want))
+    assert not check.is_correct(check.compare(want, want, refresh_failures=1))
 
 
 # -- least bytes of a search batch --------------------------------------------
