@@ -57,6 +57,7 @@ def sample_histogram(table: PagedTable, resolution: int,
 class MaintenanceCounters:
     inserts: int = 0
     entries_touched: int = 0
+    pages_opened: int = 0      # pages an insert summarized first
     entries_created: int = 0
     vacuums: int = 0
     entries_resummarized: int = 0
@@ -170,50 +171,28 @@ class HippoIndex:
                                       jnp.int32(page_id))
         self.counters.inserts += 1
         self.counters.entries_touched += 1
+        self.counters.pages_opened += int(opens_page)
         self.counters.entries_created += int(self.state.num_entries) - before
 
     def insert_batch(self, values: np.ndarray) -> None:
-        """Vectorized insert (beyond-paper fast path). Atomic: either the
-        whole batch lands or, on slot-capacity exhaustion, table and index
-        are rolled back to their pre-batch snapshot before the raise.
-
-        Tuples landing on already-summarized pages take one fused scatter;
-        tuples opening new pages replay the eager path (they are few: at most
-        one page per page_card tuples).
-        """
+        """Batched insert at the table tail: one eager Algorithm 3 program
+        for the whole batch (``core.index.insert_appended``). Atomic: a
+        batch that could open more pages than there are free slots is
+        refused before the table or the index changes."""
         values = np.asarray(values, np.float32).ravel()
         if values.size == 0:
             return
-        snap_state = self.state
-        snap_pages, snap_fill = self.table.num_pages, self.table.fill
-        try:
-            self._insert_batch_apply(values)
-        except RuntimeError:
-            self.state = snap_state
-            self.table.truncate_to(snap_pages, snap_fill)
-            raise
-        self.counters.inserts += len(values)
-
-    def _insert_batch_apply(self, values: np.ndarray) -> None:
-        pages = []
-        for v in values:
-            pid, _ = self.table.insert(float(v))
-            pages.append(pid)
-        pages = np.asarray(pages, np.int32)
-        old_mask = pages <= int(self.state.summarized_until)
-        if old_mask.any():
-            # full batch passed with a mask => one stable jit shape per N;
-            # the fused scatter never relocates, so it consumes no slots
-            self.state = hix.insert_batch_existing(
-                self.cfg, self.state, jnp.asarray(values),
-                jnp.asarray(pages), jnp.asarray(old_mask))
-        for v, p in zip(values[~old_mask], pages[~old_mask]):
-            # only page-opening creates and (with relocation) eager updates
-            # can consume a slot — check per tuple, at actual need
-            if self.cfg.relocate_on_update or p > int(self.state.summarized_until):
-                self._require_slot_capacity()
-            self.state = hix.insert_tuple(self.cfg, self.state, jnp.float32(v),
-                                          jnp.int32(p))
+        pages = (self.table.tail + np.arange(values.size)) // \
+            self.table.page_card
+        opened = np.unique(pages[pages > int(self.state.summarized_until)]).size
+        self._require_slot_capacity(opened)
+        self.table.append(values)
+        before = int(self.state.num_entries)
+        self.state = hix.insert_appended(self.cfg, self.state,
+                                         *hix.pad_rows(values, pages))
+        self.counters.inserts += values.size
+        self.counters.pages_opened += opened
+        self.counters.entries_created += int(self.state.num_entries) - before
 
     def vacuum(self) -> int:
         """Lazy maintenance after deletes (§5.2): re-summarize entries whose

@@ -746,28 +746,103 @@ def insert_tuple(cfg: HippoConfig, state: HippoState, value: jnp.ndarray,
     return jax.lax.cond(is_new_page, new_page, existing_page, state)
 
 
-@partial(jax.jit, static_argnames=("cfg",))
-def insert_batch_existing(cfg: HippoConfig, state: HippoState, values: jnp.ndarray,
-                          page_ids: jnp.ndarray, mask: jnp.ndarray) -> HippoState:
-    """Vectorized eager update for tuples landing on already-summarized pages.
+def value_bits(cfg: HippoConfig, bounds: jnp.ndarray, values: jnp.ndarray,
+               rows: jnp.ndarray, live: jnp.ndarray, num_rows: int
+               ) -> jnp.ndarray:
+    """(num_rows, W) packed bucket bits: row r is the OR of the bits of the
+    live ``values`` whose ``rows`` entry is r (rows out of range are
+    dropped)."""
+    ids = bucketize(Histogram(bounds), values)
+    rows = jnp.where(live, rows, num_rows)
+    bits = jnp.zeros((num_rows, cfg.resolution), bool)
+    return bm.from_bool(bits.at[rows, ids].set(True, mode="drop"))
 
-    Beyond-paper fast path: bucketize all values, locate all owning slots with
-    one vectorized sorted-list binary search, and OR the new bits in via a
-    segment reduction. Semantically identical to repeated ``insert_tuple``
-    (modulo physical relocation, which fixed-width slots make unnecessary).
 
-    ``mask`` selects the tuples to apply (shape-stable: callers pass the full
-    batch each time; masked-out tuples route to a dropped segment).
+def pad_rows(values: np.ndarray, local_pages: np.ndarray,
+             live: np.ndarray | None = None) -> tuple:
+    """(values, local_pages, live) of an append padded to a power of two of
+    at least 8 rows with rows that never go live, so appends of similar
+    size share one compiled ``insert_appended``."""
+    n = values.shape[0]
+    b = 1 << max(n - 1, 7).bit_length()
+    pv = np.zeros((b,), np.float32)
+    pv[:n] = values
+    pl = np.full((b,), local_pages[-1], np.int32)
+    pl[:n] = local_pages
+    pm = np.zeros((b,), bool)
+    pm[:n] = True if live is None else live
+    return pv, pl, pm
+
+
+@partial(jax.jit, static_argnames=("cfg",), donate_argnames=("state",))
+def insert_appended(cfg: HippoConfig, state: HippoState, values: jnp.ndarray,
+                    local_pages: jnp.ndarray, live: jnp.ndarray) -> HippoState:
+    """Algorithm 3 for a batch of rows appended to the heap, in heap order:
+    the state repeated ``insert_tuple`` calls reach, in one program.
+
+    ``values``/``local_pages``/``live``: (N,) rows in append order, their
+    pages (non-decreasing, as appends fill the tail) and which of them go
+    live; padding rows have ``live`` False. Only the tail page can already
+    be summarized, and the last entry owns it: its live rows OR their bits
+    into that entry (``hippo.insert_existing``). Every later page's bitmap
+    is the OR of its rows' bits; a loop over those pages then applies the
+    paper's rule in order — extend the last entry while its density is
+    under D, else create an entry — which is what the row-by-row path
+    decides too, since a page's choice depends only on the last entry after
+    all rows of earlier pages (``hippo.insert_pages``). A page with no live
+    row opens nothing. Pages of N rows span at most N // page_card + 2
+    pages, so the page bitmaps' shape follows N.
+
+    Unlike ``insert_tuple`` with ``relocate_on_update``, it never relocates
+    an entry: bits are set in place. Logical entries (starts, ends and
+    bitmaps in sorted order) equal the row-by-row result; physical slots
+    may differ. The caller makes sure there is a free slot for every page
+    the batch opens. ``state`` is donated.
     """
-    hist = Histogram(state.bounds)
-    ids = bucketize(hist, values)                      # (N,)
-    slots, _ = jax.vmap(lambda p: locate_slot(state, p))(page_ids)
-    slots = jnp.where(mask, slots, cfg.max_slots)      # dropped by segment_max
-    onehot = jax.nn.one_hot(ids, cfg.resolution, dtype=jnp.int32)  # (N, H)
-    agg = jax.ops.segment_max(onehot, slots,
-                              num_segments=cfg.max_slots + 1) > 0
-    packed = bm.from_bool(agg[: cfg.max_slots])
-    return state._replace(bitmaps=state.bitmaps | packed)
+    num_pages = values.shape[0] // cfg.page_card + 2
+    first = local_pages[0]
+    new = local_pages > state.summarized_until
+    with jax.named_scope("hippo.insert_existing"):
+        tail_bits = value_bits(cfg, state.bounds, values,
+                               jnp.zeros_like(local_pages), live & ~new, 1)[0]
+        last = state.sorted_order[jnp.maximum(state.num_entries - 1, 0)]
+        state = state._replace(bitmaps=state.bitmaps.at[last].set(
+            state.bitmaps[last] | tail_bits))
+    with jax.named_scope("hippo.insert_pages"):
+        page_bits = value_bits(cfg, state.bounds, values, local_pages - first,
+                               live & new, num_pages)
+        count = jnp.max(jnp.where(live & new, local_pages - first + 1, 0))
+
+        def open_page(j, st: HippoState) -> HippoState:
+            bits = page_bits[j]
+            has = jnp.any(bits != 0)
+            page = first + j
+            last = st.sorted_order[jnp.maximum(st.num_entries - 1, 0)]
+            last_density = jnp.where(
+                st.num_entries > 0,
+                bm.density(st.bitmaps[last], cfg.resolution),
+                jnp.float32(2.0),  # empty index -> always create
+            )
+            create = has & ~(last_density < cfg.density)
+            slot = jnp.where(create, st.num_slots, last)
+            pos = jnp.minimum(st.num_entries, st.sorted_order.shape[0] - 1)
+            return st._replace(
+                bitmaps=st.bitmaps.at[slot].set(
+                    jnp.where(create, bits, st.bitmaps[slot] | bits)),
+                starts=st.starts.at[slot].set(
+                    jnp.where(create, page, st.starts[slot])),
+                ends=st.ends.at[slot].set(
+                    jnp.where(has, page, st.ends[slot])),
+                slot_live=st.slot_live.at[slot].set(
+                    st.slot_live[slot] | create),
+                sorted_order=st.sorted_order.at[pos].set(
+                    jnp.where(create, slot, st.sorted_order[pos])),
+                num_entries=st.num_entries + create,
+                num_slots=st.num_slots + create,
+                summarized_until=jnp.where(has, page, st.summarized_until),
+            )
+
+        return jax.lax.fori_loop(0, count, open_page, state)
 
 
 # ---------------------------------------------------------------------------

@@ -47,6 +47,7 @@ counts stay exact before, during, and after a partial re-summarization.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from typing import NamedTuple
 
 import numpy as np
@@ -148,6 +149,35 @@ def summary_of(st: hix.HippoState) -> jnp.ndarray:
     live = st.slot_live & (jnp.arange(s) < st.num_slots)
     masked = jnp.where(live[:, None], st.bitmaps, jnp.uint32(0))
     return jax.lax.reduce(masked, jnp.uint32(0), jax.lax.bitwise_or, (0,))
+
+
+@partial(jax.jit, static_argnames=("cfg",), donate_argnames=("state",))
+def apply_appended(cfg: hix.HippoConfig, state: ShardedHippoState, s,
+                   values: jnp.ndarray, local_pages: jnp.ndarray,
+                   live: jnp.ndarray) -> ShardedHippoState:
+    """Shard ``s``'s eager Algorithm 3 for rows appended to its slab
+    (``core.index.insert_appended``, page ids local to the slab), with its
+    summary ORed with the rows' bits: one program per shard an append
+    touches, in place on the donated stacked state."""
+    st = shard_state(state.shards, s)
+    bits = hix.value_bits(cfg, st.bounds, values, jnp.zeros_like(local_pages),
+                          live, 1)[0]
+    st = hix.insert_appended(cfg, st, values, local_pages, live)
+    return ShardedHippoState(
+        shards=set_shard(state.shards, s, st),
+        summaries=state.summaries.at[s].set(state.summaries[s] | bits))
+
+
+class AppendPiece(NamedTuple):
+    """The rows ``[lo, hi)`` of an append that land in shard ``shard``, on
+    slab-local ``pages``, opening ``opened`` pages the shard has not
+    summarized; the shard held ``entries`` entries before."""
+    shard: int
+    lo: int
+    hi: int
+    pages: np.ndarray
+    opened: int
+    entries: int
 
 
 def build_sharded(cfg: hix.HippoConfig, spec: ShardSpec, hist: hg.Histogram,
@@ -466,59 +496,73 @@ class ShardedHippoIndex:
         self._apply_shard(s, st)
         self.counters.inserts += 1
         self.counters.entries_touched += 1
+        self.counters.pages_opened += int(opens_page)
         self.counters.entries_created += int(st.num_entries) - before
 
     def insert_batch(self, values: np.ndarray) -> None:
-        """Atomic vectorized insert: tuples landing on already-summarized
-        pages take one fused scatter per touched shard (same batch shape for
-        every shard => one compiled trace); page-opening tuples replay the
-        eager path. On refusal the table and every shard roll back."""
+        """Atomic batched insert at the table tail: one eager Algorithm 3
+        program per shard the rows land in (``apply_appended``). What the
+        layout cannot hold is refused before the table or any shard
+        changes."""
         self._check_swap_guard()
         self._check_no_staged()
         values = np.asarray(values, np.float32).ravel()
         if values.size == 0:
             return
-        snap_state = self.state
-        snap_pages, snap_fill = self.table.num_pages, self.table.fill
-        try:
-            self._insert_batch_apply(values)
-        except RuntimeError:
-            self.state = snap_state
-            self.table.truncate_to(snap_pages, snap_fill)
-            raise
+        pieces = self.route_append(values)
+        self.table.append(values)
+        self.apply_append(pieces, values)
         self.counters.inserts += len(values)
 
-    def _insert_batch_apply(self, values: np.ndarray) -> None:
-        pages = []
-        for v in values:
-            pid, _ = self.table.insert(float(v))
-            if self.spec.owner(pid) >= self.spec.num_shards:
+    def route_append(self, values: np.ndarray, live: np.ndarray | None = None
+                     ) -> list[AppendPiece]:
+        """Route rows about to be appended at the table tail to their
+        shards, before anything changes. Refuses rows past the last slab,
+        and a shard without a free slot for every page the rows would open
+        there (each opened page creates at most one entry; nothing
+        relocates)."""
+        pps = self.spec.pages_per_shard
+        pages = (self.table.tail + np.arange(values.size)) // \
+            self.table.page_card
+        last = int(pages[-1])
+        if self.spec.owner(last) >= self.spec.num_shards:
+            raise RuntimeError(
+                f"shard layout full: page {last} falls past shard "
+                f"{self.spec.num_shards - 1}'s slab (pages_per_shard={pps}); "
+                f"rebuild with more shards or larger slabs")
+        live = np.ones(values.size, bool) if live is None else live
+        su, slots, entries = jax.device_get((
+            self.state.shards.summarized_until, self.state.shards.num_slots,
+            self.state.shards.num_entries))
+        owners = pages // pps
+        pieces = []
+        for s in range(int(owners[0]), int(owners[-1]) + 1):
+            lo, hi = (int(i) for i in np.searchsorted(owners, [s, s + 1]))
+            local = pages[lo:hi] - s * pps
+            opened = np.unique(local[live[lo:hi] & (local > su[s])]).size
+            if int(slots[s]) + opened > self.cfg.max_slots:
                 raise RuntimeError(
-                    f"shard layout full: page {pid} falls past shard "
-                    f"{self.spec.num_shards - 1}'s slab; rebuild with more "
-                    f"shards or larger slabs")
-            pages.append(pid)
-        pages = np.asarray(pages, np.int32)
-        owners = pages // self.spec.pages_per_shard
-        old_mask = pages <= self.summarized_until
-        vals_dev = jnp.asarray(values)
-        for s in np.unique(owners[old_mask]):
-            local = jnp.asarray(np.clip(pages - self.spec.page_lo(int(s)), 0,
-                                        self.spec.pages_per_shard - 1))
-            mask = jnp.asarray(old_mask & (owners == s))
-            st = hix.insert_batch_existing(
-                self.cfg, shard_state(self.state.shards, int(s)), vals_dev,
-                local, mask)
-            self._apply_shard(int(s), st)
-        for v, p in zip(values[~old_mask], pages[~old_mask]):
-            s = self.spec.owner(int(p))
-            opens = int(p) > self.summarized_until
-            if opens or self.cfg.relocate_on_update:
-                self._require_capacity(s, int(p), opens)
-            st = hix.insert_tuple(self.cfg, shard_state(self.state.shards, s),
-                                  jnp.float32(v),
-                                  jnp.int32(self.spec.to_local(int(p))))
-            self._apply_shard(s, st)
+                    f"shard {s} at slot capacity ({int(slots[s])}/"
+                    f"{self.cfg.max_slots}, {opened} pages to open); "
+                    f"rebuild with a larger max_slots")
+            pieces.append(AppendPiece(s, lo, hi, local, opened,
+                                      int(entries[s])))
+        return pieces
+
+    def apply_append(self, pieces: list[AppendPiece], values: np.ndarray,
+                     live: np.ndarray | None = None) -> None:
+        """Index rows the table has just appended, as ``route_append``
+        routed them: each piece padded (``core.index.pad_rows``) and applied
+        by ``apply_appended``."""
+        for p in pieces:
+            rows = hix.pad_rows(values[p.lo:p.hi], p.pages,
+                                None if live is None else live[p.lo:p.hi])
+            self.state = apply_appended(self.cfg, self.state, np.int32(p.shard),
+                                        *rows)
+            self.counters.pages_opened += p.opened
+        entries = np.asarray(self.state.shards.num_entries)
+        self.counters.entries_created += sum(
+            int(entries[p.shard]) - p.entries for p in pieces)
 
     def dirty_shards(self) -> np.ndarray:
         """Shard ids owning at least one dirty page (pending vacuum work)."""

@@ -84,7 +84,15 @@ drains shard queues between batches under one of three interleave policies:
                    depth but still accumulates vacuum work)
   manual           drain only on explicit ``flush()``
 
-Queue depth, staged rows, and drain latency land in ``EngineStats``.
+``write_many(values)`` takes a batch of rows in order: under ``sync`` the
+index applies them as one eager Algorithm 3 program per shard touched
+(``insert_batch``) before it returns; under a writer policy they are staged
+in one routed append and the policy's trigger runs once. The call is the
+profiler span ``hippo.write_many`` (arg ``rows``).
+
+Queue depth, staged rows, and drain latency land in ``EngineStats``, with
+the index's insert counters (pages opened, entries created) and the bytes
+sent to the device slabs.
 
 Drift re-summarization (``drift_threshold`` / ``auto_resummarize``): the
 writer's drift telemetry (``core.histogram.DriftTracker``) watches the
@@ -172,6 +180,9 @@ class EngineStats:
     queue_depth: int = 0     # staged tuples pending after the last engine op
     peak_queue_depth: int = 0
     staged_rows: int = 0     # live staged rows currently overlaid into counts
+    insert_pages_opened: int = 0     # pages inserts summarized first
+    insert_entries_created: int = 0  # index entries those pages created
+    slab_upload_bytes: int = 0       # bytes sent to the table's device slabs
     # -- durable persistence (checkpointing + runtime.persister) -------------
     persists: int = 0          # durable commits (full snapshots + deltas)
     persist_pending: int = 0   # background commits queued or in flight
@@ -472,6 +483,7 @@ class QueryEngine:
         self.stats.writes += 1
         if self.writer is None:
             self.index.insert(float(value))
+            self._sync_index_stats()
             return
         self.writer.write(float(value))
         self._maybe_schedule_resummarize()
@@ -479,6 +491,27 @@ class QueryEngine:
                 and self._maintenance_backlog() >= self.drain_depth):
             self._drain(None)
         self._sync_writer_stats()
+
+    def write_many(self, values) -> None:
+        """Insert rows, in order, as ``write`` row by row would. Sync policy
+        applies them through ``index.insert_batch`` before returning; async
+        policies stage them in one routed append to the shard queues, then
+        run the policy's trigger once. Counts include the rows either
+        way."""
+        values = np.asarray(values, np.float32).ravel()
+        with TraceAnnotation("hippo.write_many", rows=values.size):
+            if self.writer is None:
+                self.index.insert_batch(values)
+                self.stats.writes += values.size
+                self._sync_index_stats()
+                return
+            self.writer.write_many(values)
+            self.stats.writes += values.size
+            self._maybe_schedule_resummarize()
+            if (self.drain_policy == "on_depth"
+                    and self._maintenance_backlog() >= self.drain_depth):
+                self._drain(None)
+            self._sync_writer_stats()
 
     def delete(self, lo: float, hi: float) -> int:
         """Delete tuples with key in [lo, hi]. The validity-mask update is
@@ -810,7 +843,15 @@ class QueryEngine:
             self._durable_watermark = 0
         self._start_persister()
 
+    def _sync_index_stats(self) -> None:
+        st = self.stats
+        counters = self.index.counters
+        st.insert_pages_opened = counters.pages_opened
+        st.insert_entries_created = counters.entries_created
+        st.slab_upload_bytes = self.index.table.slab_upload_bytes
+
     def _sync_writer_stats(self) -> None:
+        self._sync_index_stats()
         w = self.writer
         st = self.stats
         if self.journal is not None:
@@ -881,6 +922,7 @@ class QueryEngine:
                 self.stats.slots_filled += len(active)
                 self.stats.pad_slots += self.batch - len(active)
             self.stats.served += len(finished)
+            self._sync_index_stats()     # a search may upload the slabs
             return finished
 
     def _maybe_drain_between_batches(self) -> None:

@@ -127,6 +127,9 @@ class ServeStats:
     backoff_s: float = 0.0  # total restart backoff slept
 
 
+MIN_HANG_S = 0.25   # shortest step the serving supervisor calls a hang
+
+
 class _HungStep(RuntimeError):
     """Internal: a watchdog flag under ``hang_restart`` tears the step down
     through the same restart path a crash takes."""
@@ -151,6 +154,10 @@ def resilient_serve(storage_dir, workload: Callable, *,
     restarts; exhausting the budget re-raises the last failure. An
     ``engine`` may be passed in to adopt a live one for the first step
     (its ``storage_dir`` is still where recovery reads after it dies).
+
+    A step the watchdog flags is a hang only if it also took at least
+    ``MIN_HANG_S``: against a median of milliseconds, one synchronous
+    snapshot or compile is ordinary work, not a hang.
 
     Returns ``(engine, ServeStats)`` with the engine that completed the
     final step still live.
@@ -178,7 +185,7 @@ def resilient_serve(storage_dir, workload: Callable, *,
             stats.steps += 1
             if done:
                 return engine, stats
-            if flagged and hang_restart:
+            if flagged and hang_restart and dt >= MIN_HANG_S:
                 stats.hangs += 1
                 raise _HungStep(
                     f"step {stats.steps - 1} took {dt:.3f}s against a "

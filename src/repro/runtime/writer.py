@@ -25,23 +25,22 @@ Lifecycle (per shard):
            read the validity mask, §5.2 lazy deletes) and kills staged rows
            in range before they ever reach the table.
   drain    between engine batches the writer takes one shard's whole queue,
-           appends its tuples to the table, and applies Algorithm 3 as a
-           single fused ``insert_batch`` against a *copy* of that shard's
-           slice of ``ShardedHippoState``; dirty shards get their §5.2
-           ``vacuum_shard`` the same way. Queues drain in ascending shard
-           order so staged page ids land exactly where stage-time routing
-           predicted.
-  swap     one assignment publishes the rebuilt slice (``set_shard`` + a
-           refreshed summary bitmap) and the table patches just that shard's
-           slab into the cached device view (``refresh_shard_slabs``) — no
-           full (S, PPS, C) re-upload. While the swap is in flight the index
+           appends its tuples to the table (which patches the touched pages
+           into the cached device slabs) and applies Algorithm 3 to them in
+           one program, the same apply as ``insert_batch``
+           (``ShardedHippoIndex.route_append``/``apply_append``); dirty
+           shards get their §5.2 ``vacuum_shard`` the same way. Queues drain
+           in ascending shard order so staged page ids land exactly where
+           stage-time routing predicted.
+  swap     the apply updates the shard's slice of ``ShardedHippoState`` and
+           its summary bitmap in place. While a drain is in flight the index
            refuses queries and maintenance (``swap_in_flight``) instead of
            serving a shard whose state and table disagree.
 
-Failure atomicity: a drain that refuses (slot capacity) rolls the table back
-to its pre-drain snapshot and requeues the shard's staged rows — the overlay
-keeps counts exact, and the error surfaces from ``drain``/``flush``, not from
-a query.
+Failure atomicity: a drain that refuses (slot capacity) does so before the
+table or the index changes, and requeues the shard's staged rows; a failure
+after the append rolls the table back. The overlay keeps counts exact, and
+the error surfaces from ``drain``/``flush``, not from a query.
 
 Drift re-summarization (beyond paper): every staged insert feeds a
 ``histogram.DriftTracker`` (per-bucket hit counters + reservoir sample), so
@@ -101,6 +100,12 @@ class _ShardQueue:
         self.values.append(v)
         self.live.append(True)
         self.n_live += 1
+        self._sorted = None
+
+    def extend(self, values: np.ndarray) -> None:
+        self.values.extend(values.tolist())
+        self.live.extend([True] * values.size)
+        self.n_live += values.size
         self._sorted = None
 
     def kill_range(self, lo: float, hi: float) -> int:
@@ -238,6 +243,38 @@ class MaintenanceWriter:
         self.stats.staged += 1
         self.drift.observe(value)
         return s
+
+    def write_many(self, values: np.ndarray) -> None:
+        """Stage rows in order, as ``write`` row by row would: each row's
+        shard follows from the page it will occupy, so the array splits
+        into one run per shard. The whole array is refused, before anything
+        is staged, if its last row falls past the last slab."""
+        self.index._check_swap_guard()
+        self._check_attached()
+        values = np.asarray(values, np.float32).ravel()
+        if values.size == 0:
+            return
+        spec = self.index.spec
+        pos = self._tail_pos() + self._staged_total + np.arange(values.size)
+        owners = pos // self.index.table.page_card // spec.pages_per_shard
+        if int(owners[-1]) >= spec.num_shards:
+            raise RuntimeError(
+                f"shard layout full: staged tuple would land on page "
+                f"{int(pos[-1]) // self.index.table.page_card}, past shard "
+                f"{spec.num_shards - 1}'s slab "
+                f"(pages_per_shard={spec.pages_per_shard}); rebuild with more "
+                f"shards or larger slabs")
+        if self.journal is not None:
+            for s, v in zip(owners.tolist(), values.tolist()):
+                self.journal.append_insert(s, v)
+        for s in range(int(owners[0]), int(owners[-1]) + 1):
+            lo, hi = np.searchsorted(owners, [s, s + 1])
+            self._queues.setdefault(s, _ShardQueue()).extend(values[lo:hi])
+        self._staged_total += values.size
+        self._version += 1
+        self._dev_cache = None
+        self.stats.staged += values.size
+        self.drift.observe(values)
 
     def delete(self, lo: float, hi: float) -> int:
         """Apply a delete: table tuples in range go invalid now (queries read
@@ -495,72 +532,32 @@ class MaintenanceWriter:
         return dropped
 
     def _drain_shard(self, s: int) -> int:
-        """Drain shard s's queue: append to the table, rebuild a copy of the
-        shard's state slice via Algorithm 3, swap it in atomically."""
+        """Drain shard s's queue: append it to the table and index it with
+        the same apply as ``insert_batch``, under the swap guard."""
         idx = self.index
         table = idx.table
-        spec = idx.spec
         q = self._queues.pop(s)
         values = np.asarray(q.values, np.float32)
+        # dead staged rows occupy their predicted slots but never go live —
+        # they keep later queues' page routing exact
         live = np.asarray(q.live, bool)
         snap_pages, snap_fill = table.num_pages, table.fill
-        was_fresh = table._dev_shard is not None and not table._dev_shard_stale
         idx.swap_in_flight = s
+        appended = False
         try:
-            st = shard_state(idx.state.shards, s)   # working copy (functional)
-            pages = np.empty(values.shape[0], np.int64)
-            offs = np.empty(values.shape[0], np.int64)
-            for i, v in enumerate(values):
-                pages[i], _ = table.insert(float(v))
-                offs[i] = table.fill - 1
-            if pages.size and not (pages // spec.pages_per_shard == s).all():
+            pieces = idx.route_append(values, live)  # refusals: nothing changed
+            if [p.shard for p in pieces] != [s]:
                 raise RuntimeError(
                     f"writer invariant violated: shard {s} drain appended "
                     f"pages outside its slab (was the table mutated behind "
                     f"the staged queues?)")
-            # dead staged rows occupy their predicted slots but never go
-            # live — they keep later queues' page routing exact
-            for p, o in zip(pages[~live], offs[~live]):
-                table.valid[int(p), int(o)] = False
-            lp = (pages - spec.page_lo(s)).astype(np.int32)
-            # Algorithm 3 against the copy: one fused scatter for tuples on
-            # already-summarized pages, padded to a power-of-two width so
-            # drains of different queue depths share one compiled trace ...
-            old = live & (lp <= int(st.summarized_until))
-            if old.any():
-                n = values.shape[0]
-                b = _STAGE_BUCKET_MIN
-                while b < n:
-                    b *= 2
-                pv = np.zeros((b,), np.float32)
-                pl = np.zeros((b,), np.int32)
-                pm = np.zeros((b,), bool)
-                pv[:n] = values
-                pl[:n] = np.clip(lp, 0, spec.pages_per_shard - 1)
-                pm[:n] = old
-                st = hix.insert_batch_existing(idx.cfg, st, jnp.asarray(pv),
-                                               jnp.asarray(pl),
-                                               jnp.asarray(pm))
-            # ... and the eager path for page-opening tuples (few: <= one per
-            # page_card staged rows), capacity-checked against the copy
-            for v, p in zip(values[live & ~old], lp[live & ~old]):
-                opens = int(p) > int(st.summarized_until)
-                if opens or idx.cfg.relocate_on_update:
-                    if int(st.num_slots) + 1 > idx.cfg.max_slots:
-                        raise RuntimeError(
-                            f"shard {s} at slot capacity "
-                            f"({int(st.num_slots)}/{idx.cfg.max_slots}); "
-                            f"rebuild with a larger max_slots")
-                st = hix.insert_tuple(idx.cfg, st, jnp.float32(v),
-                                      jnp.int32(int(p)))
-            # atomic swap: one assignment publishes the rebuilt slice +
-            # refreshed summary; every other shard's arrays are untouched
+            table.append(values, live)
+            appended = True
             crashpoint("drain.pre_swap")
-            idx.state = ShardedHippoState(
-                shards=set_shard(idx.state.shards, s, st),
-                summaries=idx.state.summaries.at[s].set(summary_of(st)))
+            idx.apply_append(pieces, values, live)
         except Exception:
-            table.truncate_to(snap_pages, snap_fill)
+            if appended:
+                table.truncate_to(snap_pages, snap_fill)
             self._queues[s] = q      # rows stay staged; overlay stays exact
             raise
         finally:
@@ -569,9 +566,6 @@ class MaintenanceWriter:
         self._version += 1
         self._dev_cache = None
         self._dirty_since_checkpoint.add(s)
-        if was_fresh:
-            table.refresh_shard_slabs([s], spec.num_shards,
-                                      spec.pages_per_shard)
         applied = int(live.sum())
         idx.counters.inserts += applied
         self.stats.drained_rows += applied

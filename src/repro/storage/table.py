@@ -15,15 +15,51 @@ page space into S contiguous slabs of ``pages_per_shard`` pages each — the
 storage-layout half of the partition layer (``core.partition``). Each shard
 owns the page range [s*PPS, (s+1)*PPS); slab pages past ``num_pages`` are
 zero-key/invalid padding, so per-shard programs are shape-stable while the
-table grows into its slabs.
+table grows into its slabs. An append patches the cached slabs in place at
+the pages it touched (``hippo.slab_append``); the whole view is uploaded
+again only for a new layout or after a delete.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
+
+# Pages per slab patch: an append's page range goes up in blocks of this
+# many pages (the last block overlapping the one before), and a shorter
+# range in one block of the next power of two, so appends of any length
+# reuse a handful of compiled patch programs.
+_PATCH_PAGES = 64
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def _patch_slab(keys, valid, s, page, block_keys, block_valid):
+    """Write a (k, C) block of pages into shard ``s``'s slab at ``page``, in
+    place: the slabs are donated."""
+    with jax.named_scope("hippo.slab_append"):
+        at = (s, page, jnp.int32(0))
+        return (jax.lax.dynamic_update_slice(keys, block_keys[None], at),
+                jax.lax.dynamic_update_slice(valid, block_valid[None], at))
+
+
+def _patch_blocks(lo: int, hi: int, limit: int) -> list[tuple[int, int]]:
+    """(first page, pages) blocks that cover pages [lo, hi) and lie inside
+    [0, limit): ``_PATCH_PAGES``-page blocks, the last one moved back to end
+    at ``hi``; a range shorter than that is one block of the next power of
+    two, moved back where it would pass ``limit``."""
+    n = hi - lo
+    if n <= 0:
+        return []
+    if n < _PATCH_PAGES:
+        size = min(1 << (n - 1).bit_length(), limit)
+        return [(min(lo, limit - size), size)]
+    size = min(_PATCH_PAGES, limit)
+    starts = list(range(lo, hi - size, size)) + [hi - size]
+    return [(a, size) for a in starts]
 
 
 @dataclass
@@ -39,11 +75,15 @@ class PagedTable:
     #                                             (kept incrementally: the engine's
     #                                             on_depth backlog reads it per write)
     payload: dict = field(default_factory=dict)  # name -> (capacity, page_card) array
+    # bytes sent to the sharded device slabs: whole views, shard slabs and
+    # the pages an append patched
+    slab_upload_bytes: int = field(default=0, compare=False)
     _dev: tuple | None = field(default=None, repr=False, compare=False)  # device-view cache
-    _dev_shard: tuple | None = field(default=None, repr=False, compare=False)  # slab-view cache
-    # Mutations mark the slab cache stale instead of dropping it, so a
-    # shard-local writer swap can patch just the touched slabs back in
-    # (``refresh_shard_slabs``) instead of re-uploading every shard.
+    # ((S, PPS), keys, valid): the slab-view cache. Appends patch it in
+    # place; deletes mark it stale, so a shard-local writer can patch just
+    # the touched slabs back in (``refresh_shard_slabs``) instead of
+    # re-uploading every shard.
+    _dev_shard: tuple | None = field(default=None, repr=False, compare=False)
     _dev_shard_stale: bool = field(default=False, repr=False, compare=False)
 
     def __post_init__(self):
@@ -53,6 +93,9 @@ class PagedTable:
             self.valid = np.zeros((self.capacity_pages, self.page_card), bool)
         if self.dirty is None:
             self.dirty = np.zeros((self.capacity_pages,), bool)
+        # appends write through flat views of the row-major page arrays
+        self.keys = np.ascontiguousarray(self.keys)
+        self.valid = np.ascontiguousarray(self.valid)
 
     # -- construction -------------------------------------------------------
 
@@ -85,6 +128,14 @@ class PagedTable:
     def heap_nbytes(self) -> int:
         """Bytes of live table storage (key column only, paper's table size)."""
         return self.num_pages * self.page_card * 4
+
+    @property
+    def tail(self) -> int:
+        """Tuple position (``page * page_card + slot``) the next append
+        takes: the row id it will have."""
+        if self.num_pages == 0:
+            return 0
+        return (self.num_pages - 1) * self.page_card + self.fill
 
     # -- row-id decoding (compact-path result payloads) ----------------------
 
@@ -128,11 +179,13 @@ class PagedTable:
     # -- sharded device views (core.partition slab layout) -------------------
 
     def _shard_views(self, num_shards: int, pages_per_shard: int) -> tuple:
-        """(keys, valid) slabs of shape (S, PPS, page_card), cached like
-        ``_device_views``. Slab pages beyond ``num_pages`` are invalid padding;
-        per-shard entries never cover them, so they cost inspection FLOPs only
-        inside their shard's fixed-shape program."""
-        key = (num_shards, pages_per_shard, self.num_pages)
+        """(keys, valid) slabs of shape (S, PPS, page_card), cached per
+        layout. Slab pages beyond ``num_pages`` are invalid padding;
+        per-shard entries never cover them, so they cost inspection FLOPs
+        only inside their shard's fixed-shape program. Appends inside the
+        layout patch the cache (``append``); a new layout or a stale cache
+        uploads the whole view."""
+        key = (num_shards, pages_per_shard)
         if (self._dev_shard is None or self._dev_shard_stale
                 or self._dev_shard[0] != key):
             total = num_shards * pages_per_shard
@@ -145,47 +198,80 @@ class PagedTable:
             keys[: self.num_pages] = self.keys[: self.num_pages]
             valid[: self.num_pages] = self.valid[: self.num_pages]
             shape = (num_shards, pages_per_shard, self.page_card)
+            self._dev_shard = None          # let the old view go first
             self._dev_shard = (key, jnp.asarray(keys.reshape(shape)),
                                jnp.asarray(valid.reshape(shape)))
             self._dev_shard_stale = False
+            self.slab_upload_bytes += keys.nbytes + valid.nbytes
         return self._dev_shard
 
-    def _host_slab(self, s: int, pages_per_shard: int) -> tuple:
-        """(keys, valid) host copy of shard s's slab, zero/invalid padded."""
-        lo = s * pages_per_shard
-        hi = min(lo + pages_per_shard, self.num_pages)
-        keys = np.zeros((pages_per_shard, self.page_card), np.float32)
-        valid = np.zeros((pages_per_shard, self.page_card), bool)
+    def _host_pages(self, lo: int, n: int) -> tuple:
+        """(keys, valid) host copies of global pages [lo, lo + n), zero/
+        invalid past the table's capacity."""
+        keys = np.zeros((n, self.page_card), np.float32)
+        valid = np.zeros((n, self.page_card), bool)
+        hi = min(lo + n, self.capacity_pages)
         if hi > lo:
             keys[: hi - lo] = self.keys[lo:hi]
             valid[: hi - lo] = self.valid[lo:hi]
         return keys, valid
+
+    def _patch_pages(self, lo: int, hi: int) -> None:
+        """Upload global pages [lo, hi) from host into the cached slabs, in
+        place (``_patch_slab``), block by block within each shard."""
+        layout, keys_dev, valid_dev = self._dev_shard
+        pps = layout[1]
+        self._dev_shard = None        # the slabs are donated below
+        for s in range(lo // pps, (hi - 1) // pps + 1):
+            base = s * pps
+            for a, n in _patch_blocks(max(lo, base) - base,
+                                      min(hi, base + pps) - base, pps):
+                hk, hv = self._host_pages(base + a, n)
+                keys_dev, valid_dev = _patch_slab(
+                    keys_dev, valid_dev, np.int32(s), np.int32(a), hk, hv)
+                self.slab_upload_bytes += hk.nbytes + hv.nbytes
+        self._dev_shard = (layout, keys_dev, valid_dev)
+
+    def _slabs_follow(self, lo: int, hi: int) -> None:
+        """Bring a fresh slab cache up to date after pages [lo, hi) changed
+        on the host: patch them in place, or drop the cache when they fall
+        outside its layout. A stale cache is rebuilt whole on its next
+        use anyway."""
+        self._dev = None
+        if self._dev_shard is None or self._dev_shard_stale or hi <= lo:
+            return
+        shards, pps = self._dev_shard[0]
+        if hi > shards * pps:
+            self._dev_shard = None
+            return
+        self._patch_pages(lo, hi)
 
     def refresh_shard_slabs(self, shard_ids, num_shards: int,
                             pages_per_shard: int) -> bool:
         """Patch a stale slab cache in place after shard-local mutations.
 
         Contract: every mutation since the cache went stale must be confined
-        to the slabs in ``shard_ids`` (the writer's drain/swap guarantees
-        this; ``delete_where`` callers can pass the owners of the pages they
-        dirtied). Each touched slab is re-uploaded with one (PPS, C) H2D
-        instead of rebuilding the whole (S, PPS, C) view. Returns True if the
-        cache was patched; False if there was no compatible cache (the next
-        ``device_*_sharded`` call rebuilds fully — always correct).
+        to the slabs in ``shard_ids`` (``delete_where`` callers pass the
+        owners of the pages they dirtied). Each touched slab is re-uploaded
+        with one (PPS, C) H2D instead of rebuilding the whole (S, PPS, C)
+        view. Returns True if the cache was patched; False if there was no
+        compatible cache (the next ``device_*_sharded`` call rebuilds fully —
+        always correct).
         """
         if self._dev_shard is None:
             return False
-        (cs, cpps, _), keys_dev, valid_dev = self._dev_shard
-        if (cs, cpps) != (num_shards, pages_per_shard):
+        if self._dev_shard[0] != (num_shards, pages_per_shard):
             return False
         if num_shards * pages_per_shard < self.num_pages:
             return False                     # table outgrew the layout
+        (_, pps), keys_dev, valid_dev = self._dev_shard
+        self._dev_shard = None        # the slabs are donated below
         for s in sorted(set(int(s) for s in shard_ids)):
-            hk, hv = self._host_slab(s, pages_per_shard)
-            keys_dev = keys_dev.at[s].set(jnp.asarray(hk))
-            valid_dev = valid_dev.at[s].set(jnp.asarray(hv))
-        key = (num_shards, pages_per_shard, self.num_pages)
-        self._dev_shard = (key, keys_dev, valid_dev)
+            hk, hv = self._host_pages(s * pps, pps)
+            keys_dev, valid_dev = _patch_slab(
+                keys_dev, valid_dev, np.int32(s), np.int32(0), hk, hv)
+            self.slab_upload_bytes += hk.nbytes + hv.nbytes
+        self._dev_shard = ((num_shards, pages_per_shard), keys_dev, valid_dev)
         self._dev_shard_stale = False
         return True
 
@@ -207,33 +293,37 @@ class PagedTable:
         new_page = self.fill == self.page_card or self.num_pages == 0
         return (self.num_pages if new_page else self.num_pages - 1), new_page
 
-    def insert(self, value: float) -> tuple[int, bool]:
-        """Append one tuple; returns (page_id, is_new_page).
+    def append(self, values: np.ndarray, valid: np.ndarray | None = None
+               ) -> tuple[int, int]:
+        """Append tuples at the heap tail, in order; returns the first and
+        last page they touched (an empty range for no values).
 
-        Appends to the last partially-filled page, else opens a new page —
-        matching heap-file append behaviour assumed by Algorithm 3.
-        """
-        _, new_page = self.next_page_id()
-        if new_page:
-            if self.num_pages == self.capacity_pages:
-                self._grow()
-            self.num_pages += 1
-            self.fill = 0
-        p = self.num_pages - 1
-        self.keys[p, self.fill] = np.float32(value)
-        self.valid[p, self.fill] = True
-        self.fill += 1
-        self._dev = None
-        self._dev_shard_stale = True
-        return p, new_page
-
-    def insert_batch(self, values: np.ndarray) -> tuple[int, int]:
-        """Vectorized append; returns (first_page_touched, last_page)."""
+        Each value's page and slot follow from the tail by arithmetic: the
+        last partially-filled page fills first, then new pages open — the
+        heap-file append Algorithm 3 assumes. ``valid`` (default all True)
+        lets a writer place rows that never go live. A fresh slab cache is
+        patched in place at the touched pages."""
         values = np.asarray(values, np.float32).ravel()
-        first = max(self.num_pages - 1, 0)
-        for v in values:  # page-boundary bookkeeping is trivial; keys are bulk-set below
-            self.insert(float(v))
-        return first, self.num_pages - 1
+        if values.size == 0:
+            return self.num_pages, self.num_pages - 1
+        lo = self.tail
+        hi = lo + values.size
+        pages = -(-hi // self.page_card)
+        while pages > self.capacity_pages:
+            self._grow()
+        self.keys.reshape(-1)[lo:hi] = values
+        self.valid.reshape(-1)[lo:hi] = True if valid is None else valid
+        self.num_pages = pages
+        self.fill = hi - (pages - 1) * self.page_card
+        first = lo // self.page_card
+        self._slabs_follow(first, pages)
+        return first, pages - 1
+
+    def insert(self, value: float) -> tuple[int, bool]:
+        """Append one tuple; returns (page_id, is_new_page)."""
+        _, new_page = self.next_page_id()
+        _, page = self.append(np.asarray([value], np.float32))
+        return page, new_page
 
     def delete_where(self, lo: float, hi: float) -> int:
         """Mark tuples with key in [lo, hi] deleted; set page dirty notes."""
@@ -262,6 +352,7 @@ class PagedTable:
         forward of the snapshot position, so clearing that region restores
         the pre-batch table exactly.
         """
+        dropped = self.num_pages
         self.valid[num_pages:] = False
         self.keys[num_pages:] = 0.0
         self.num_dirty -= int(self.dirty[num_pages:].sum())
@@ -271,8 +362,7 @@ class PagedTable:
             self.keys[num_pages - 1, fill:] = 0.0
         self.num_pages = num_pages
         self.fill = fill
-        self._dev = None
-        self._dev_shard_stale = True
+        self._slabs_follow(max(num_pages - 1, 0), dropped)
 
     def _grow(self) -> None:
         add = max(self.capacity_pages // 2, 64)

@@ -27,7 +27,6 @@ import struct
 import numpy as np
 import pytest
 
-import repro.runtime.writer as writer_mod
 from repro.checkpointing.checkpoint import restore_checkpoint, save_checkpoint
 from repro.checkpointing.layout import (CorruptSnapshotError, pack_sections,
                                         read_section_file, unpack_sections,
@@ -229,9 +228,11 @@ def test_crash_mid_drain_pre_swap_recovers_every_acknowledged_write(
         eng.write(v)
     eng.delete(10.0, 12.0)  # journaled delete rides the same recovery
 
-    def boom(shards, s, st):
+    def boom(index, pieces, values, live=None):
         raise _Boom("killed at the swap")
-    monkeypatch.setattr(writer_mod, "set_shard", boom)
+    # the drain has appended its rows to the table; publishing their index
+    # update is the swap
+    monkeypatch.setattr(ShardedHippoIndex, "apply_append", boom)
     with pytest.raises(_Boom):
         eng.flush()
     monkeypatch.undo()

@@ -173,3 +173,42 @@ def test_build_scan_fits_at_sf10(spec):
     _fits(grouping.group_pages.lower(
         spec((PPS, RESOLUTION), jnp.bool_), resolution=RESOLUTION,
         density_threshold=DENSITY))
+
+
+# -- the write path at the refresh deployment's shapes ----------------------
+
+ROWS_SF30, SPARE_PAGES_RF1, RF1_ROWS = 179_998_372, 131_072, 32_728
+PPS_RF1 = -(-(-(-ROWS_SF30 // PAGE_CARD) + SPARE_PAGES_RF1) // SHARDS)
+
+
+def test_batched_insert_fits_at_sf30_rf1(spec):
+    """One round of TPC-H RF1 at SF 30 (32,728 rows, padded to 32,768) into
+    a shard of ``tpch_sf30_shipdate_rf1``'s slabs, in place on the donated
+    stacked state; and the slab patch of one 64-page block."""
+    from repro.core.partition import ShardedHippoState, apply_appended
+    from repro.storage.table import _patch_slab
+    slots = default_max_slots(PPS_RF1)
+    cfg = hix.HippoConfig(resolution=RESOLUTION, density=DENSITY,
+                          page_card=PAGE_CARD, max_slots=slots)
+    lead = (SHARDS,)
+    state = ShardedHippoState(
+        shards=hix.HippoState(
+            bounds=spec(lead + (RESOLUTION + 1,), jnp.float32),
+            bitmaps=spec(lead + (slots, WORDS), jnp.uint32),
+            starts=spec(lead + (slots,), jnp.int32),
+            ends=spec(lead + (slots,), jnp.int32),
+            sorted_order=spec(lead + (slots,), jnp.int32),
+            slot_live=spec(lead + (slots,), jnp.bool_),
+            num_entries=spec(lead, jnp.int32),
+            num_slots=spec(lead, jnp.int32),
+            summarized_until=spec(lead, jnp.int32)),
+        summaries=spec((SHARDS, WORDS), jnp.uint32))
+    n = 1 << (RF1_ROWS - 1).bit_length()
+    _fits(apply_appended.lower(
+        cfg, state, spec((), jnp.int32), spec((n,), jnp.float32),
+        spec((n,), jnp.int32), spec((n,), jnp.bool_)))
+    _fits(_patch_slab.lower(
+        spec((SHARDS, PPS_RF1, PAGE_CARD), jnp.float32),
+        spec((SHARDS, PPS_RF1, PAGE_CARD), jnp.bool_),
+        spec((), jnp.int32), spec((), jnp.int32),
+        spec((64, PAGE_CARD), jnp.float32), spec((64, PAGE_CARD), jnp.bool_)))

@@ -157,3 +157,42 @@ def test_engine_records_its_spans_nested_in_run_batch(index, tmp_path):
     inside = [n for n, a, b, _ in spans if f0 <= a and b <= f1]
     assert sorted(inside) == ["hippo.dispatch", "hippo.fallback",
                               "hippo.readback"]
+
+
+def _scopes(lowered) -> set[str]:
+    text = lowered.as_text(dialect="hlo", debug_info=True)
+    return {s for op in re.findall(r'op_name="([^"]*)"', text)
+            for s in re.findall(r"hippo\.[a-z_]+", op)}
+
+
+def test_insert_programs_name_their_stages(index):
+    """The append's device programs: the batched Algorithm 3 names its two
+    stages, and the slab patch its own."""
+    from repro.core.partition import apply_appended
+    from repro.storage.table import _patch_slab
+    rows = (jnp.zeros((8,), jnp.float32), jnp.zeros((8,), jnp.int32),
+            jnp.ones((8,), bool))
+    assert _scopes(apply_appended.lower(index.cfg, index.state, jnp.int32(3),
+                                        *rows)) == {"hippo.insert_existing",
+                                                    "hippo.insert_pages"}
+    keys, valid = index._slabs()
+    block = (jnp.zeros((2, 50), jnp.float32), jnp.ones((2, 50), bool))
+    assert _scopes(_patch_slab.lower(keys, valid, jnp.int32(0), jnp.int32(4),
+                                     *block)) == {"hippo.slab_append"}
+
+
+@pytest.mark.parametrize("policy", ["sync", "between_batches"])
+def test_engine_records_the_write_many_span(policy, tmp_path):
+    rng = np.random.default_rng(9)
+    table = PagedTable.from_values(
+        rng.uniform(0, 1000, 800).astype(np.float32), page_card=50,
+        spare_pages=40)
+    idx = ShardedHippoIndex.create(table, num_shards=4, resolution=64,
+                                   density=0.2)
+    eng = QueryEngine(idx, batch=8, drain_policy=policy)
+    eng.write_many(rng.uniform(0, 1000, 5))          # compile
+    with jax.profiler.trace(str(tmp_path)):
+        eng.write_many(rng.uniform(0, 1000, 70))
+    spans = _host_spans(tmp_path)
+    assert [(n, st) for n, _, _, st in spans] == [("hippo.write_many",
+                                                   {"rows": 70})]

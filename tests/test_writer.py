@@ -227,20 +227,29 @@ def test_drain_swaps_only_the_drained_shard():
 
 
 def test_drain_patches_slab_cache_in_place():
-    """After a drain the table's sharded device view is patched (fresh, key
-    advanced) rather than left stale for a full (S, PPS, C) rebuild."""
+    """After a drain the table's sharded device view is patched at the
+    appended pages (fresh, same layout) rather than left stale for a full
+    (S, PPS, C) rebuild."""
     rng = np.random.default_rng(31)
     aidx = make_sidx(rng.uniform(0, 100, 200))
     engine = QueryEngine(aidx, batch=4, drain_policy="manual")
     engine.run_all([Predicate.between(0, 50)])  # builds the slab cache
     t = aidx.table
     assert t._dev_shard is not None and not t._dev_shard_stale
+    uploaded = t.slab_upload_bytes
     for v in rng.uniform(0, 100, 10):
         engine.write(float(v))
     engine.flush()
     assert t._dev_shard is not None
     assert not t._dev_shard_stale               # patched, not invalidated
-    assert t._dev_shard[0][2] == t.num_pages    # key tracks the new tail
+    assert t._dev_shard[0] == (aidx.spec.num_shards, aidx.spec.pages_per_shard)
+    # 200 rows fill 25 pages of 8: the 10 rows open pages 25 and 26, and
+    # only those two pages go up (4 B of key and 1 B of validity a slot)
+    assert t.slab_upload_bytes - uploaded == 2 * t.page_card * 5
+    for dev, host in ((t._dev_shard[1], t.keys), (t._dev_shard[2], t.valid)):
+        np.testing.assert_array_equal(
+            np.asarray(dev).reshape(-1, t.page_card)[: t.num_pages],
+            host[: t.num_pages])
     got = engine.run_all([Predicate.between(-1e30, 1e30)])
     assert got[0] == brute_force(t, -1e30, 1e30)
 
