@@ -144,6 +144,12 @@ class HippoIndex:
         compact engine mode's dense-fallback ``max_selected``)."""
         return max(self.table.num_pages, 1)
 
+    def inspects_in_place(self, max_selected: int) -> bool:
+        """Whether ``search_compact_batch`` at this slab width inspects the
+        table where it lies instead of gathering
+        (``core.index.inspects_in_place``)."""
+        return hix.inspects_in_place(max_selected, self.table.num_pages)
+
     # -- maintenance -----------------------------------------------------------
 
     def _require_slot_capacity(self, needed: int = 1) -> None:

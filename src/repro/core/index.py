@@ -113,7 +113,8 @@ class CompactBatchResult(NamedTuple):
     #                               the whole batch (summed over shards)
     pages_gathered: jnp.ndarray   # i32 scalar — selected pages that fit the
     #                               slab, min(union, max_selected) per shard
-    #                               summed (gather-occupancy numerator)
+    #                               summed (gather-occupancy numerator); 0
+    #                               when inspected in place
     row_ids: jnp.ndarray          # (Q, top_k) i32 global row ids in ascending
     #                               order, -1 padded; (Q, 0) when top_k == 0
 
@@ -208,6 +209,25 @@ def owning_slots(state: HippoState, page_ids: np.ndarray) -> np.ndarray:
 
 
 _BLOCK = 128   # pages per block of the page expansion; the TPU's lane width
+# how many times narrower than its table a compact slab must be before
+# gathering it pays, by backend (PERF.md, section 6). On a TPU v5e a
+# gathered page costs ~21 times a page inspected where it lies, and the
+# union's selection a fixed ~25 ms more at SF 30: the two cross at ~1/100
+# of a shard. On a CPU a gathered page costs less than inspecting it where
+# it lies, and the two cross at 0.7-0.85 of a shard. A backend not measured
+# takes 1: only a slab as wide as its table is not gathered.
+_GATHER_SHRINK = {"tpu": 128, "cpu": 1}
+
+
+def inspects_in_place(max_selected: int, num_pages: int) -> bool:
+    """Whether a compact search of slab width ``max_selected`` over a table
+    (or shard) of ``num_pages`` pages inspects the table where it lies
+    instead of gathering the batch union into a slab: true unless the slab
+    is at least ``_GATHER_SHRINK`` times narrower than the table on the
+    default backend, where the program runs. Both are static shapes, so
+    the choice is made at trace time."""
+    shrink = _GATHER_SHRINK.get(jax.default_backend(), 1)
+    return max_selected * shrink >= num_pages
 
 
 def _page_owners(ls: jnp.ndarray, table: jnp.ndarray, num_pages: int
@@ -306,6 +326,18 @@ def _page_match(state: HippoState, query_bitmaps: jnp.ndarray,
     return page_mask, matched
 
 
+def _page_counts(page_mask: jnp.ndarray, keys: jnp.ndarray,
+                 valid: jnp.ndarray, los: jnp.ndarray, his: jnp.ndarray
+                 ) -> jnp.ndarray:
+    """Step 3 of Algorithm 1 for Q intervals: (Q, M) rows of each page that
+    are valid and inside query q's interval, counted only on the pages
+    ``page_mask`` (Q, M) selects for q. keys/valid: (M, C)."""
+    v = keys.astype(jnp.float32)[None]
+    qual = (page_mask[:, :, None] & valid[None]
+            & (v >= los[:, None, None]) & (v <= his[:, None, None]))
+    return qual.sum(axis=2, dtype=jnp.int32)
+
+
 @partial(jax.jit, static_argnames=())
 def search(state: HippoState, query_bitmap: jnp.ndarray, keys: jnp.ndarray,
            valid: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray) -> SearchResult:
@@ -350,10 +382,8 @@ def search_many(state: HippoState, query_bitmaps: jnp.ndarray, keys: jnp.ndarray
     page_mask, matched = _page_match(state, query_bitmaps, num_pages)
     # Step 3, batched: inspect possible qualified pages for every query.
     with jax.named_scope("hippo.inspect"):
-        v = keys.astype(jnp.float32)[None]
-        qualified = (page_mask[:, :, None] & valid[None]
-                     & (v >= los[:, None, None]) & (v <= his[:, None, None]))
-        counts = qualified.sum(axis=(1, 2), dtype=jnp.int32)
+        counts = _page_counts(page_mask, keys, valid, los, his).sum(
+            axis=1, dtype=jnp.int32)
         inspected = page_mask.sum(axis=1, dtype=jnp.int32)
     return BatchSearchResult(
         counts=counts,
@@ -499,6 +529,12 @@ def search_compact_many(state: HippoState, query_bitmaps: jnp.ndarray,
     O(Q x max_selected x C) instead of ``search_many``'s O(Q x P x C),
     i.e. proportional to the batch's selectivity, not the table.
 
+    A slab too wide for the gather to pay (``inspects_in_place``) is not
+    built: every query's interval test runs on ``keys``/``valid`` where
+    they lie, masked by its own page mask. That branch never truncates and
+    gathers nothing (``pages_gathered`` 0); ``bucket_needed`` and
+    ``pages_selected`` still report the union.
+
     Row q's ``counts`` is bit-identical to ``search_many`` whenever
     ``truncated[q]`` is False (pages are gathered in ascending page order and
     inspection is exact). With ``top_k > 0``, ``row_ids[q]`` carries the
@@ -517,64 +553,81 @@ def search_compact_many(state: HippoState, query_bitmaps: jnp.ndarray,
     num_pages = keys.shape[0]
     # Step 2, batched: joint-bucket test + page-range expansion per query.
     page_mask, matched = _page_match(state, query_bitmaps, num_pages)
-    # Union across the batch: one gather serves every query's inspection.
-    with jax.named_scope("hippo.select"):
-        union = jnp.any(page_mask, axis=0)                          # (P,)
-        n_union = union.sum(dtype=jnp.int32)
-        sel = jnp.nonzero(union, size=max_selected, fill_value=num_pages)[0]
-        in_range = sel < num_pages                                  # (M,)
-    with jax.named_scope("hippo.gather"):
-        slab_keys = jnp.where(in_range[:, None],
-                              keys.at[sel].get(mode="fill", fill_value=0.0),
-                              0.0)
-        slab_valid = (valid.at[sel].get(mode="fill", fill_value=False)
-                      & in_range[:, None])
-        # Each query's mask restricted to the gathered slab (filter-match
-        # half of the fused inspect; kernels/compact_inspect is the Pallas
-        # twin).
-        sel_mask = (page_mask.at[:, sel].get(mode="fill", fill_value=False)
-                    & in_range[None, :])                            # (Q, M)
     with jax.named_scope("hippo.inspect"):
-        v = slab_keys.astype(jnp.float32)[None]
-        qual = (sel_mask[:, :, None] & slab_valid[None]
-                & (v >= los[:, None, None]) & (v <= his[:, None, None]))
         pages_inspected = page_mask.sum(axis=1, dtype=jnp.int32)
-        covered = sel_mask.sum(axis=1, dtype=jnp.int32)
-        page_counts = qual.sum(axis=2, dtype=jnp.int32)             # (Q, M)
+    if inspects_in_place(max_selected, num_pages):
+        sel, slab_keys, slab_valid = None, keys, valid
+        with jax.named_scope("hippo.inspect"):
+            n_union = jnp.any(page_mask, axis=0).sum(dtype=jnp.int32)
+            page_counts = _page_counts(page_mask, keys, valid, los, his)
+        truncated = jnp.zeros_like(pages_inspected, bool)
+        gathered = jnp.int32(0)
+    else:
+        # Union across the batch: one gather serves every query's inspection.
+        with jax.named_scope("hippo.select"):
+            union = jnp.any(page_mask, axis=0)                      # (P,)
+            n_union = union.sum(dtype=jnp.int32)
+            sel = jnp.nonzero(union, size=max_selected,
+                              fill_value=num_pages)[0]
+            in_range = sel < num_pages                              # (M,)
+        with jax.named_scope("hippo.gather"):
+            slab_keys = jnp.where(in_range[:, None],
+                                  keys.at[sel].get(mode="fill",
+                                                   fill_value=0.0), 0.0)
+            slab_valid = (valid.at[sel].get(mode="fill", fill_value=False)
+                          & in_range[:, None])
+            # Each query's mask restricted to the gathered slab (filter-
+            # match half of the fused inspect; kernels/compact_inspect is
+            # the Pallas twin).
+            sel_mask = (page_mask.at[:, sel].get(mode="fill",
+                                                 fill_value=False)
+                        & in_range[None, :])                        # (Q, M)
+        with jax.named_scope("hippo.inspect"):
+            page_counts = _page_counts(sel_mask, slab_keys, slab_valid,
+                                       los, his)
+            covered = sel_mask.sum(axis=1, dtype=jnp.int32)
+        truncated = covered < pages_inspected
+        gathered = jnp.minimum(n_union, max_selected)
+    with jax.named_scope("hippo.inspect"):
         counts = page_counts.sum(axis=1, dtype=jnp.int32)
     if top_k:
         with jax.named_scope("hippo.row_ids"):
-            row_ids = _first_row_ids(page_counts, sel, slab_keys, slab_valid,
-                                     los, his, top_k)
+            row_ids = _first_row_ids(page_counts, slab_keys, slab_valid,
+                                     los, his, top_k, sel)
     else:
-        row_ids = jnp.zeros((qual.shape[0], 0), jnp.int32)
+        row_ids = jnp.zeros((page_counts.shape[0], 0), jnp.int32)
     return CompactBatchResult(
         counts=counts,
         pages_inspected=pages_inspected,
         entries_matched=matched,
-        truncated=covered < pages_inspected,
+        truncated=truncated,
         bucket_needed=n_union,
         pages_selected=n_union,
-        pages_gathered=jnp.minimum(n_union, max_selected),
+        pages_gathered=gathered,
         row_ids=row_ids,
     )
 
 
-def _first_row_ids(page_counts, sel, slab_keys, slab_valid, los, his,
-                   top_k: int) -> jnp.ndarray:
+def _first_row_ids(page_counts, keys, valid, los, his, top_k: int,
+                   sel=None) -> jnp.ndarray:
     """First ``top_k`` qualifying global row ids per query, ascending, -1
-    padded: slab order is ascending global row order (``sel`` ascends and
-    slots are row-ordered).
+    padded. ``keys``/``valid`` (M, C) are the gathered slab, whose page m
+    is table page ``sel[m]``, or with ``sel`` None the table itself; either
+    way page order is ascending global row order (``sel`` ascends and slots
+    are row-ordered).
 
     A prefix sum of the per-page qualifying counts finds, for each k, the
-    slab page holding the query's k-th row and its rank inside that page;
-    only those (Q, top_k) pages are re-inspected. Memory is O(Q x M) plus
-    O(Q x top_k x C) — a (Q, M*C) position array is never built. A page
-    holding a qualifying row is one the query selected, so the re-inspection
-    needs no selection mask; invalid slots read as NaN, which fails every
-    interval test (one f32 gather in place of bool gathers).
+    page holding the query's k-th row and its rank inside that page; only
+    those (Q, top_k) pages are read again and re-inspected. Memory is
+    O(Q x M) plus O(Q x top_k x C) — a (Q, M*C) position array is never
+    built. A page holding a qualifying row is one the query selected, so
+    the re-inspection needs no selection mask; its invalid slots read as
+    NaN, which fails every interval test.
     """
-    m, card = slab_keys.shape
+    m, card = keys.shape
+    q = page_counts.shape[0]
+    if m == 0:                        # an empty table holds no row
+        return jnp.full((q, top_k), -1, jnp.int32)
     ks = jnp.arange(top_k, dtype=jnp.int32)
     incl = jnp.cumsum(page_counts, axis=1, dtype=jnp.int32)         # (Q, M)
     # the first page whose inclusive count exceeds k holds the k-th row
@@ -582,13 +635,20 @@ def _first_row_ids(page_counts, sel, slab_keys, slab_valid, los, his,
     found = ks[None, :] < incl[:, -1:]                              # (Q, K)
     page = jnp.minimum(page, m - 1).astype(jnp.int32)
     rank = ks[None, :] - jnp.take_along_axis(incl - page_counts, page, axis=1)
-    live_keys = jnp.where(slab_valid, slab_keys.astype(jnp.float32), jnp.nan)
-    pk = live_keys[page]                                            # (Q, K, C)
+    if sel is None:
+        # in place: the NaN rule on the (Q, K) pages read again, not on the
+        # whole table
+        pk = jnp.where(valid[page], keys[page].astype(jnp.float32), jnp.nan)
+        first = page
+    else:
+        # a gathered slab is narrow: one pass over it, then one f32 gather
+        pk = jnp.where(valid, keys.astype(jnp.float32), jnp.nan)[page]
+        first = sel[page]                                           # (Q, K)
     hit = (pk >= los[:, None, None]) & (pk <= his[:, None, None])
     # slot of the rank-th hit: the number of prefix counts not past rank
     seen = jnp.cumsum(hit, axis=2, dtype=jnp.int32)
     slot = (seen <= rank[:, :, None]).sum(axis=2, dtype=jnp.int32)
-    return jnp.where(found, sel[page] * card + slot, -1)
+    return jnp.where(found, first * card + slot, -1)
 
 
 _I32_PAD = jnp.int32(_INT32_MAX)

@@ -381,10 +381,12 @@ class ShardedHippoIndex:
         page yet, so they appear in counts only, never in row ids, and cannot
         truncate. Row ids are global (``page_id * page_card + slot``) and
         bit-identical to the unsharded gather. The conversion and search
-        dispatches are the profiler span ``hippo.dispatch`` (arg ``bucket``,
-        the slab width ``max_selected``)."""
+        dispatches are the profiler span ``hippo.dispatch`` (args
+        ``bucket``, the slab width ``max_selected``, and ``in_place``, whether
+        the shards are inspected where they lie, ``inspects_in_place``)."""
         self._check_swap_guard()
-        with TraceAnnotation("hippo.dispatch", bucket=max_selected):
+        with TraceAnnotation("hippo.dispatch", bucket=max_selected,
+                             in_place=self.inspects_in_place(max_selected)):
             qbms = self._query_bitmaps(preds)
             los, his = intervals(preds)
             keys, valid = self._slabs()
@@ -402,6 +404,12 @@ class ShardedHippoIndex:
         """Per-shard slab width at which the gather path can never truncate
         (a shard's union is at most its ``pages_per_shard`` slab pages)."""
         return self.spec.pages_per_shard
+
+    def inspects_in_place(self, max_selected: int) -> bool:
+        """Whether ``search_compact_batch`` at this per-shard slab width
+        inspects each shard's slab where it lies instead of gathering
+        (``core.index.inspects_in_place``)."""
+        return hix.inspects_in_place(max_selected, self.spec.pages_per_shard)
 
     def search_batch_shard(self, s: int, preds: list[Predicate]
                            ) -> hix.BatchSearchResult:

@@ -22,7 +22,11 @@ Execution modes (``mode``): the default ``compact`` mode runs the batch
 through the gather path (``search_compact_batch``): the per-query page masks
 are unioned, the union's pages gathered once into a shared slab of
 ``max_selected`` pages, and every query inspected against that slab — so
-inspect cost tracks the batch's selectivity, not the table size. The mode
+inspect cost tracks the batch's selectivity, not the table size. A slab
+too wide for the gather to pay is not built: the index inspects the table
+where it lies (``core.index.inspects_in_place``; counted in
+``EngineStats.compact_in_place``), and such a bucket is dispatched at the
+cap, so one program serves every width inspected in place. The mode
 ladder keeps it exact and trace-stable:
 
   compact    run at the current slab bucket (a power of two, adapted from
@@ -36,11 +40,12 @@ ladder keeps it exact and trace-stable:
              bit-identical to dense mode, never silently short
 
 Compact serving stats land in ``EngineStats``: ``compact_hits`` /
-``compact_fallbacks``, ``gather_occupancy`` (union pages over slab capacity
-dispatched), and ``selected_page_ratio`` (union pages over table pages —
-the fraction of the table the batch actually touched). With ``top_k`` set,
-tickets additionally carry the first ``top_k`` qualifying global row ids
-(``row_ids``; decode via ``PagedTable.row_values``).
+``compact_fallbacks``, ``compact_in_place``, ``gather_occupancy`` (union
+pages over gathered slab capacity), and ``selected_page_ratio`` (union
+pages over table pages — the fraction of the table the batch actually
+touched). With ``top_k`` set, tickets additionally carry the first
+``top_k`` qualifying global row ids (``row_ids``; decode via
+``PagedTable.row_values``).
 
 ``mode="dense"`` is the previous full-table behavior: one (Q, P, C) program
 (or, with ``sharded=True``, the summary-routed per-shard dispatch below).
@@ -167,8 +172,9 @@ class EngineStats:
     compact_batches: int = 0     # batches executed through the gather path
     compact_hits: int = 0        # queries served off the gathered slab
     compact_fallbacks: int = 0   # truncated queries re-run at the dense cap
+    compact_in_place: int = 0    # dispatches inspected in place, fallbacks too
     gather_union_pages: int = 0  # batch-union pages gathered into slabs, cum.
-    gather_slab_pages: int = 0   # slab capacity dispatched, cumulative
+    gather_slab_pages: int = 0   # gathered slab capacity dispatched, cum.
     selected_pages: int = 0      # batch-union pages selected (unclamped), cum.
     table_pages_seen: int = 0    # table pages visible per compact batch, cum.
     # -- async maintenance (runtime.writer) ----------------------------------
@@ -967,6 +973,8 @@ class QueryEngine:
         preds = [t.pred if t is not None else _EMPTY for t in self.slots]
         cap = self.index.gather_cap
         bucket = min(self._compact_bucket, cap)   # never gather past the slab
+        if self.index.inspects_in_place(bucket):
+            bucket = cap        # in place, every width runs the cap's program
         res = self.index.search_compact_batch(preds, max_selected=bucket,
                                               top_k=self.top_k)
         with TraceAnnotation("hippo.readback"):
@@ -978,8 +986,7 @@ class QueryEngine:
         row_ids = res.row_ids.copy() if self.top_k else None
         st = self.stats
         st.compact_batches += 1
-        shards = getattr(self.index, "num_shards", 1)
-        self._account_compact_dispatch(res, bucket * shards)
+        self._account_compact_dispatch(res, bucket)
         needed = int(res.bucket_needed)
         if needed > bucket:
             # adapt: the next batch starts at a slab the last union fits
@@ -1000,7 +1007,7 @@ class QueryEngine:
             # stats overreport exactly when the engine is doing extra work
             st.slots_filled += len(bad)
             st.pad_slots += width - len(bad)
-            self._account_compact_dispatch(fb, cap * shards)
+            self._account_compact_dispatch(fb, cap)
             if bool(fb.truncated[: len(bad)].any()):
                 raise RuntimeError(
                     "compact fallback truncated at the full gather cap — "
@@ -1014,12 +1021,18 @@ class QueryEngine:
         return (counts[active], inspected[active], matched[active],
                 row_ids[active] if row_ids is not None else None)
 
-    def _account_compact_dispatch(self, res, slab_capacity: int) -> None:
-        """Fold one gather dispatch (primary batch or truncation fallback)
-        into the gather telemetry and the pruning-quality window."""
+    def _account_compact_dispatch(self, res, max_selected: int) -> None:
+        """Fold one compact dispatch (primary batch or truncation fallback)
+        at slab width ``max_selected`` into the gather telemetry and the
+        pruning-quality window. A dispatch inspected in place gathers no
+        slab, so it adds no slab capacity."""
         st = self.stats
+        if self.index.inspects_in_place(max_selected):
+            st.compact_in_place += 1
+        else:
+            shards = getattr(self.index, "num_shards", 1)
+            st.gather_slab_pages += max_selected * shards
         st.gather_union_pages += int(res.pages_gathered)
-        st.gather_slab_pages += slab_capacity
         st.selected_pages += int(res.pages_selected)
         st.table_pages_seen += self.index.table.num_pages
         st.window_selected_pages += int(res.pages_selected)

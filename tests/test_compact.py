@@ -13,6 +13,10 @@ loop like the ``shard``/``writer`` suites. Run alone with ``-m compact``.
 import numpy as np
 import pytest
 
+import jax
+import jax.numpy as jnp
+
+from repro.core import histogram as hg
 from repro.core import index as hix
 from repro.core.hippo import HippoIndex
 from repro.core.partition import ShardedHippoIndex
@@ -84,10 +88,14 @@ def test_compact_counts_and_row_ids_match_dense_where_untruncated(dist, num_shar
     want_ids = [brute_ids(idx.table, *p.selectivity_interval())[:TOP_K]
                 for p in preds]
     full = idx.table.num_pages
+    # the narrow caps gather (and may truncate), on both indexes
+    assert not any(t.inspects_in_place(cap) for t in (idx, sidx)
+                   for cap in (4, 32))
     for cap in (4, 32, full):
         res = idx.search_compact_batch(preds, max_selected=cap, top_k=TOP_K)
         trunc = np.asarray(res.truncated)
         counts = np.asarray(res.counts)
+        assert trunc.any() or cap != 4           # the narrowest cap truncates
         assert (counts[~trunc] == want_counts[~trunc]).all(), cap
         assert (counts <= want_counts).all()     # truncation only ever loses
         np.testing.assert_array_equal(np.asarray(res.pages_inspected),
@@ -165,6 +173,130 @@ def test_compact_through_maintenance_and_staged_overlay():
         np.testing.assert_array_equal(
             ids[ids >= 0],
             brute_ids(sidx.table, *p.selectivity_interval())[:TOP_K])
+
+
+# ---------------------------------------------------------------------------
+# Inspection in place: a slab too wide for the gather to pay is not built
+# ---------------------------------------------------------------------------
+
+def _slab_pages(target) -> int:
+    """Pages each compact program inspects: the table's, or a shard's."""
+    spec = getattr(target, "spec", None)
+    return spec.pages_per_shard if spec is not None else target.table.num_pages
+
+
+def _in_place_case(name):
+    """(index, predicates, expected counts) for one in-place case; expected
+    counts come from the dense path, staged-aware where a writer holds
+    rows."""
+    rng = np.random.default_rng({"unsharded": 23, "sharded_spare": 29,
+                                 "staged": 31, "maintained": 37,
+                                 "empty": 41}[name])
+    # 2,000 pages: wide enough that a bucket below the threshold holds
+    # whole queries
+    values = np.sort(rng.uniform(0, 1000, 16000))
+    preds = workload(rng)
+    if name == "empty":
+        hist = hg.build(jnp.asarray(rng.uniform(0, 1000, 256)), 64)
+        idx = HippoIndex.create(
+            PagedTable.from_values(np.zeros(0, np.float32),
+                                   page_card=PAGE_CARD),
+            resolution=64, density=0.25, hist=hist)
+        return idx, preds, np.asarray(idx.search_batch(preds).counts)
+    idx, sidx = make_pair(values, 3 if name == "sharded_spare" else 2)
+    if name == "unsharded":
+        return idx, preds, np.asarray(idx.search_batch(preds).counts)
+    if name == "maintained":
+        for v in rng.uniform(0, 1000, 20):
+            idx.insert(float(v))
+        idx.table.delete_where(200, 260)
+        idx.vacuum()
+        return idx, preds, np.asarray(idx.search_batch(preds).counts)
+    if name == "staged":
+        writer = MaintenanceWriter(sidx)
+        for v in rng.uniform(0, 1000, 15):
+            writer.write(float(v))
+        assert sidx.staging.staged_rows
+    assert sidx.spec.pages_per_shard * 3 > sidx.table.num_pages  # spare slab
+    return sidx, preds, np.asarray(sidx.search_batch(preds).counts)
+
+
+@pytest.mark.parametrize("backend,shrink", [("tpu", 128), ("cpu", 1),
+                                            ("gpu", 1)])
+def test_inspects_in_place_reads_the_backends_crossover(monkeypatch, backend,
+                                                        shrink):
+    """The rule takes the crossover of the backend the program runs on: a
+    slab ``shrink`` times narrower than its table still goes in place, one
+    page narrower gathers. A backend not measured gathers below the cap."""
+    monkeypatch.setattr(hix.jax, "default_backend", lambda: backend)
+    for pages in (1, 1000, 4096):
+        narrowest = -(-pages // shrink)
+        assert hix.inspects_in_place(narrowest, pages)
+        assert not hix.inspects_in_place(narrowest - 1, pages)
+    assert hix.inspects_in_place(0, 0)     # an empty table gathers nothing
+
+
+@pytest.mark.parametrize("shrink", [None, 128])
+@pytest.mark.parametrize("case", ["unsharded", "sharded_spare", "staged",
+                                  "maintained", "empty"])
+def test_in_place_equals_gather_dense_and_brute_force(case, shrink,
+                                                      monkeypatch):
+    """In place, the compact search answers what the gather branch, the
+    dense ``search_many`` and a brute-force scan answer: counts,
+    ``pages_inspected``, ``entries_matched`` and the first ``TOP_K`` row
+    ids. It runs at the first width ``inspects_in_place`` takes and at the
+    cap, and the gather branch one width below that threshold; in place
+    never flags ``truncated`` and gathers no page. With ``shrink`` 128 the
+    TPU's crossover stands in for this backend's, so the in-place program
+    also runs at a slab narrower than its table."""
+    if shrink is not None:
+        monkeypatch.setitem(hix._GATHER_SHRINK, jax.default_backend(), shrink)
+    target, preds, want_counts = _in_place_case(case)
+    want_ids = [brute_ids(target.table, *p.selectivity_interval())[:TOP_K]
+                for p in preds]
+    pages = _slab_pages(target)
+    first = next(m for m in range(1, pages + 2)
+                 if target.inspects_in_place(m))
+    assert all(target.inspects_in_place(m)
+               for m in (first, first + 1, target.gather_cap))
+    dense = target.search_batch(preds)
+    results = {}
+    for m in sorted({first, target.gather_cap}):
+        res = target.search_compact_batch(preds, max_selected=m, top_k=TOP_K)
+        assert not np.asarray(res.truncated).any(), m
+        assert int(res.pages_gathered) == 0, m
+        np.testing.assert_array_equal(np.asarray(res.counts), want_counts)
+        np.testing.assert_array_equal(np.asarray(res.pages_inspected),
+                                      np.asarray(dense.pages_inspected))
+        np.testing.assert_array_equal(np.asarray(res.entries_matched),
+                                      np.asarray(dense.entries_matched))
+        # the batch's union over the table, and its widest part in a shard
+        union = np.asarray(dense.page_mask).any(axis=0)
+        per_shard = [union[s:s + pages].sum()
+                     for s in range(0, union.size, max(pages, 1))]
+        assert int(res.pages_selected) == union.sum()
+        assert int(res.bucket_needed) == max(per_shard, default=0)
+        for q in range(len(preds)):
+            ids = np.asarray(res.row_ids[q])
+            np.testing.assert_array_equal(ids[ids >= 0], want_ids[q], (m, q))
+        results[m] = res
+    if first == 1:          # no width gathers: only the empty table
+        assert case == "empty" and pages == 0
+        return
+    below = first - 1
+    assert not target.inspects_in_place(below)
+    g = target.search_compact_batch(preds, max_selected=below, top_k=TOP_K)
+    trunc = np.asarray(g.truncated)
+    assert (~trunc).any() and int(g.pages_gathered) > 0
+    res = results[first]
+    np.testing.assert_array_equal(np.asarray(g.counts)[~trunc],
+                                  np.asarray(res.counts)[~trunc])
+    np.testing.assert_array_equal(np.asarray(g.row_ids)[~trunc],
+                                  np.asarray(res.row_ids)[~trunc])
+    for name in ("pages_inspected", "entries_matched", "pages_selected",
+                 "bucket_needed"):
+        np.testing.assert_array_equal(np.asarray(getattr(g, name)),
+                                      np.asarray(getattr(res, name)), name)
 
 
 # ---------------------------------------------------------------------------

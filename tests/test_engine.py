@@ -166,7 +166,8 @@ def test_compact_fallback_accounted_in_occupancy_and_gather_stats():
     the engine was doing extra work. A compact_bucket of 1 forces every
     page-selecting query through the fallback."""
     rng = np.random.default_rng(21)
-    idx = make_index(np.sort(rng.uniform(0, 1000, 800)))
+    # 400 pages, so a bucket of 1 gathers (``inspects_in_place``)
+    idx = make_index(np.sort(rng.uniform(0, 1000, 3200)))
     engine = QueryEngine(idx, batch=4, compact_bucket=1)
     preds = [Predicate.between(0, 1000), Predicate.between(100, 900)]
     counts = engine.run_all(preds)
@@ -174,10 +175,13 @@ def test_compact_fallback_accounted_in_occupancy_and_gather_stats():
     np.testing.assert_array_equal(counts, want)      # fallback stays exact
     st = engine.stats
     assert st.compact_fallbacks == 2
-    cap = idx.gather_cap
-    # gather telemetry covers both dispatches: the bucket-1 primary slab and
-    # the fallback's never-truncating cap
-    assert st.gather_slab_pages == 1 + cap
+    # gather telemetry covers both dispatches: the bucket-1 primary slab,
+    # and the fallback at the never-truncating cap, which inspects the table
+    # in place and so adds no gathered slab
+    assert not idx.inspects_in_place(1)
+    assert idx.inspects_in_place(idx.gather_cap)
+    assert st.gather_slab_pages == 1
+    assert st.compact_in_place == 1
     assert st.table_pages_seen == 2 * idx.table.num_pages
     assert st.selected_pages > 0
     assert 0.0 < st.gather_occupancy <= 1.0
@@ -185,6 +189,38 @@ def test_compact_fallback_accounted_in_occupancy_and_gather_stats():
     assert st.slots_filled == 2 + 2                  # primary batch + fallback
     assert st.pad_slots == (4 - 2) + (8 - 2)
     assert st.occupancy == pytest.approx(4 / 12)
+
+
+@pytest.mark.parametrize("bucket,in_place", [(2, False), (4, True)])
+def test_compact_bucket_in_place_is_dispatched_at_the_cap(monkeypatch, bucket,
+                                                          in_place):
+    """A bucket that ``inspects_in_place`` takes runs at the cap, where the
+    slab width changes nothing, so one program serves every such width; a
+    narrower bucket gathers at its own width. The TPU's crossover (a slab
+    1/128 of its table) stands in for this backend's, so on a 400-page
+    table a bucket of 4 goes in place and one of 2 gathers."""
+    import jax
+    monkeypatch.setitem(hix._GATHER_SHRINK, jax.default_backend(), 128)
+    rng = np.random.default_rng(5)
+    idx = make_index(np.sort(rng.uniform(0, 1000, 3200)))     # 400 pages
+    assert idx.inspects_in_place(bucket) == in_place
+    widths, search = [], idx.search_compact_batch
+
+    def spy(preds, max_selected, top_k=0):
+        widths.append(max_selected)
+        return search(preds, max_selected=max_selected, top_k=top_k)
+
+    monkeypatch.setattr(idx, "search_compact_batch", spy)
+    engine = QueryEngine(idx, batch=4, compact_bucket=bucket)
+    preds = [Predicate.between(100, 101), Predicate.between(600, 601)]
+    counts = engine.run_all(preds)
+    np.testing.assert_array_equal(
+        counts, np.asarray(idx.search_batch(preds).counts))
+    st = engine.stats
+    assert widths[0] == (idx.gather_cap if in_place else bucket)
+    assert widths[1:] == [idx.gather_cap] * (len(widths) - 1)   # fallbacks
+    assert st.compact_in_place == len(widths) - (not in_place)
+    assert st.gather_slab_pages == (0 if in_place else bucket)
 
 
 def test_writerless_noop_delete_skips_vacuum():

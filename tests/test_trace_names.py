@@ -25,17 +25,27 @@ from repro.runtime.engine import QueryEngine
 from repro.storage.table import PagedTable
 
 STEP2 = {"hippo.entry_filter", "hippo.page_expand"}
-COMPACT = STEP2 | {"hippo.select", "hippo.gather", "hippo.inspect",
-                   "hippo.row_ids"}
+IN_PLACE = STEP2 | {"hippo.inspect", "hippo.row_ids"}
+COMPACT = IN_PLACE | {"hippo.select", "hippo.gather"}
+ROWS = 64000     # 1,280 pages: shards wide enough for a gathering bucket
 
 
 @pytest.fixture(scope="module")
 def index():
     rng = np.random.default_rng(7)
     table = PagedTable.from_values(
-        rng.uniform(0, 1000, 3200).astype(np.float32), page_card=50)
+        rng.uniform(0, 1000, ROWS).astype(np.float32), page_card=50)
     return ShardedHippoIndex.create(table, num_shards=4, resolution=64,
                                     density=0.2)
+
+
+def _gather_bucket(index) -> int:
+    """The widest slab that the compact search still gathers."""
+    pps = index.spec.pages_per_shard
+    below = max(m for m in range(1, pps + 1)
+                if not index.inspects_in_place(m))
+    assert below >= 2 and index.inspects_in_place(below + 1)
+    return below
 
 
 def _args(index):
@@ -46,25 +56,31 @@ def _args(index):
 
 
 def _lowered(index, name):
+    """The lowered program ``name``; the compact search gathers at the
+    widest gathering bucket, and with ``[in_place]`` runs at the cap."""
     qbms, keys, valid, los, his = _args(index)
     shards = index.state.shards
     staged = (jnp.zeros((4, 8), jnp.float32), jnp.ones((4, 8), bool))
-    if name == "search_compact_many_sharded":
+    width = (index.gather_cap if name.endswith("[in_place]")
+             else _gather_bucket(index))
+    if name.startswith("search_compact_many_sharded_staged"):
+        fn = partial(hix.search_compact_many_sharded_staged,
+                     max_selected=width, top_k=32)
+        return jax.jit(fn).lower(shards, qbms, keys, valid, los, his, *staged)
+    if name.startswith("search_compact_many_sharded"):
         return hix.search_compact_many_sharded.lower(
-            shards, qbms, keys, valid, los, his, max_selected=16, top_k=32)
+            shards, qbms, keys, valid, los, his, max_selected=width,
+            top_k=32)
     if name == "search_many_sharded":
         return hix.search_many_sharded.lower(shards, qbms, keys, valid, los,
                                              his)
-    if name == "search_compact_many_sharded_staged":
-        fn = partial(hix.search_compact_many_sharded_staged, max_selected=16,
-                     top_k=32)
-        return jax.jit(fn).lower(shards, qbms, keys, valid, los, his, *staged)
     return interval_bitmaps_sharded.lower(shards.bounds, los, his,
                                           jnp.ones(los.shape, bool))
 
 
 @pytest.mark.parametrize("name,scopes", [
     ("search_compact_many_sharded", COMPACT),
+    ("search_compact_many_sharded[in_place]", IN_PLACE),
     ("search_many_sharded", STEP2 | {"hippo.inspect"}),
     ("search_compact_many_sharded_staged", COMPACT | {"hippo.staged_overlay"}),
     ("interval_bitmaps_sharded", {"hippo.convert"}),
@@ -74,6 +90,37 @@ def test_lowered_program_names_each_stage_in_op_name(index, name, scopes):
     found = {s for op in re.findall(r'op_name="([^"]*)"', text)
              for s in re.findall(r"hippo\.[a-z_]+", op)}
     assert found == scopes
+
+
+DEFINED = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.-]+)\s*=\s*(\w+)\[([\d,]*)\]")
+GATHER = re.compile(r"=\s*\w+\[([\d,]*)\][^=]*?\bgather\(%?([\w.-]+)")
+
+
+def _gathers(hlo_text: str) -> list[tuple[tuple, tuple]]:
+    """(operand shape, result shape) of every ``gather`` in an HLO module's
+    text, the operand's shape looked up where it is defined."""
+    shapes = {}
+    for line in hlo_text.splitlines():
+        d = DEFINED.match(line)
+        if d:
+            shapes[d.group(1)] = tuple(int(x) for x in d.group(3).split(",")
+                                       if x)
+    dims = lambda t: tuple(int(x) for x in t.split(",") if x)
+    return [(shapes[g.group(2)], dims(g.group(1)))
+            for g in map(GATHER.search, hlo_text.splitlines()) if g]
+
+
+def test_in_place_program_gathers_no_slab(index):
+    """At the cap, the program reads the (S, PPS, C) key and validity slabs
+    where they lie: a gather from them returns only the pages the row ids
+    read again, never a slab's worth of pages."""
+    text = _lowered(index, "search_compact_many_sharded[in_place]").as_text(
+        dialect="hlo")
+    keys, _ = index._slabs()
+    from_slab = [r for a, r in _gathers(text) if a == keys.shape]
+    assert from_slab                          # the row ids read pages again
+    assert all(np.prod(r) < np.prod(keys.shape) for r in from_slab), \
+        from_slab
 
 
 CALLEES = re.compile(r"\b(?:to_apply|condition|body|calls)=%?([\w.-]+)")
@@ -133,12 +180,13 @@ def test_engine_records_its_spans_nested_in_run_batch(index, tmp_path):
     eng = QueryEngine(index, batch=8, mode="compact", top_k=4,
                       compact_bucket=1)
     wide = Predicate.between(0.0, 1000.0)
+    rows = index.table.cardinality
     eng.run_all([wide, Predicate.between(5.0, 9.0)])       # compile
     eng.write(500.0)                  # a staged row: the next batch drains it
     with jax.profiler.trace(str(tmp_path)):
         eng._compact_bucket = 1       # so the wide query truncates again
         counts = eng.run_all([wide, Predicate.between(5.0, 9.0)])
-    assert counts[0] == 3201          # the staged row drained before the batch
+    assert counts[0] == rows + 1      # the staged row drained before the batch
 
     spans = _host_spans(tmp_path)
     names = [s[0] for s in spans]
@@ -151,12 +199,35 @@ def test_engine_records_its_spans_nested_in_run_batch(index, tmp_path):
     args = {n: st for n, _, _, st in spans if n != "hippo.run_batch"}
     assert args["hippo.drain"] == {"units": 1}
     assert args["hippo.fallback"] == {"width": 8}
-    assert sorted(st["bucket"] for n, _, _, st in spans
-                  if n == "hippo.dispatch") == [1, index.gather_cap]
+    # the bucket-1 batch gathers; its fallback at the cap inspects in place
+    assert sorted((st["bucket"], st["in_place"]) for n, _, _, st in spans
+                  if n == "hippo.dispatch") == [(1, False),
+                                                (index.gather_cap, True)]
     (_, f0, f1, _), = [s for s in spans if s[0] == "hippo.fallback"]
     inside = [n for n, a, b, _ in spans if f0 <= a and b <= f1]
     assert sorted(inside) == ["hippo.dispatch", "hippo.fallback",
                               "hippo.readback"]
+
+
+def test_engine_counts_in_place_dispatches(index, tmp_path):
+    """``EngineStats.compact_in_place`` counts every compact dispatch that
+    inspects in place, the primary batch and the truncation fallback
+    alike, as the ``hippo.dispatch`` spans' ``in_place`` argument says."""
+    eng = QueryEngine(index, batch=8, mode="compact", top_k=4,
+                      compact_bucket=1)
+    wide, narrow = Predicate.between(0.0, 1000.0), Predicate.between(5.0, 9.0)
+    eng.run_all([wide, narrow])                            # compile
+    with jax.profiler.trace(str(tmp_path)):
+        eng._compact_bucket = 1
+        eng.run_all([wide, narrow])   # gathers, the fallback in place
+        eng.run_all([wide, narrow])   # at the widened bucket: in place
+    st = eng.stats
+    # 3 batches, 2 of them with one fallback dispatch (of both queries)
+    assert st.compact_batches == 3 and st.compact_fallbacks == 4
+    assert st.compact_in_place == 3
+    flags = [a["in_place"] for n, _, _, a in _host_spans(tmp_path)
+             if n == "hippo.dispatch"]
+    assert sorted(flags) == [False, True, True]
 
 
 def _scopes(lowered) -> set[str]:
