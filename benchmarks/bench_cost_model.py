@@ -28,7 +28,7 @@ def run(card=CARD) -> None:
         sf = 0.001
         lo, hi = tpch.selectivity_window(sf)
         res = idx.search(Predicate.between(lo, hi))
-        measured_prob = int(res.pages_inspected) / idx.table.num_pages
+        measured_prob = int(res.pages_inspected[0]) / idx.table.num_pages
         est_prob = cost.prob_inspect(sf, h, d)
         emit(f"cost_prob_h{h}_d{int(d*100)}", 0.0,
              measured=round(measured_prob, 3), estimated=round(est_prob, 3))

@@ -1,11 +1,13 @@
-"""Batched query-engine throughput: ``search_many`` vs a per-query loop.
+"""Batched query-engine throughput: one search program vs a per-query loop.
 
 The production metric for an index serving many users is queries/second, not
-single-query latency. This benchmark submits Q identical workloads both ways:
+single-query latency. This benchmark submits Q identical workloads three ways:
 
-  loop    — Q separate ``index.search`` dispatches (the seed's only path)
-  batched — one ``search_many`` device program over all Q predicates
-  engine  — ``QueryEngine.run_all`` (batched path + submit/slot bookkeeping)
+  loop    — Q separate ``index.search`` dispatches, a batch of one each
+  batched — one ``core.index.search_compact_many`` device program over all
+            Q predicates at the never-truncating cap
+  engine  — ``QueryEngine.run_all`` (the same program behind the adaptive
+            slab ladder + submit/slot bookkeeping)
 
 Counts are asserted bit-identical between the paths before timing; the
 ``speedup`` derived field is loop_qps vs batched_qps at each Q.
@@ -50,13 +52,15 @@ def run(card: int = CARD, batches=BATCHES) -> None:
         preds = _workload(rng, q)
 
         def loop():
-            return [idx.search(p).count for p in preds]
+            return [idx.search(p).counts[0] for p in preds]
 
         def batched():
             # starts from Predicate objects, like the loop: conversion is paid
             qbms = to_bucket_bitmaps(preds, idx.state.histogram)
             los, his = intervals(preds)
-            return hix.search_many(idx.state, qbms, keys, valid, los, his).counts
+            return hix.search_compact_many(
+                idx.state, qbms, keys, valid, los, his,
+                max_selected=idx.gather_cap).counts
 
         loop_counts = np.asarray(jax.device_get(loop()))
         batch_counts = np.asarray(batched())
@@ -72,10 +76,9 @@ def run(card: int = CARD, batches=BATCHES) -> None:
         emit(f"engine_search_many_q{q}", us_batch, qps=round(qps_batch, 1),
              speedup=round(qps_batch / qps_loop, 2))
 
-        # mode="dense" pins the engine to the same batched program as the
-        # raw path above, so this row isolates submit/slot bookkeeping cost
-        # (the compact default is measured in bench_selectivity_sweep)
-        engine = QueryEngine(idx, batch=q, mode="dense")
+        # the engine's bucket ladder is measured across selectivities in
+        # bench_selectivity_sweep; this row adds submit/slot bookkeeping
+        engine = QueryEngine(idx, batch=q)
         engine.run_all(preds)  # warm the trace before timing
         us_eng = timeit(lambda: engine.run_all(preds), warmup=1, iters=5)
         emit(f"engine_run_all_q{q}", us_eng,

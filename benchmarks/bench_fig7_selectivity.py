@@ -32,7 +32,7 @@ def run(card=CARD) -> None:
         lo, hi = tpch.selectivity_window(sf)
         pred = Predicate.between(lo, hi)
 
-        us_hippo = timeit(lambda: idx.search(pred).count)
+        us_hippo = timeit(lambda: idx.count(pred))
         res = idx.search(pred)
         us_btree = timeit(lambda: bt.count_range(lo, hi))
         us_scan = timeit(lambda: FullScan.search(keys, valid, lo, hi)[0])
@@ -41,10 +41,10 @@ def run(card=CARD) -> None:
         emit(f"fig7_sf{sf:g}", us_hippo,
              qps=round(1e6 / us_hippo, 1),
              btree_us=round(us_btree, 1), scan_us=round(us_scan, 1),
-             pages_inspected=int(res.pages_inspected),
+             pages_inspected=int(res.pages_inspected[0]),
              total_pages=table.num_pages,
-             inspected_frac=round(int(res.pages_inspected) / table.num_pages, 3),
-             model_tuples=round(est), count=int(res.count))
+             inspected_frac=round(int(res.pages_inspected[0]) / table.num_pages, 3),
+             model_tuples=round(est), count=int(res.counts[0]))
 
 
 if __name__ == "__main__":

@@ -26,7 +26,7 @@ def run(card=CARD) -> None:
             resolution=400, density=d), warmup=1, iters=3)
         idx = HippoIndex.create(PagedTable.from_values(li.shipdate, PAGE_CARD),
                                 resolution=400, density=d)
-        us_q = timeit(lambda: idx.search(pred).count)
+        us_q = timeit(lambda: idx.count(pred))
         res = idx.search(pred)
         size = idx.nbytes()
         if base is None:
@@ -35,7 +35,7 @@ def run(card=CARD) -> None:
              qps=round(1e6 / us_q, 1),
              init_us=round(us_init, 1), size_bytes=size,
              size_vs_d20=round(size / base, 3), entries=idx.num_entries,
-             pages_inspected=int(res.pages_inspected),
+             pages_inspected=int(res.pages_inspected[0]),
              total_pages=idx.table.num_pages)
 
 

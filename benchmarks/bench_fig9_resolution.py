@@ -26,13 +26,13 @@ def run(card=CARD) -> None:
             resolution=h, density=0.2), warmup=1, iters=3)
         idx = HippoIndex.create(PagedTable.from_values(li.shipdate, PAGE_CARD),
                                 resolution=h, density=0.2)
-        us_q = timeit(lambda: idx.search(pred).count)
+        us_q = timeit(lambda: idx.count(pred))
         res = idx.search(pred)
         emit(f"fig9_resolution{h}", us_q,
              qps=round(1e6 / us_q, 1),
              init_us=round(us_init, 1), size_bytes=idx.nbytes(),
              rle_bytes=idx.nbytes(compressed=True), entries=idx.num_entries,
-             pages_inspected=int(res.pages_inspected),
+             pages_inspected=int(res.pages_inspected[0]),
              total_pages=idx.table.num_pages)
 
 

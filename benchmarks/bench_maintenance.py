@@ -49,7 +49,7 @@ def run(card=CARD) -> None:
          entries_resummarized=idx3.counters.entries_resummarized,
          total_entries=idx3.num_entries)
     res = idx3.search(Predicate.between(1000.0, 3000.0))
-    emit("maint_vacuum_exact", 0.0, count_after=int(res.count))
+    emit("maint_vacuum_exact", 0.0, count_after=int(res.counts[0]))
 
 
 if __name__ == "__main__":

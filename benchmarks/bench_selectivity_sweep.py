@@ -1,30 +1,30 @@
-"""Selectivity-proportional serving: compact (gather) vs dense engine mode.
+"""Selectivity-proportional serving: the engine's slab bucket vs the cap.
 
-The compact pipeline's claim is that inspect cost tracks what the batch
-*selects*, not the table: the per-query page masks are unioned, the union
-gathered once into a shared slab, and every query inspected against it
-(``core.index.search_compact_many``), while dense mode materializes the full
-(Q, P, C) tensor regardless of selectivity. This sweep serves the same
-hot-spot workload (Q=64 range queries around a handful of popular centers —
-the skewed access pattern of real serving) through both modes of one
-S=4 sharded index at several selectivities:
+The search's claim is that inspect cost tracks what the batch *selects*,
+not the table: the per-query page masks are unioned, the union gathered
+once into a shared slab, and every query inspected against it
+(``core.index.search_compact_many``), while at the cap the whole table is
+inspected in place, a full (Q, P, C) pass regardless of selectivity. This
+sweep serves the same hot-spot workload (Q=64 range queries around a
+handful of popular centers — the skewed access pattern of real serving)
+through two engines over one S=4 sharded index at several selectivities:
 
-  dense    QueryEngine(mode="dense", sharded=False) — the fused full-table
-           (S, Q, PPS, C) program
-  compact  QueryEngine(mode="compact") — the default gather path, adaptive
-           power-of-two slab bucketing + dense fallback on truncation
+  dense    QueryEngine(compact_bucket=gather_cap) — every batch at the
+           never-truncating cap, each shard's (Q, PPS, C) slab in place
+  compact  QueryEngine() — the default: adaptive power-of-two slab
+           bucketing + a fallback at the cap on truncation
 
 The index runs a serving-tuned configuration (H=1600, D=0.01, right-sized
 ``max_slots``): fig8/fig9's density/resolution tradeoff pushed toward query
 speed, so each entry summarizes ~1% of the key domain and
 ``pages_inspected`` actually tracks selectivity (at the paper-default D=0.2
 every query inspects ~20% of the table no matter how narrow it is, and the
-batch union saturates). ``max_slots`` matters for both modes equally: the
+batch union saturates). ``max_slots`` matters for both engines equally: the
 bitmap filter scans every physical slot, so a capacity 40x the live entry
 count would turn the match phase into the floor both paths share.
 
-Counts are asserted bit-identical between the modes at every selectivity
-before timing. The expected trend: the compact mode's q/s advantage widens
+Counts are asserted bit-identical between the engines at every selectivity
+before timing. The expected trend: the compact engine's q/s advantage widens
 as selectivity drops (≥3x at ~1% on CPU; asserted ≥1.5x at the lowest
 selectivity of the sweep) and shrinks toward parity at 50% where the union
 covers the table; ``sel_ratio`` (the engine's measured selected-page ratio)
@@ -85,12 +85,12 @@ def run(card: int = CARD, selectivities=SELECTIVITIES) -> None:
     for sel in selectivities:
         preds = _workload(rng, Q, sel)
 
-        dense = QueryEngine(sidx, batch=Q, mode="dense", sharded=False)
-        compact = QueryEngine(sidx, batch=Q)          # default: compact mode
+        dense = QueryEngine(sidx, batch=Q, compact_bucket=sidx.gather_cap)
+        compact = QueryEngine(sidx, batch=Q)          # default: adaptive
         dense_counts = dense.run_all(preds)           # also warms the traces
         compact_counts = compact.run_all(preds)       # ... and the bucket
         assert (compact_counts == dense_counts).all(), \
-            f"compact counts diverge from dense mode at selectivity {sel}"
+            f"compact counts diverge from the cap at selectivity {sel}"
 
         us_dense = timeit(lambda: dense.run_all(preds), warmup=2, iters=5)
         us_compact = timeit(lambda: compact.run_all(preds), warmup=2, iters=5)
@@ -109,7 +109,7 @@ def run(card: int = CARD, selectivities=SELECTIVITIES) -> None:
 
     lowest = min(speedups)
     assert speedups[lowest] >= ASSERT_MIN_SPEEDUP, (
-        f"compact mode only {speedups[lowest]:.2f}x dense at selectivity "
+        f"compact engine only {speedups[lowest]:.2f}x the cap at selectivity "
         f"{lowest} (need >= {ASSERT_MIN_SPEEDUP}x)")
 
 

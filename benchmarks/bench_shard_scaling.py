@@ -1,17 +1,17 @@
-"""Shard-scaling throughput: the sharded engine vs shard count on one CPU.
+"""Shard-scaling throughput: the sharded engine vs shard count on one device.
 
-The partition layer's CPU win is *work avoidance*, not device parallelism:
-with S shards, the engine's summary routing sends each admitted query only to
-the shards whose bucket unions it can match, so one batch becomes S narrow
-dispatches of ~Q/S queries over P/S pages instead of one Q x P program.
+Every batch runs one program over every shard
+(``core.index.search_compact_many_sharded``): each shard runs Algorithm 1
+over its P/S-page slab, vmapped over the shard axis, and counts reduce
+across it. On one device the shards share its cores, so this measures what
+the shard axis costs or saves in the one program (per-shard slab buckets,
+the reduce), not device parallelism — that needs the shard axis on a mesh.
 Keys are sorted (the time-ordered append workload page grouping itself is
-built for), so page ranges correlate with value ranges and routing is
-selective; on uniform shuffled keys every shard matches every query and
-sharding only helps once shards sit on separate devices.
+built for), so page ranges correlate with value ranges.
 
 Counts are asserted bit-identical between every shard count and the
 unsharded ``HippoIndex`` path before timing. The ``speedup`` field is
-queries/sec vs the S=1 engine (acceptance: S=4 >= 2x S=1 at Q=64).
+queries/sec vs the S=1 engine at Q=64.
 
   PYTHONPATH=src python -m benchmarks.bench_shard_scaling [--quick]
 """
@@ -55,22 +55,18 @@ def run(card: int = CARD, shards=SHARDS) -> None:
         table = PagedTable.from_values(values.copy(), page_card=50)
         sidx = ShardedHippoIndex.create(table, num_shards=s,
                                         resolution=400, density=0.2)
-        # sharded=True pins the summary-routed dispatch this bench measures
-        # (the engine's default mode is now the compact gather path)
-        engine = QueryEngine(sidx, batch=Q, sharded=True)
-        counts = engine.run_all(preds)        # also warms every trace width
+        engine = QueryEngine(sidx, batch=Q)
+        counts = engine.run_all(preds)        # also warms the slab bucket
         assert (counts == want).all(), \
             f"sharded counts diverge from the unsharded path at S={s}"
 
-        us = timeit(lambda: QueryEngine(sidx, batch=Q, sharded=True)
-                    .run_all(preds), warmup=1, iters=3)
+        us = timeit(lambda: engine.run_all(preds), warmup=1, iters=3)
         qps = Q / (us / 1e6)
         if base_qps is None:
             base_qps = qps
         emit(f"shard_scaling_s{s}_q{Q}", us, qps=round(qps, 1),
              speedup=round(qps / base_qps, 2),
-             dispatches=engine.stats.shard_dispatches,
-             pruned=engine.stats.shards_pruned,
+             fallbacks=engine.stats.compact_fallbacks,
              occupancy=round(engine.stats.occupancy, 3))
 
 

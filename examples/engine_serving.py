@@ -4,14 +4,14 @@
 
 Simulates the multi-user serving scenario the engine exists for: a queue of
 mixed-selectivity range queries is admitted into a fixed-slot batch and
-executed one device program per batch (core.index.search_many), then the
-same stream is replayed through the per-query loop to show the throughput
-gap, then through a sharded index (core.partition) where the engine routes
-each batch through per-shard summary bitmaps, then through the default
-compact (gather) mode whose tickets also carry qualifying row ids, and
-finally with writes mixed in: the async maintenance writer (runtime.writer)
-stages inserts/deletes in per-shard queues and drains them between batches,
-with staged rows overlaid into every count. Counts are asserted identical
+executed one device program per batch (core.index.search_compact_many),
+then the same stream is replayed through the per-query loop to show the
+throughput gap, then through a sharded index (core.partition) where each
+batch runs one program over every shard and reduces counts across them,
+with tickets that also carry qualifying row ids, and finally with writes
+mixed in: the async maintenance writer (runtime.writer) stages
+inserts/deletes in per-shard queues and drains them between batches, with
+staged rows overlaid into every count. Counts are asserted identical
 between all paths.
 """
 import time
@@ -29,8 +29,8 @@ def main():
     rng = np.random.default_rng(0)
     card, page_card = 100_000, 50
     # Sorted keys: the time-ordered append workload (think order dates) where
-    # page ranges correlate with value ranges — the case partition pruning
-    # (and Hippo's page grouping itself) is built for.
+    # page ranges correlate with value ranges — the case Hippo's page
+    # grouping is built for.
     values = np.sort(rng.uniform(0, 1_000_000, card))
     table = PagedTable.from_values(values, page_card=page_card)
     idx = HippoIndex.create(table, resolution=400, density=0.2)
@@ -54,9 +54,9 @@ def main():
           f"occupancy {st.occupancy:.0%} "
           f"({st.slots_filled} real / {st.pad_slots} pad slots)")
 
-    idx.search(preds[0])               # warm the scalar trace
+    idx.count(preds[0])                # warm the batch-of-one trace
     t0 = time.perf_counter()
-    loop_counts = np.asarray([int(idx.search(p).count) for p in preds])
+    loop_counts = np.asarray([idx.count(p) for p in preds])
     dt_loop = time.perf_counter() - t0
     print(f"loop:    {len(preds)} queries in {dt_loop*1e3:.1f} ms "
           f"({len(preds)/dt_loop:.0f} q/s)")
@@ -64,39 +64,25 @@ def main():
     assert (counts == loop_counts).all(), "engine must be exact"
     print(f"counts identical across paths; engine speedup {dt_loop/dt_engine:.1f}x")
 
-    # The same stream through a sharded partition layer with the routed
-    # dense dispatch: the engine routes each batch through per-shard summary
-    # bitmaps and reduces counts (mode="dense" + sharded=True).
+    # The same stream through a sharded partition layer: each batch runs one
+    # program over every shard, inspecting the gathered union-of-selected-
+    # pages slab — work proportional to what the batch selects (see
+    # bench_selectivity_sweep for the workload where that wins big; this
+    # broad mixed stream is its worst case) — and reduces counts across
+    # the shard axis.
     t2 = PagedTable.from_values(values, page_card=page_card)
     sidx = ShardedHippoIndex.create(t2, num_shards=4, resolution=400, density=0.2)
-    sharded = QueryEngine(sidx, batch=64, sharded=True)
-    # warm every dispatch-width trace the stream will use (steady state)
-    QueryEngine(sidx, batch=64, sharded=True).run_all(preds)
+    sharded = QueryEngine(sidx, batch=64)
+    sharded.run_all(preds)                 # warm the traces + slab bucket
     t0 = time.perf_counter()
     shard_counts = sharded.run_all(preds)
     dt_shard = time.perf_counter() - t0
     ss = sharded.stats
-    occ = ", ".join(f"s{k}={v:.0%}" for k, v in ss.shard_occupancy().items())
-    print(f"sharded: {len(preds)} queries in {dt_shard*1e3:.1f} ms "
-          f"({len(preds)/dt_shard:.0f} q/s) — {ss.shard_dispatches} shard "
-          f"dispatches, {ss.shards_pruned} pruned; occupancy {occ}")
     assert (shard_counts == loop_counts).all(), "sharded engine must be exact"
-
-    # The default (compact) mode serves the same stream off the gathered
-    # union-of-selected-pages slab — work proportional to what the batch
-    # selects (see bench_selectivity_sweep for the workload where that wins
-    # big; this broad mixed stream is its worst case and stays near parity).
-    compact = QueryEngine(sidx, batch=64)
-    compact.run_all(preds)                 # warm the traces + slab bucket
-    t0 = time.perf_counter()
-    compact_counts = compact.run_all(preds)
-    dt_compact = time.perf_counter() - t0
-    cs = compact.stats
-    assert (compact_counts == loop_counts).all(), "compact engine must be exact"
-    print(f"compact: {len(preds)} queries in {dt_compact*1e3:.1f} ms "
-          f"({len(preds)/dt_compact:.0f} q/s) — selected-page ratio "
-          f"{cs.selected_page_ratio:.0%}, gather occupancy "
-          f"{cs.gather_occupancy:.0%}, {cs.compact_fallbacks} dense fallbacks")
+    print(f"sharded: {len(preds)} queries in {dt_shard*1e3:.1f} ms "
+          f"({len(preds)/dt_shard:.0f} q/s) — selected-page ratio "
+          f"{ss.selected_page_ratio:.0%}, gather occupancy "
+          f"{ss.gather_occupancy:.0%}, {ss.compact_fallbacks} fallbacks")
 
     # With top_k set, tickets also carry qualifying global row ids.
     ids_engine = QueryEngine(sidx, batch=8, top_k=8)
@@ -105,7 +91,7 @@ def main():
     vals = sidx.table.row_values(ticket.row_ids)
     lo, hi = ticket.pred.selectivity_interval()
     assert ((vals >= lo) & (vals <= hi)).all()
-    print(f"compact: ticket qid={ticket.qid} carries {len(ticket.row_ids)} "
+    print(f"row ids: ticket qid={ticket.qid} carries {len(ticket.row_ids)} "
           f"row ids of its {ticket.count} matches, e.g. "
           f"{[int(i) for i in ticket.row_ids[:3]]} -> {np.round(vals[:3], 1)}")
 

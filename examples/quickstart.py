@@ -35,13 +35,13 @@ def main():
     res = idx.search(pred)
     _, mm_pages = mm.search(table.device_keys(), table.device_valid(),
                             500_000.0, 501_000.0)
-    print(f"  hippo: {int(res.count)} rows, inspected "
-          f"{int(res.pages_inspected)}/{table.num_pages} pages "
-          f"({int(res.pages_inspected)/table.num_pages:.1%})")
+    print(f"  hippo: {int(res.counts[0])} rows, inspected "
+          f"{int(res.pages_inspected[0])}/{table.num_pages} pages "
+          f"({int(res.pages_inspected[0])/table.num_pages:.1%})")
     print(f"  minmax (unordered data): inspected {int(mm_pages)}/{table.num_pages} "
           f"pages ({int(mm_pages)/table.num_pages:.1%}) — the §8 failure mode")
     brute = int(((values >= 500_000) & (values <= 501_000)).sum())
-    assert int(res.count) == brute, "Hippo must be exact"
+    assert int(res.counts[0]) == brute, "Hippo must be exact"
     print(f"  exactness check vs brute force: OK ({brute} rows)")
 
     print("\n== INSERT (eager, Algorithm 3) ==")
@@ -50,18 +50,18 @@ def main():
         idx.insert(float(v))
     res2 = idx.search(pred)
     print(f"  inserted 200 tuples; entries {before} -> {idx.num_entries}; "
-          f"query still exact: {int(res2.count)} rows")
+          f"query still exact: {int(res2.counts[0])} rows")
 
     print("\n== DELETE + VACUUM (lazy, §5.2) ==")
     n = table.delete_where(500_000, 501_000)
     res3 = idx.search(pred)     # correct BEFORE any index maintenance
     resum = idx.vacuum()
     res4 = idx.search(pred)
-    print(f"  deleted {n} tuples; pre-vacuum count={int(res3.count)} (exact), "
+    print(f"  deleted {n} tuples; pre-vacuum count={int(res3.counts[0])} (exact), "
           f"vacuum re-summarized {resum}/{idx.num_entries} entries, "
-          f"post-vacuum count={int(res4.count)}")
-    print(f"  pages inspected after vacuum: {int(res4.pages_inspected)} "
-          f"(was {int(res3.pages_inspected)})")
+          f"post-vacuum count={int(res4.counts[0])}")
+    print(f"  pages inspected after vacuum: {int(res4.pages_inspected[0])} "
+          f"(was {int(res3.pages_inspected[0])})")
 
 
 if __name__ == "__main__":

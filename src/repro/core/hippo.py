@@ -3,7 +3,7 @@ DELETE / VACUUM surface (§7.1), wrapping the functional core.
 
     table = PagedTable.from_values(values, page_card=50)
     idx = HippoIndex.create(table, resolution=400, density=0.2)
-    res = idx.search(Predicate.between(1000, 2000))
+    n = idx.count(Predicate.between(1000, 2000))
     idx.insert(1234.0)                  # eager (Algorithm 3)
     table.delete_where(500, 600)        # marks pages dirty
     idx.vacuum()                        # lazy re-summarize (§5.2)
@@ -22,8 +22,7 @@ import jax.numpy as jnp
 
 from repro.core import histogram as hg
 from repro.core import index as hix
-from repro.core.predicate import (Predicate, intervals, to_bucket_bitmap,
-                                  to_bucket_bitmaps)
+from repro.core.predicate import Predicate, intervals, to_bucket_bitmaps
 from repro.storage.table import PagedTable
 
 
@@ -93,44 +92,18 @@ class HippoIndex:
 
     # -- query (Algorithm 1) ---------------------------------------------------
 
-    def search(self, pred: Predicate) -> hix.SearchResult:
-        qbm = to_bucket_bitmap(pred, self.state.histogram)
-        los, his = intervals([pred])
-        return hix.search(self.state, qbm, self.table.device_keys(),
-                          self.table.device_valid(), los[0], his[0])
-
-    def search_batch(self, preds: list[Predicate]) -> hix.BatchSearchResult:
-        """Batched Algorithm 1: Q predicates in one device program.
-
-        Row q of the result equals the corresponding ``search(preds[q])``
-        scalars; see ``runtime.engine.QueryEngine`` for the queued/slotted
-        serving front over this path.
-        """
-        qbms = to_bucket_bitmaps(preds, self.state.histogram)
-        los, his = intervals(preds)
-        return hix.search_many(self.state, qbms, self.table.device_keys(),
-                               self.table.device_valid(), los, his)
-
-    def search_compact(self, pred: Predicate, max_selected: int | None = None):
-        """Gather-path search. Returns (count, pages_inspected, truncated)."""
-        qbm = to_bucket_bitmap(pred, self.state.histogram)
-        if max_selected is None:
-            max_selected = self.table.num_pages
-        los, his = intervals([pred])
-        return hix.search_compact(self.state, qbm, self.table.device_keys(),
-                                  self.table.device_valid(), los[0], his[0],
-                                  max_selected=max_selected)
-
-    def search_compact_batch(self, preds: list[Predicate], *,
-                             max_selected: int, top_k: int = 0
-                             ) -> hix.CompactBatchResult:
-        """Batched gather path: union the batch's page masks, gather once,
-        inspect every predicate against the shared slab
-        (``core.index.search_compact_many``). Counts are bit-identical to
-        ``search_batch`` for rows whose ``truncated`` flag is clear; with
-        ``top_k`` set, rows carry qualifying global row ids
+    def search_batch(self, preds: list[Predicate], *,
+                     max_selected: int | None = None, top_k: int = 0
+                     ) -> hix.CompactBatchResult:
+        """Batched Algorithm 1: Q predicates in one device program
+        (``core.index.search_compact_many``). ``max_selected`` is the slab
+        width; ``None`` means ``gather_cap``, the program that never
+        truncates. With ``top_k`` set, rows carry qualifying global row ids
         (``page_id * page_card + slot``, decode via
-        ``PagedTable.row_values``)."""
+        ``PagedTable.row_values``). See ``runtime.engine.QueryEngine`` for
+        the queued/slotted serving front over this path."""
+        if max_selected is None:
+            max_selected = self.gather_cap
         qbms = to_bucket_bitmaps(preds, self.state.histogram)
         los, his = intervals(preds)
         return hix.search_compact_many(
@@ -138,14 +111,21 @@ class HippoIndex:
             self.table.device_valid(), los, his,
             max_selected=max_selected, top_k=top_k)
 
+    def search(self, pred: Predicate) -> hix.CompactBatchResult:
+        """Single-predicate convenience: a batch of one at the cap."""
+        return self.search_batch([pred])
+
+    def count(self, pred: Predicate) -> int:
+        return int(self.search_batch([pred]).counts[0])
+
     @property
     def gather_cap(self) -> int:
-        """Slab width at which the gather path can never truncate (the
-        compact engine mode's dense-fallback ``max_selected``)."""
+        """Slab width at which the search can never truncate (the engine's
+        fallback ``max_selected``)."""
         return max(self.table.num_pages, 1)
 
     def inspects_in_place(self, max_selected: int) -> bool:
-        """Whether ``search_compact_batch`` at this slab width inspects the
+        """Whether ``search_batch`` at this slab width inspects the
         table where it lies instead of gathering
         (``core.index.inspects_in_place``)."""
         return hix.inspects_in_place(max_selected, self.table.num_pages)

@@ -15,6 +15,14 @@ State layout (fixed-shape device arrays, functional updates):
 Static config (``HippoConfig``) carries H (resolution), D (density threshold),
 page_card, and capacity; it is hashable and passed as a static argument.
 
+Search (Algorithm 1) is one program, ``search_compact_many``: step 2
+(``_page_match``) gives each query's possible-qualified pages, and step 3
+(``_page_counts``) inspects them, either where the table lies or, for a
+slab narrow enough to pay (``inspects_in_place``), in a gathered slab of the
+batch's union. ``search_compact_many_sharded`` runs it over a stacked shard
+axis and reduces across it; ``search_compact_many_sharded_staged`` adds the
+writer's staged rows. All three return a ``CompactBatchResult``.
+
 Out-of-place updates: the paper relocates an updated entry to the end of the
 index when its compressed bitmap no longer fits (§5.1). Fixed-width device
 slots always fit, so relocation is **optional** here (``relocate_on_update``);
@@ -34,7 +42,6 @@ import jax.numpy as jnp
 from repro.core import bitmap as bm
 from repro.core import grouping
 from repro.core.histogram import Histogram, bucketize
-from repro.core.predicate import Predicate, to_bucket_bitmap
 
 _INT32_MAX = np.iinfo(np.int32).max
 
@@ -68,36 +75,17 @@ class HippoState(NamedTuple):
         return Histogram(self.bounds)
 
 
-class SearchResult(NamedTuple):
-    count: jnp.ndarray            # qualified tuple count
-    qualified: jnp.ndarray        # (num_pages, page_card) bool — exact matches
-    page_mask: jnp.ndarray        # (num_pages,) bool — possible qualified pages
-    pages_inspected: jnp.ndarray  # scalar i32 (the paper's I/O metric)
-    entries_matched: jnp.ndarray  # scalar i32
-
-
-class BatchSearchResult(NamedTuple):
-    """Per-query results of ``search_many`` (query axis Q leads).
-
-    ``qualified`` is intentionally omitted: a (Q, P, C) tuple mask is the one
-    output whose memory scales with Q×table size; counts and page masks carry
-    the paper's metrics and the engine's result payload.
-    """
-    counts: jnp.ndarray           # (Q,) i32
-    page_mask: jnp.ndarray        # (Q, num_pages) bool
-    pages_inspected: jnp.ndarray  # (Q,) i32
-    entries_matched: jnp.ndarray  # (Q,) i32
-
-
 class CompactBatchResult(NamedTuple):
-    """Per-query results of the batched gather path (``search_compact_many``).
+    """Per-query results of Algorithm 1 over a batch (``search_compact_many``
+    and its sharded forms, the one search program).
 
     Work after the bitmap filter is proportional to ``max_selected`` gathered
     pages, not to the table — the paper's "read only possible qualified
     pages" cost model on an accelerator. ``truncated`` is exact per query: it
     fires iff one of *that query's* selected pages fell outside the gathered
     slab, in which case ``counts[q]``/``row_ids[q]`` are lower bounds and the
-    caller must fall back to a wider slab or the dense path.
+    caller must fall back to a wider slab (at the cap the table is
+    inspected in place, which never truncates).
     ``pages_inspected``/``entries_matched`` are computed before the gather,
     so they are exact even for truncated rows.
     """
@@ -338,61 +326,6 @@ def _page_counts(page_mask: jnp.ndarray, keys: jnp.ndarray,
     return qual.sum(axis=2, dtype=jnp.int32)
 
 
-@partial(jax.jit, static_argnames=())
-def search(state: HippoState, query_bitmap: jnp.ndarray, keys: jnp.ndarray,
-           valid: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray) -> SearchResult:
-    """Algorithm 1: filter false positives by bitmap AND, inspect the rest.
-
-    keys/valid: (num_pages, page_card) device views of the table.
-    lo/hi: the predicate interval for exact inspection (step 3).
-    """
-    num_pages = keys.shape[0]
-    # Step 2 — bit-level parallel joint-bucket test (Fig. 3).
-    page_mask, matched = _page_match(state, query_bitmap, num_pages)
-    # Step 3 — inspect possible qualified pages tuple-by-tuple (vectorized).
-    with jax.named_scope("hippo.inspect"):
-        v = keys.astype(jnp.float32)
-        qualified = page_mask[:, None] & valid & (v >= lo) & (v <= hi)
-        count = qualified.sum(dtype=jnp.int32)
-        inspected = page_mask.sum(dtype=jnp.int32)
-    return SearchResult(
-        count=count,
-        qualified=qualified,
-        page_mask=page_mask,
-        pages_inspected=inspected,
-        entries_matched=matched,
-    )
-
-
-@partial(jax.jit, static_argnames=())
-def search_many(state: HippoState, query_bitmaps: jnp.ndarray, keys: jnp.ndarray,
-                valid: jnp.ndarray, los: jnp.ndarray, his: jnp.ndarray,
-                ) -> BatchSearchResult:
-    """Algorithm 1 over a batch of Q predicates in one device program.
-
-    query_bitmaps: (Q, W) packed query bitmaps; los/his: (Q,) predicate
-    intervals. The entry-match and page-mask steps of ``search`` gain a
-    leading query axis — one (Q, S) joint-bucket AND, one (Q, P) lookup of
-    each page's owning entry — so Q queries cost one dispatch instead of Q.
-    Row q of every output is bit-identical to the scalars ``search`` returns
-    for predicate q.
-    """
-    num_pages = keys.shape[0]
-    # Step 2, batched: joint-bucket test of every query against every entry.
-    page_mask, matched = _page_match(state, query_bitmaps, num_pages)
-    # Step 3, batched: inspect possible qualified pages for every query.
-    with jax.named_scope("hippo.inspect"):
-        counts = _page_counts(page_mask, keys, valid, los, his).sum(
-            axis=1, dtype=jnp.int32)
-        inspected = page_mask.sum(axis=1, dtype=jnp.int32)
-    return BatchSearchResult(
-        counts=counts,
-        page_mask=page_mask,
-        pages_inspected=inspected,
-        entries_matched=matched,
-    )
-
-
 # Per-shard vmap axes for a stacked ``HippoState``: every array gains a
 # leading shard axis, *including* ``bounds`` — each shard carries its own
 # complete-histogram boundary set so a drift re-summarization can remap one
@@ -405,45 +338,6 @@ SHARD_AXES = HippoState(
 
 
 @partial(jax.jit, static_argnames=())
-def search_many_sharded(shards: HippoState, query_bitmaps: jnp.ndarray,
-                        keys: jnp.ndarray, valid: jnp.ndarray,
-                        los: jnp.ndarray, his: jnp.ndarray) -> BatchSearchResult:
-    """``search_many`` over S shards in one device program, count-reduced.
-
-    ``shards`` is a stacked ``HippoState`` (leading shard axis per
-    ``SHARD_AXES``); keys/valid are (S, PPS, page_card) slabs where shard s
-    owns global pages [s*PPS, (s+1)*PPS) and its entry page ids are local to
-    the slab. ``query_bitmaps`` is (S, Q, W): row s holds the Q predicates
-    converted under shard s's histogram bounds — identical rows while every
-    shard shares one bounds epoch, distinct rows mid-drift-resummarization
-    (the exactness contract is per shard: a shard's page bitmaps and its
-    query bitmaps always share one bucket space). Each shard runs the full
-    Algorithm 1 pipeline over its slab; counts/match-stats reduce by
-    summation over the shard axis — the ``jax.lax.psum`` of a ``shard_map``
-    placement, expressed as an array-axis sum so it is identical under vmap
-    on one device and lowers to an AllReduce when the shard axis is sharded
-    over a mesh ``data`` axis (``launch.shardings.sharded_hippo_shardings``).
-
-    Shards partition the page space, so per-shard exact counts sum to exactly
-    the unsharded count: row q's ``counts`` is bit-identical to
-    ``search_many`` on the unsharded index. ``page_mask`` is returned in
-    global page order, (Q, S*PPS).
-    """
-    per = jax.vmap(search_many,
-                   in_axes=(SHARD_AXES, 0, 0, 0, None, None))(
-        shards, query_bitmaps, keys, valid, los, his)
-    s, q = per.counts.shape
-    pps = keys.shape[1]
-    page_mask = jnp.moveaxis(per.page_mask, 0, 1).reshape(q, s * pps)
-    return BatchSearchResult(
-        counts=per.counts.sum(axis=0),                 # psum over shards
-        page_mask=page_mask,
-        pages_inspected=per.pages_inspected.sum(axis=0),
-        entries_matched=per.entries_matched.sum(axis=0),
-    )
-
-
-@partial(jax.jit, static_argnames=())
 def staged_overlay_counts(staged_vals: jnp.ndarray, staged_live: jnp.ndarray,
                           los: jnp.ndarray, his: jnp.ndarray) -> jnp.ndarray:
     """Exact counts of staged-but-undrained rows per query.
@@ -452,7 +346,7 @@ def staged_overlay_counts(staged_vals: jnp.ndarray, staged_live: jnp.ndarray,
     bucketed width B; staged_live: (S, B) bool (False for pads and for staged
     rows killed by a later delete); los/his: (Q,) f32 predicate intervals.
     Returns (Q,) i32. Staged rows live in no page yet, so this is a plain
-    interval test — the device half of the writer's staging-buffer overlay
+    interval test — the writer's staging-buffer overlay
     (``runtime.writer.MaintenanceWriter``).
     """
     with jax.named_scope("hippo.staged_overlay"):
@@ -460,59 +354,6 @@ def staged_overlay_counts(staged_vals: jnp.ndarray, staged_live: jnp.ndarray,
         hit = (staged_live[None] & (v >= los[:, None, None])
                & (v <= his[:, None, None]))
         return hit.sum(axis=(1, 2), dtype=jnp.int32)
-
-
-def search_many_sharded_staged(shards: HippoState, query_bitmaps: jnp.ndarray,
-                               keys: jnp.ndarray, valid: jnp.ndarray,
-                               los: jnp.ndarray, his: jnp.ndarray,
-                               staged_vals: jnp.ndarray,
-                               staged_live: jnp.ndarray) -> BatchSearchResult:
-    """``search_many_sharded`` plus the staging-buffer overlay.
-
-    ``counts`` gains the staged rows matching each predicate, so results
-    never go stale while inserts wait in the writer's per-shard queues:
-    row q equals what ``search_many_sharded`` would return *after* every
-    staged row drained. ``page_mask``/``pages_inspected``/``entries_matched``
-    are the index-only values — staged rows occupy no page until their drain.
-    """
-    res = search_many_sharded(shards, query_bitmaps, keys, valid, los, his)
-    return res._replace(
-        counts=res.counts + staged_overlay_counts(staged_vals, staged_live,
-                                                  los, his))
-
-
-@partial(jax.jit, static_argnames=("max_selected",))
-def search_compact(state: HippoState, query_bitmap: jnp.ndarray, keys: jnp.ndarray,
-                   valid: jnp.ndarray, lo, hi, max_selected: int):
-    """Gather-then-inspect variant: touches only selected pages (TPU I/O model).
-
-    Work after filtering is proportional to ``max_selected`` pages — the
-    accelerator analogue of "only read possible qualified pages from disk".
-    Returns (count, pages_inspected, truncated); if ``truncated`` is true the
-    selection overflowed ``max_selected`` and the caller must fall back to the
-    dense path (the count would otherwise be incomplete).
-
-    Fill-value contract: the selection pads with ``fill_value=num_pages`` and
-    the gathers run with ``mode="fill"``, so pad rows contribute nothing; a
-    ``max_selected`` of zero would make every row a pad and silently count 0,
-    so it is rejected here (static arg => plain raise at trace time).
-    """
-    if max_selected < 1:
-        raise ValueError(f"max_selected must be >= 1, got {max_selected}")
-    num_pages = keys.shape[0]
-    page_mask, _ = _page_match(state, query_bitmap, num_pages)
-    with jax.named_scope("hippo.select"):
-        n_sel = page_mask.sum(dtype=jnp.int32)
-        sel = jnp.nonzero(page_mask, size=max_selected, fill_value=num_pages)[0]
-        in_range = sel < num_pages
-    with jax.named_scope("hippo.gather"):
-        pk = jnp.where(in_range[:, None],
-                       keys.at[sel].get(mode="fill", fill_value=0.0), 0.0)
-        pv = valid.at[sel].get(mode="fill", fill_value=False) & in_range[:, None]
-    with jax.named_scope("hippo.inspect"):
-        v = pk.astype(jnp.float32)
-        count = (pv & (v >= lo) & (v <= hi)).sum(dtype=jnp.int32)
-    return count, n_sel, n_sel > max_selected
 
 
 @partial(jax.jit, static_argnames=("max_selected", "top_k"))
@@ -526,7 +367,7 @@ def search_compact_many(state: HippoState, query_bitmaps: jnp.ndarray,
     The per-query page masks of Algorithm 1 step 2 are unioned, the union's
     pages are gathered **once** into a ``(max_selected, C)`` slab, and every
     query's interval test runs against that shared slab — so inspect cost is
-    O(Q x max_selected x C) instead of ``search_many``'s O(Q x P x C),
+    O(Q x max_selected x C) instead of the table's O(Q x P x C),
     i.e. proportional to the batch's selectivity, not the table.
 
     A slab too wide for the gather to pay (``inspects_in_place``) is not
@@ -535,16 +376,17 @@ def search_compact_many(state: HippoState, query_bitmaps: jnp.ndarray,
     gathers nothing (``pages_gathered`` 0); ``bucket_needed`` and
     ``pages_selected`` still report the union.
 
-    Row q's ``counts`` is bit-identical to ``search_many`` whenever
+    Row q's ``counts`` is bit-identical to the in-place branch whenever
     ``truncated[q]`` is False (pages are gathered in ascending page order and
     inspection is exact). With ``top_k > 0``, ``row_ids[q]`` carries the
     first ``top_k`` qualifying global row ids (``page_id * C + slot``) in
     ascending order, -1 padded; when ``counts[q] > top_k`` the id list is a
     prefix (callers see the shortfall from the count itself).
 
-    The fill-value contract of ``search_compact`` applies: selection pads
-    with ``num_pages`` and gathers with ``mode="fill"``, so pad rows can
-    never qualify; ``max_selected`` must be >= 1.
+    Fill-value contract: selection pads with ``num_pages`` and gathers
+    with ``mode="fill"``, so pad rows can never qualify; a ``max_selected``
+    of zero would make every row a pad and silently count 0, so it is
+    rejected (static arg => plain raise at trace time).
     """
     if max_selected < 1:
         raise ValueError(f"max_selected must be >= 1, got {max_selected}")
@@ -660,11 +502,23 @@ def search_compact_many_sharded(shards: HippoState, query_bitmaps: jnp.ndarray,
                                 los: jnp.ndarray, his: jnp.ndarray, *,
                                 max_selected: int, top_k: int = 0
                                 ) -> CompactBatchResult:
-    """``search_compact_many`` over S shards, count-reduced like
-    ``search_many_sharded``.
+    """``search_compact_many`` over S shards in one device program,
+    count-reduced.
 
-    ``query_bitmaps`` is (S, Q, W), one conversion per shard bounds epoch
-    (see ``search_many_sharded``). ``max_selected`` is the *per-shard* slab
+    ``shards`` is a stacked ``HippoState`` (leading shard axis per
+    ``SHARD_AXES``); keys/valid are (S, PPS, page_card) slabs where shard s
+    owns global pages [s*PPS, (s+1)*PPS) and its entry page ids are local to
+    the slab. ``query_bitmaps`` is (S, Q, W): row s holds the Q predicates
+    converted under shard s's histogram bounds — identical rows while every
+    shard shares one bounds epoch, distinct rows mid-drift-resummarization
+    (a shard's page bitmaps and its query bitmaps always share one bucket
+    space). The reductions over the shard axis are the ``jax.lax.psum`` of
+    a ``shard_map`` placement, expressed as array-axis sums so they are
+    identical under vmap on one device and lower to an AllReduce when the
+    shard axis is sharded over a mesh ``data`` axis
+    (``launch.shardings.sharded_hippo_shardings``). Shards partition the
+    page space, so per-shard exact counts sum to exactly the unsharded
+    count. ``max_selected`` is the *per-shard* slab
     size (each shard gathers its own union). Counts/pages_inspected/
     entries_matched sum over the shard axis — bit-identical to the unsharded
     gather over the same pages wherever no shard truncated; ``truncated``
@@ -711,12 +565,12 @@ def search_compact_many_sharded_staged(shards: HippoState,
                                        ) -> CompactBatchResult:
     """``search_compact_many_sharded`` plus the staging-buffer overlay.
 
-    The compact twin of ``search_many_sharded_staged``: counts gain the
-    staged rows matching each predicate, so the gather path never goes stale
-    while inserts wait in the writer's queues. Staged rows occupy no page
-    until their drain, so they appear in ``counts`` only — never in
-    ``row_ids``/``pages_inspected`` (exactly as the dense path keeps them out
-    of ``page_mask``) — and they cannot cause truncation.
+    Counts gain the staged rows matching each predicate, so results never
+    go stale while inserts wait in the writer's queues: row q equals what
+    the search would count *after* every staged row drained. Staged rows
+    occupy no page until their drain, so they appear in ``counts`` only —
+    never in ``row_ids``/``pages_inspected``/``entries_matched`` — and they
+    cannot cause truncation.
     """
     res = search_compact_many_sharded(shards, query_bitmaps, keys, valid,
                                       los, his, max_selected=max_selected,

@@ -13,17 +13,15 @@ Hippo structure over its slab:
   routing map     ``ShardSpec``: pure page-id arithmetic mapping any page to
                   its owning shard (the thin analogue of a partition catalog)
   summary bitmap  the union of a shard's live partial-histogram bitmaps —
-                  one (W,) packed bitmap per shard. A query whose bucket
-                  bitmap shares no joint bucket with a shard's summary
-                  (§3.2's test, lifted from entries to shards) cannot match
-                  any entry there, so the shard is skipped outright:
-                  partition pruning with the same no-false-negative guarantee
-                  as the entry-level filter
+                  one (W,) packed bitmap per shard, kept up to date by
+                  inserts and vacuum and stored in snapshots (§3.2's test
+                  lifted from entries to shards); no query reads it
 
-Search runs Algorithm 1 per shard and reduces counts/match-stats across the
-shard axis (``core.index.search_many_sharded``); because shards partition the
-page space and page inspection is exact, per-shard counts sum bit-identically
-to the unsharded count. Maintenance (Algorithm 3 inserts, §5.2 vacuum) routes
+Search runs Algorithm 1 per shard in one device program and reduces counts/
+match-stats across the shard axis (``core.index.search_compact_many_sharded``,
+``ShardedHippoIndex.search_batch``); because shards partition the page space
+and page inspection is exact, per-shard counts sum bit-identically to the
+unsharded count. Maintenance (Algorithm 3 inserts, §5.2 vacuum) routes
 through ``ShardSpec`` and touches exactly one shard's arrays per page — the
 locality that lets shards live on different devices (``launch.shardings``)
 and lets the async writer (``runtime.writer.MaintenanceWriter``) rebuild and
@@ -40,7 +38,7 @@ histogram boundary set (``SHARD_AXES.bounds = 0``), initially identical
 across shards. A drift re-summarization (``runtime.writer``) remaps shards
 onto new bounds one at a time, bumping that shard's entry in
 ``bounds_epochs``; predicates are converted once per distinct epoch and fed
-to the fused search paths as (S, Q, W) per-shard query bitmaps, so every
+to the search program as (S, Q, W) per-shard query bitmaps, so every
 shard's query bitmaps and page bitmaps always share one bucket space —
 counts stay exact before, during, and after a partial re-summarization.
 """
@@ -62,7 +60,7 @@ from repro.core import index as hix
 from repro.core import learned as ln
 from repro.core.hippo import MaintenanceCounters, sample_histogram, sample_keys
 from repro.core.predicate import (Predicate, intervals,
-                                  interval_bitmaps_sharded, to_bucket_bitmaps)
+                                  interval_bitmaps_sharded)
 from repro.storage.table import PagedTable
 
 # Summary-policy ladder: how a boundary set is produced, at build time and at
@@ -139,11 +137,10 @@ def set_shard(shards: hix.HippoState, s, st: hix.HippoState) -> hix.HippoState:
 
 @jax.jit
 def summary_of(st: hix.HippoState) -> jnp.ndarray:
-    """(W,) packed union of a shard's live entry bitmaps (pruning filter).
+    """(W,) packed union of a shard's live entry bitmaps (the summary).
 
     After deletes+vacuum the union can only lose bits, so a cached summary is
-    always a superset of the true union — stale summaries may fail to prune a
-    shard but can never skip a matching one.
+    always a superset of the true union.
     """
     s = st.bitmaps.shape[0]
     live = st.slot_live & (jnp.arange(s) < st.num_slots)
@@ -210,10 +207,9 @@ class ShardedHippoIndex:
     """Shard-parallel counterpart of ``core.hippo.HippoIndex``.
 
     ``cfg.max_slots`` is *per shard*. ``search_batch`` matches
-    ``HippoIndex.search_batch`` in signature and in counts (bit-identical),
-    so ``runtime.engine.QueryEngine`` serves either transparently; its
-    sharded mode additionally uses ``plan_batch``/
-    ``search_batch_shard_arrays`` for summary-pruned per-shard dispatch.
+    ``HippoIndex.search_batch`` in signature and in counts, so
+    ``runtime.engine.QueryEngine`` serves either transparently; only this
+    index takes a ``runtime.writer.MaintenanceWriter``.
     """
     cfg: hix.HippoConfig
     spec: ShardSpec
@@ -347,43 +343,25 @@ class ShardedHippoIndex:
             self.state.shards.bounds, los, his,
             jnp.asarray([not p.empty for p in preds]))
 
-    def search_batch(self, preds: list[Predicate]) -> hix.BatchSearchResult:
-        """Fused (Q, S) path: one device program over every shard, counts
-        reduced across the shard axis. Bit-identical counts to the unsharded
-        ``HippoIndex.search_batch``; with a writer attached, counts also
-        include its staged-but-undrained rows (never-stale contract). The
-        conversion and search dispatches are the profiler span
-        ``hippo.dispatch``."""
-        self._check_swap_guard()
-        with TraceAnnotation("hippo.dispatch"):
-            qbms = self._query_bitmaps(preds)
-            los, his = intervals(preds)
-            keys, valid = self._slabs()
-            if self.staging is not None and self.staging.staged_rows:
-                vals, live = self.staging.device_buffers()
-                res = hix.search_many_sharded_staged(
-                    self.state.shards, qbms, keys, valid, los, his, vals,
-                    live)
-            else:
-                res = hix.search_many_sharded(self.state.shards, qbms, keys,
-                                              valid, los, his)
-        return res._replace(page_mask=res.page_mask[:, : self.table.num_pages])
-
-    def search_compact_batch(self, preds: list[Predicate], *,
-                             max_selected: int, top_k: int = 0
-                             ) -> hix.CompactBatchResult:
-        """Batched gather path over every shard in one device program
-        (``core.index.search_compact_many_sharded``): each shard gathers its
-        own (``max_selected``, C) slab of the batch union and inspects every
-        predicate against it, counts reduced across the shard axis. With a
-        writer attached, the staging-buffer overlay folds into counts exactly
-        as on the dense path (never-stale contract); staged rows occupy no
-        page yet, so they appear in counts only, never in row ids, and cannot
-        truncate. Row ids are global (``page_id * page_card + slot``) and
-        bit-identical to the unsharded gather. The conversion and search
+    def search_batch(self, preds: list[Predicate], *,
+                     max_selected: int | None = None, top_k: int = 0
+                     ) -> hix.CompactBatchResult:
+        """Algorithm 1 for a batch of predicates over every shard in one
+        device program (``core.index.search_compact_many_sharded``), counts
+        reduced across the shard axis. ``max_selected`` is the per-shard
+        slab width; ``None`` means ``gather_cap``, the program that never
+        truncates. Each shard inspects its slab where it lies, or gathers
+        its own (``max_selected``, C) slab of the batch union where that
+        pays (``inspects_in_place``). With a writer attached, the
+        staging-buffer overlay folds into counts (never-stale contract);
+        staged rows occupy no page yet, so they appear in counts only,
+        never in row ids, and cannot truncate. Row ids are global
+        (``page_id * page_card + slot``). The conversion and search
         dispatches are the profiler span ``hippo.dispatch`` (args
         ``bucket``, the slab width ``max_selected``, and ``in_place``, whether
         the shards are inspected where they lie, ``inspects_in_place``)."""
+        if max_selected is None:
+            max_selected = self.gather_cap
         self._check_swap_guard()
         with TraceAnnotation("hippo.dispatch", bucket=max_selected,
                              in_place=self.inspects_in_place(max_selected)):
@@ -406,61 +384,13 @@ class ShardedHippoIndex:
         return self.spec.pages_per_shard
 
     def inspects_in_place(self, max_selected: int) -> bool:
-        """Whether ``search_compact_batch`` at this per-shard slab width
+        """Whether ``search_batch`` at this per-shard slab width
         inspects each shard's slab where it lies instead of gathering
         (``core.index.inspects_in_place``)."""
         return hix.inspects_in_place(max_selected, self.spec.pages_per_shard)
 
-    def search_batch_shard(self, s: int, preds: list[Predicate]
-                           ) -> hix.BatchSearchResult:
-        """Algorithm 1 over one shard's slab only (list-of-predicates form).
-
-        Shapes are identical for every shard, so one compiled trace per batch
-        size serves all S shards. Predicates convert under *this shard's*
-        bounds (shards may serve different epochs mid-resummarization)."""
-        qbms = to_bucket_bitmaps(preds, self.shard_histogram(s))
-        los, his = intervals(preds)
-        return self.search_batch_shard_arrays(s, qbms, los, his)
-
-    def search_batch_shard_arrays(self, s: int, qbms, los, his
-                                  ) -> hix.BatchSearchResult:
-        """Array form of ``search_batch_shard`` for callers that already
-        converted predicates once (``plan_batch``): qbms (Q, W) uint32,
-        los/his (Q,) float32. Counts are index-only — the engine's routed
-        dispatch adds the writer's staging overlay itself (staged rows belong
-        to no entry yet, so summary pruning cannot route them)."""
-        self._check_swap_guard()
-        keys, valid = self._slabs()
-        return hix.search_many(shard_state(self.state.shards, s),
-                               jnp.asarray(qbms), keys[s], valid[s],
-                               jnp.asarray(los), jnp.asarray(his))
-
-    def plan_batch(self, preds: list[Predicate]
-                   ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        """One predicate conversion (per bounds epoch) for a routed batch.
-
-        Returns host arrays (qbms (S, Q, W), los (Q,), his (Q,),
-        match (Q, S)) where ``qbms[s]`` holds the predicates converted under
-        shard s's bounds epoch and ``match[q, s]`` is the joint-bucket test
-        of query q (converted for shard s) against shard s's summary. False
-        entries are provably count-zero for that (query, shard) pair, so a
-        dispatcher may skip them; rows of ``qbms[s]`` slice/pad directly
-        into ``search_batch_shard_arrays`` calls without reconverting the
-        predicates per shard.
-        """
-        self._check_swap_guard()
-        qbms = self._query_bitmaps(preds)                       # (S, Q, W)
-        los, his = intervals(preds)
-        match = np.asarray(bm.any_joint(qbms,
-                                        self.state.summaries[:, None, :])).T
-        return np.asarray(qbms), np.asarray(los), np.asarray(his), match
-
-    def shard_match_matrix(self, preds: list[Predicate]) -> np.ndarray:
-        """(Q, S) bool pruning matrix (see ``plan_batch``)."""
-        return self.plan_batch(preds)[3]
-
-    def search(self, pred: Predicate) -> hix.BatchSearchResult:
-        """Single-predicate convenience: row 0 of a Q=1 fused batch."""
+    def search(self, pred: Predicate) -> hix.CompactBatchResult:
+        """Single-predicate convenience: a batch of one at the cap."""
         return self.search_batch([pred])
 
     def count(self, pred: Predicate) -> int:

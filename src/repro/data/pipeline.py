@@ -43,11 +43,12 @@ class HippoDataPipeline:
     # -- selection (the paper's access path) ---------------------------------
 
     def refresh_selection(self) -> None:
-        res = self.index.search(self.predicate)
-        qual = np.asarray(res.qualified)              # (pages, page_card) bool
-        flat = qual.ravel()[: self.corpus.num_seqs]
-        self.selected_ids = np.flatnonzero(flat)
-        self.pages_inspected = int(res.pages_inspected)
+        n = self.index.count(self.predicate)
+        res = self.index.search_batch([self.predicate],
+                                      top_k=1 << max(n - 1, 0).bit_length())
+        ids = np.asarray(res.row_ids[0])            # ascending, -1 padded
+        self.selected_ids = ids[(ids >= 0) & (ids < self.corpus.num_seqs)]
+        self.pages_inspected = int(res.pages_inspected[0])
         if self.selected_ids.size == 0:
             raise ValueError("predicate selects no sequences")
 

@@ -60,7 +60,8 @@ def batch_filter_sharded_kernel(queries: jnp.ndarray, entries: jnp.ndarray,
                                 *, interpret: bool = False) -> jnp.ndarray:
     """Shard-axis extension of ``batch_filter_kernel``: the grid gains a
     leading shard dimension so one fused launch covers every (query, shard,
-    entry) tile — the match phase of ``core.index.search_many_sharded``.
+    entry) tile — the match phase of
+    ``core.index.search_compact_many_sharded``.
 
     queries: (Q, W) uint32 (Q % BLOCK_Q == 0), shared across shards;
     entries: (S, E, W) uint32 (E % BLOCK_E == 0, W % 128 == 0), one entry

@@ -1,4 +1,7 @@
-"""batch_filter public wrappers — the fused §3.2 match phase of the engine.
+"""batch_filter public wrappers — a Pallas form of the §3.2 match phase of
+the one search program (the entry filter of ``core.index._page_match``).
+The search itself runs the jnp form; these kernels are checked against it
+but are not on the served path.
 
 Shapes/dtypes: ``batch_filter(queries (Q, W) uint32, entries (E, W) uint32)
 -> (Q, E) int32 0/1`` — joint-bucket test of every query bitmap against
@@ -14,7 +17,7 @@ never a silent fallback). ``ref.py`` holds the jnp reference twin.
 
 Equivalence contract: the sharded form is the unsharded form vmapped over
 the shard axis — ``batch_filter_sharded(q, e)[s] == batch_filter(q, e[s])``
-bit-exactly, which is what lets ``core.index.search_many_sharded`` reduce
+bit-exactly, as ``core.index.search_compact_many_sharded`` needs to reduce
 per-shard results into the unsharded answer.
 """
 from __future__ import annotations

@@ -9,7 +9,7 @@ the gathered slab width (``max_selected`` pages of the batch's union,
 pages its bitmap filter could not rule out. ``counts[q].sum()`` is query
 q's qualifying-tuple count over the slab — bit-identical to the compact
 search's count for untruncated queries, which is the kernel-level statement
-of the compact/dense equivalence contract.
+of the gathered/in-place equivalence contract.
 
 The wrapper pads M to the kernel block (padded slab pages carry valid=False
 and sel_mask=False), Q to the query block (padded queries carry the empty

@@ -189,8 +189,9 @@ def sharded_hippo_shardings(mesh, state):
     (divisibility-fitted, degrading to replication like every other rule
     here) — including the per-shard histogram ``bounds``, which gained a
     shard axis with the drift-resummarization layer. Under this placement
-    the shard-axis sums in ``core.index.search_many_sharded`` lower to the
-    cross-device AllReduce — the ``jax.lax.psum`` of the count-reduce engine.
+    the shard-axis sums in ``core.index.search_compact_many_sharded`` lower
+    to the cross-device AllReduce — the ``jax.lax.psum`` of the count-reduce
+    engine.
     """
     from repro.core import index as hix
     from repro.core.partition import ShardedHippoState
@@ -215,7 +216,7 @@ def place_sharded(mesh, state, keys, valid):
     """device_put a ``ShardedHippoState`` + its table slabs onto the mesh.
 
     Returns (state, keys, valid) with every shard-axis array placed over the
-    ``data`` axis; pass them straight to ``search_many_sharded``.
+    ``data`` axis; pass them straight to ``search_compact_many_sharded``.
     """
     st = jax.device_put(state, sharded_hippo_shardings(mesh, state))
     k = jax.device_put(keys, shard_slab_shardings(mesh, keys))

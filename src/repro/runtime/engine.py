@@ -2,7 +2,7 @@
 
 Mirrors ``launch/serve.py``'s lock-step batch server: queries arrive as
 ``Predicate``s, get admitted into a fixed number of slots, execute together in
-one device program (``core.index.search_many``), and finished queries free
+one device program (``index.search_batch``), and finished queries free
 their slot for the next queued request. The fixed slot count keeps every
 ``run_batch`` at one stable jit shape — (batch, W) bitmaps, (batch,) interval
 bounds — so the trace is compiled once and recycled for the life of the engine.
@@ -18,16 +18,19 @@ the query analogue of a recycled decode slot idling on a pad token. Pads are
 tracked separately (``EngineStats.pad_slots``) and never counted as served
 work; ``EngineStats.occupancy`` is real queries over dispatched slots.
 
-Execution modes (``mode``): the default ``compact`` mode runs the batch
-through the gather path (``search_compact_batch``): the per-query page masks
-are unioned, the union's pages gathered once into a shared slab of
-``max_selected`` pages, and every query inspected against that slab — so
-inspect cost tracks the batch's selectivity, not the table size. A slab
-too wide for the gather to pay is not built: the index inspects the table
-where it lies (``core.index.inspects_in_place``; counted in
+One query path: every batch runs ``index.search_batch`` — Algorithm 1 in
+one device program (``core.index.search_compact_many`` on a ``HippoIndex``,
+``search_compact_many_sharded`` over every shard of a
+``core.partition.ShardedHippoIndex``, counts reduced across the shard axis).
+The per-query page masks are unioned and, where a slab of ``max_selected``
+pages is narrow enough to pay, the union's pages are gathered once into it
+and every query inspected against that slab — so inspect cost tracks the
+batch's selectivity, not the table size. A slab too wide for the gather to
+pay is not built: the index inspects the table where it lies
+(``core.index.inspects_in_place``; counted in
 ``EngineStats.compact_in_place``), and such a bucket is dispatched at the
-cap, so one program serves every width inspected in place. The mode
-ladder keeps it exact and trace-stable:
+cap, so one program serves every width inspected in place. The ladder
+keeps it exact and trace-stable:
 
   compact    run at the current slab bucket (a power of two, adapted from
              the batches seen so far, so traces are reused)
@@ -36,41 +39,22 @@ ladder keeps it exact and trace-stable:
              truncate) for subsequent batches
   fallback   queries whose own pages overflowed *this* batch's slab
              (per-query ``truncated`` flag) re-run at the never-truncating
-             cap — dense-cost, still row-id-capable — so results are always
-             bit-identical to dense mode, never silently short
+             cap, still row-id-capable — so results are never silently
+             short
 
-Compact serving stats land in ``EngineStats``: ``compact_hits`` /
+Serving stats land in ``EngineStats``: ``compact_hits`` /
 ``compact_fallbacks``, ``compact_in_place``, ``gather_occupancy`` (union
 pages over gathered slab capacity), and ``selected_page_ratio`` (union
 pages over table pages — the fraction of the table the batch actually
 touched). With ``top_k`` set, tickets additionally carry the first
 ``top_k`` qualifying global row ids (``row_ids``; decode via
-``PagedTable.row_values``).
-
-``mode="dense"`` is the previous full-table behavior: one (Q, P, C) program
-(or, with ``sharded=True``, the summary-routed per-shard dispatch below).
-
-Sharded routed dispatch (``mode="dense"`` + ``sharded=True`` on a
-``core.partition.ShardedHippoIndex``): the admitted batch is
-routed through the per-shard summary bitmaps — a (batch, S) joint-bucket
-test — and each shard receives one dispatch carrying only the queries whose
-summaries match it, padded to a small bucket width so every shard reuses the
-same compiled traces. Shards no admitted query can match are skipped
-entirely (partition pruning), and per-query counts are reduced across the
-dispatched shards on the way out. Per-shard occupancy lands in
-``EngineStats.shard_queries`` / ``shard_slots``. In compact mode a sharded
-index instead runs the fused sharded gather (every shard gathers its own
-slab of the batch union; counts reduce across the shard axis), and the
-writer's staging overlay folds into counts on either path.
+``PagedTable.row_values``). The writer's staging overlay folds into counts
+inside the index's search.
 
 Shapes/dtypes on the dispatch boundary: predicates convert once per batch to
-(Q, W) uint32 packed bucket bitmaps plus (Q,) float32 interval bounds; dense
-mode runs one (Q=batch)-wide program, sharded mode runs per-shard programs at
-bucketed widths, compact mode one (Q=batch, max_selected)-slab program.
-Equivalence contract: for the same predicate stream, dense mode on
-``HippoIndex``, dense mode on ``ShardedHippoIndex`` (fused (Q, S)
-count-reduce), the summary-routed sharded dispatch, and compact mode on
-either index all return bit-identical counts.
+(Q, W) uint32 packed bucket bitmaps ((S, Q, W) on a sharded index, one row
+per shard bounds epoch) plus (Q,) float32 interval bounds, and each batch
+is one (Q=batch, max_selected)-slab program.
 
 Writes (``runtime.writer.MaintenanceWriter``): ``write()``/``delete()``
 stage maintenance instead of running Algorithm 3 on the query path; staged
@@ -116,7 +100,7 @@ from __future__ import annotations
 
 import threading
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import jax
 import numpy as np
@@ -124,7 +108,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.core.partition import SUMMARY_POLICIES
 from repro.core.predicate import Predicate
-from repro.runtime.writer import MaintenanceWriter
+from repro.runtime.writer import MaintenanceWriter, takes_writer
 
 _EMPTY = Predicate(lo=1.0, hi=0.0)   # lo > hi: matches nothing
 
@@ -135,16 +119,15 @@ def _pow2_at_least(n: int) -> int:
         p *= 2
     return p
 
-_SHARD_BUCKET_MIN = 8     # smallest per-shard dispatch width (trace bucketing)
 _COMPACT_BUCKET_MIN = 64  # smallest gather-slab width (trace bucketing)
-_FALLBACK_Q_MIN = 8       # smallest dense-fallback query width
+_FALLBACK_Q_MIN = 8       # smallest fallback query width
 
 
 @dataclass
 class QueryTicket:
     """One submitted predicate and, once its batch ran, its results.
 
-    ``row_ids`` is filled only by the compact mode with ``top_k`` set: the
+    ``row_ids`` is filled only with the engine's ``top_k`` set: the
     first ``top_k`` qualifying global row ids in ascending order (pads
     stripped; ``count`` tells the caller whether the list is a prefix).
     """
@@ -164,14 +147,10 @@ class EngineStats:
     batches: int = 0
     slots_filled: int = 0    # real query-slots dispatched (never _EMPTY pads)
     pad_slots: int = 0       # _EMPTY pads dispatched alongside them
-    shard_dispatches: int = 0          # per-shard programs run (sharded mode)
-    shards_pruned: int = 0             # shard dispatches skipped via summaries
-    shard_queries: dict = field(default_factory=dict)  # shard -> real queries
-    shard_slots: dict = field(default_factory=dict)    # shard -> slots incl. pads
-    # -- compact mode (gather path) ------------------------------------------
-    compact_batches: int = 0     # batches executed through the gather path
-    compact_hits: int = 0        # queries served off the gathered slab
-    compact_fallbacks: int = 0   # truncated queries re-run at the dense cap
+    # -- search ladder (compact slab, fallback) -------------------------------
+    compact_batches: int = 0     # batches executed
+    compact_hits: int = 0        # queries served by their batch's dispatch
+    compact_fallbacks: int = 0   # truncated queries re-run at the cap
     compact_in_place: int = 0    # dispatches inspected in place, fallbacks too
     gather_union_pages: int = 0  # batch-union pages gathered into slabs, cum.
     gather_slab_pages: int = 0   # gathered slab capacity dispatched, cum.
@@ -206,24 +185,15 @@ class EngineStats:
 
     @property
     def occupancy(self) -> float:
-        """Fraction of *dispatched* slots that carried a real query.
-
-        Dense mode dispatches the full batch width, so pads are the free
-        batch slots; sharded mode dispatches per-shard bucketed widths, so
-        pads are the bucket roundings (a query dispatched to several shards
-        fills one slot in each)."""
+        """Fraction of *dispatched* slots that carried a real query: pads
+        are the free batch slots and the fallback's width roundings."""
         total = self.slots_filled + self.pad_slots
         return self.slots_filled / total if total else 0.0
-
-    def shard_occupancy(self) -> dict[int, float]:
-        """Per-shard occupancy of the sharded dispatch path."""
-        return {s: self.shard_queries[s] / self.shard_slots[s]
-                for s in sorted(self.shard_slots) if self.shard_slots[s]}
 
     @property
     def gather_occupancy(self) -> float:
         """Fraction of dispatched gather-slab capacity holding a selected
-        page (compact mode). Low occupancy means the adaptive bucket is
+        page. Low occupancy means the adaptive bucket is
         oversized for the workload; 1.0 means batches run at the edge of
         their bucket."""
         return (self.gather_union_pages / self.gather_slab_pages
@@ -231,9 +201,8 @@ class EngineStats:
 
     @property
     def selected_page_ratio(self) -> float:
-        """Batch-union pages over table pages across compact batches — the
-        fraction of the table the batches selected (the dense path's
-        denominator is always 1.0). Uses the unclamped union, so a
+        """Batch-union pages over table pages across batches — the
+        fraction of the table the batches selected. Uses the unclamped union, so a
         truncating batch reports what it *selected*, not the slab-capped
         subset it managed to gather (that is ``gather_occupancy``'s job)."""
         return (self.selected_pages / self.table_pages_seen
@@ -241,7 +210,7 @@ class EngineStats:
 
     @property
     def pruning_after_resummarize(self) -> float:
-        """Selected-page ratio of the compact batches since the last
+        """Selected-page ratio of the batches since the last
         re-summarization was scheduled (the whole run, if none was) — the
         "after" half of the pruning-quality pair; lower is better pruning."""
         return (self.window_selected_pages / self.window_table_pages
@@ -250,35 +219,23 @@ class EngineStats:
 
 _DRAIN_POLICIES = ("sync", "between_batches", "on_depth", "manual")
 
-_MODES = ("auto", "compact", "dense")
-
 
 class QueryEngine:
     """Lock-step batched query executor with slot recycling.
 
-    ``mode`` selects the execution path (see module docstring): ``compact``
-    (the default via ``auto``) serves batches off the gathered
-    union-of-selected-pages slab with adaptive power-of-two bucketing and a
-    per-query dense fallback on truncation; ``dense`` is the full-table
-    path. ``auto`` resolves to ``dense`` when ``sharded=True`` is requested
-    explicitly (routed dispatch is a dense-mode feature) and to ``compact``
-    otherwise.
+    Every batch runs the one search program (see module docstring) with
+    adaptive power-of-two slab bucketing and a per-query fallback at the
+    cap on truncation. ``mode`` accepts only ``"compact"``, that path.
 
-    ``sharded`` selects the summary-routed per-shard dispatch of dense mode;
-    under ``mode="dense"`` it defaults on whenever the index exposes the
-    partition-layer routing surface (``plan_batch`` /
-    ``search_batch_shard_arrays``). Compact mode on a sharded index runs the
-    fused sharded gather instead.
-
-    ``top_k`` (compact mode only) makes every ticket carry up to ``top_k``
-    qualifying global row ids; ``compact_bucket`` seeds the adaptive slab
-    bucket (rounded up to a power of two, adapted upward as batches reveal
-    their union sizes).
+    ``top_k`` makes every ticket carry up to ``top_k`` qualifying global
+    row ids; ``compact_bucket`` seeds the adaptive slab bucket (rounded up
+    to a power of two, adapted upward as batches reveal their union sizes).
 
     ``drain_policy`` selects the maintenance interleave (see module
-    docstring); the default is ``between_batches`` when the index supports a
-    writer and ``sync`` otherwise. ``drain_units`` bounds the shard
-    queues/vacuums applied per batch under ``between_batches``;
+    docstring); the default is ``between_batches`` when the index takes a
+    writer (``runtime.writer.takes_writer``) and ``sync`` otherwise.
+    ``drain_units`` bounds the shard queues/vacuums applied per batch under
+    ``between_batches``;
     ``drain_depth`` is the ``on_depth`` trigger (staged tuples + dirty
     pages, checked on writes and deletes alike).
 
@@ -301,11 +258,11 @@ class QueryEngine:
     ``learned_fallbacks`` report which path the schedules actually took.
     """
 
-    def __init__(self, index, batch: int = 64, sharded: bool | None = None,
+    def __init__(self, index, batch: int = 64,
                  drain_policy: str | None = None, drain_units: int = 1,
                  drain_depth: int = 256,
                  writer: MaintenanceWriter | None = None,
-                 mode: str = "auto", top_k: int = 0,
+                 mode: str = "compact", top_k: int = 0,
                  compact_bucket: int | None = None,
                  drift_threshold: float | None = 0.25,
                  auto_resummarize: bool = True,
@@ -320,41 +277,18 @@ class QueryEngine:
             raise ValueError(f"batch must be >= 1, got {batch}")
         self.index = index
         self.batch = batch
-        if mode not in _MODES:
-            raise ValueError(f"mode must be one of {_MODES}, got {mode!r}")
-        if mode == "auto":
-            mode = "dense" if sharded is True else "compact"
-        if mode == "compact":
-            if sharded is True:
-                raise ValueError(
-                    "sharded=True selects dense mode's routed dispatch; "
-                    "compact mode runs the fused sharded gather — pass "
-                    "mode='dense' for routing or drop sharded=True")
-            if not hasattr(index, "search_compact_batch"):
-                raise ValueError(
-                    "mode='compact' needs an index with the gather surface "
-                    "(search_compact_batch/gather_cap); got "
-                    f"{type(index).__name__}")
-            sharded = False
-        else:
-            if sharded is None:
-                sharded = hasattr(index, "plan_batch")
-            if sharded and not hasattr(index, "plan_batch"):
-                raise ValueError("sharded=True needs a ShardedHippoIndex-style "
-                                 "index (plan_batch/search_batch_shard_arrays)")
+        if mode != "compact":
+            raise ValueError(f"mode must be 'compact' (the one query path), "
+                             f"got {mode!r}")
         self.mode = mode
-        self.sharded = sharded
         if top_k < 0:
             raise ValueError(f"top_k must be >= 0, got {top_k}")
-        if top_k and mode != "compact":
-            raise ValueError("row-id payloads (top_k > 0) ride the gather "
-                             "path; they need mode='compact'")
         self.top_k = top_k
         if compact_bucket is not None and compact_bucket < 1:
             raise ValueError(f"compact_bucket must be >= 1, got {compact_bucket}")
         self._compact_bucket = _pow2_at_least(compact_bucket
                                               or _COMPACT_BUCKET_MIN)
-        supports_writer = hasattr(index, "plan_batch")
+        supports_writer = takes_writer(index)
         if drain_policy is None:
             drain_policy = "between_batches" if supports_writer else "sync"
         if drain_policy not in _DRAIN_POLICIES:
@@ -362,7 +296,7 @@ class QueryEngine:
                              f"got {drain_policy!r}")
         if drain_policy != "sync" and not supports_writer:
             raise ValueError(
-                "async drain policies need a ShardedHippoIndex-style index "
+                "async drain policies need a ShardedHippoIndex "
                 "(per-shard queues route by ShardSpec); use "
                 "drain_policy='sync' for an unsharded index")
         self.drain_policy = drain_policy
@@ -880,8 +814,8 @@ class QueryEngine:
     # -- execution ------------------------------------------------------------
 
     def run_batch(self) -> list[QueryTicket]:
-        """Admit queued queries into free slots and execute one device program
-        (or, in sharded mode, one summary-routed dispatch per matched shard).
+        """Admit queued queries into free slots and execute one device
+        program (plus, for truncated queries, one fallback at the cap).
 
         Returns the tickets retired by this batch (empty if nothing pending).
         The call is the profiler span ``hippo.run_batch`` (args ``batch``,
@@ -900,14 +834,8 @@ class QueryEngine:
             span.set_metadata(active=len(active))
             if not active:
                 return []
-            row_ids = None
-            if self.mode == "compact":
-                counts, inspected, matched, row_ids = \
-                    self._execute_compact(active)
-            elif self.sharded:
-                counts, inspected, matched = self._execute_sharded(active)
-            else:
-                counts, inspected, matched = self._execute_dense(active)
+            counts, inspected, matched, row_ids = \
+                self._execute_compact(active)
             finished = []
             for k, i in enumerate(active):
                 t = self.slots[i]
@@ -921,12 +849,8 @@ class QueryEngine:
                 finished.append(t)
                 self.slots[i] = None          # recycle the slot
             self.stats.batches += 1
-            if not self.sharded:
-                # compact and dense modes dispatch the full batch width;
-                # routed dispatch accounting happens per shard inside
-                # _execute_sharded
-                self.stats.slots_filled += len(active)
-                self.stats.pad_slots += self.batch - len(active)
+            self.stats.slots_filled += len(active)
+            self.stats.pad_slots += self.batch - len(active)
             self.stats.served += len(finished)
             self._sync_index_stats()     # a search may upload the slabs
             return finished
@@ -948,16 +872,6 @@ class QueryEngine:
             self._auto_drain_suspended = True
             raise
 
-    def _execute_dense(self, active: list[int]) -> tuple:
-        """One full-width device program; pads fill the free slots."""
-        preds = [t.pred if t is not None else _EMPTY for t in self.slots]
-        res = self.index.search_batch(preds)
-        with TraceAnnotation("hippo.readback"):
-            counts = np.asarray(res.counts)[active]
-            inspected = np.asarray(res.pages_inspected)[active]
-            matched = np.asarray(res.entries_matched)[active]
-        return counts, inspected, matched
-
     def _execute_compact(self, active: list[int]) -> tuple:
         """The compact mode ladder: gather-path batch at the current slab
         bucket, widen the bucket for future batches when the union overflows
@@ -975,8 +889,8 @@ class QueryEngine:
         bucket = min(self._compact_bucket, cap)   # never gather past the slab
         if self.index.inspects_in_place(bucket):
             bucket = cap        # in place, every width runs the cap's program
-        res = self.index.search_compact_batch(preds, max_selected=bucket,
-                                              top_k=self.top_k)
+        res = self.index.search_batch(preds, max_selected=bucket,
+                                      top_k=self.top_k)
         with TraceAnnotation("hippo.readback"):
             res = jax.device_get(res)
         counts = res.counts.copy()
@@ -998,7 +912,7 @@ class QueryEngine:
             fb_preds = [self.slots[i].pred for i in bad]
             fb_preds += [_EMPTY] * (width - len(bad))
             with TraceAnnotation("hippo.fallback", width=width):
-                fb = self.index.search_compact_batch(
+                fb = self.index.search_batch(
                     fb_preds, max_selected=cap, top_k=self.top_k)
                 with TraceAnnotation("hippo.readback"):
                     fb = jax.device_get(fb)
@@ -1037,60 +951,6 @@ class QueryEngine:
         st.table_pages_seen += self.index.table.num_pages
         st.window_selected_pages += int(res.pages_selected)
         st.window_table_pages += self.index.table.num_pages
-
-    def _execute_sharded(self, active: list[int]) -> tuple:
-        """Per-shard dispatch with summary pruning and count-reduce.
-
-        Each shard runs a program over only the active queries whose bucket
-        bitmaps share a joint bucket with its summary — padded up to a bucket
-        width so all shards share compiled traces — and per-query results sum
-        across shards (shards partition the page space, so the reduction is
-        exact; a pruned (query, shard) pair is provably count-zero). The
-        predicates are converted to bucket bitmaps once per shard bounds
-        epoch (``plan_batch`` returns (S, Q, W)); per-shard dispatches slice
-        and pad shard s's converted rows, with zero bitmaps + (lo=1, hi=0)
-        intervals as the pads.
-        """
-        preds = [self.slots[i].pred for i in active]
-        qbms, los, his, match = self.index.plan_batch(preds)
-        a = len(active)
-        counts = np.zeros((a,), np.int64)
-        inspected = np.zeros((a,), np.int64)
-        matched = np.zeros((a,), np.int64)
-        for s in range(self.index.num_shards):
-            hit = np.flatnonzero(match[:, s])
-            if hit.size == 0:
-                self.stats.shards_pruned += 1
-                continue
-            width = _pow2_at_least(max(int(hit.size), _SHARD_BUCKET_MIN))
-            qb = np.zeros((width, qbms.shape[2]), qbms.dtype)
-            qb[: hit.size] = qbms[s, hit]       # shard s's epoch conversion
-            lo = np.full((width,), _EMPTY.lo, np.float32)
-            hi = np.full((width,), _EMPTY.hi, np.float32)
-            lo[: hit.size] = los[hit]
-            hi[: hit.size] = his[hit]
-            res = self.index.search_batch_shard_arrays(s, qb, lo, hi)
-            with TraceAnnotation("hippo.readback"):
-                counts[hit] += np.asarray(res.counts)[: hit.size]
-                inspected[hit] += np.asarray(res.pages_inspected)[: hit.size]
-                matched[hit] += np.asarray(res.entries_matched)[: hit.size]
-            self.stats.shard_dispatches += 1
-            self.stats.slots_filled += int(hit.size)
-            self.stats.pad_slots += width - int(hit.size)
-            self.stats.shard_queries[s] = (
-                self.stats.shard_queries.get(s, 0) + int(hit.size))
-            self.stats.shard_slots[s] = (
-                self.stats.shard_slots.get(s, 0) + width)
-        # Staging overlay: rows waiting in a writer's queues belong to no
-        # index entry yet, so summary routing can't see them — their counts
-        # add on top, independent of which shards were dispatched or pruned.
-        # Read the overlay from the index's *attached* writer (the single
-        # source of truth), not this engine's handle: a sync-policy engine,
-        # or one whose writer was superseded, must still see staged rows.
-        staging = getattr(self.index, "staging", None)
-        if staging is not None and staging.staged_rows:
-            counts += staging.staged_counts(los, his).sum(axis=1)
-        return counts, inspected, matched
 
     def drain(self) -> list[QueryTicket]:
         """Run batches until the queue and all slots are empty."""

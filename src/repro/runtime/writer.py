@@ -15,12 +15,12 @@ Lifecycle (per shard):
            owning shard's pending queue — a small staging buffer kept in
            table-append order, with a sorted view for overlay counting.
            Nothing touches the device index; staging is a host list append.
-  overlay  queries stay exact while rows wait: ``search_batch``, the compact
-           gather path (``search_compact_batch``), and the engine's routed
-           dispatch all add the staged rows matching each predicate on top
-           of the index counts — the never-stale contract. Staged rows
-           occupy no page until their drain, so they appear in counts only,
-           never in the compact path's row ids (nor in ``page_mask``).
+  overlay  queries stay exact while rows wait: the index's
+           ``search_batch`` hands the staged rows to the search program
+           (``device_buffers``; ``core.index.staged_overlay_counts``), which
+           adds those matching each predicate on top of the index counts —
+           the never-stale contract. Staged rows occupy no page until their
+           drain, so they appear in counts only, never in row ids.
            ``delete(lo, hi)`` marks table tuples invalid immediately (queries
            read the validity mask, §5.2 lazy deletes) and kills staged rows
            in range before they ever reach the table.
@@ -74,8 +74,9 @@ import jax.numpy as jnp
 from repro.core import histogram as hg
 from repro.core import index as hix
 from repro.core import learned as ln
-from repro.core.partition import (SUMMARY_POLICIES, ShardedHippoState,
-                                  set_shard, shard_state, summary_of)
+from repro.core.partition import (SUMMARY_POLICIES, ShardedHippoIndex,
+                                  ShardedHippoState, set_shard, shard_state,
+                                  summary_of)
 from repro.runtime.faultinject import crashpoint
 
 _STAGE_BUCKET_MIN = 8   # smallest device overlay width (trace bucketing)
@@ -85,8 +86,7 @@ class _ShardQueue:
     """Pending inserts for one shard, kept in table-append order.
 
     ``live`` marks rows not yet killed by a staged delete; the sorted view of
-    live values backs the overlay's interval counting (two binary searches
-    per query per shard).
+    live values fills the device overlay's buffers.
     """
     __slots__ = ("values", "live", "n_live", "_sorted")
 
@@ -144,6 +144,13 @@ class WriterStats:
     total_drain_us: float = 0.0
 
 
+def takes_writer(index) -> bool:
+    """Whether ``index`` can carry a ``MaintenanceWriter``: only a
+    ``ShardedHippoIndex`` routes rows into per-shard queues by its
+    ``ShardSpec``."""
+    return isinstance(index, ShardedHippoIndex)
+
+
 class MaintenanceWriter:
     """Per-shard staged maintenance over a ``ShardedHippoIndex``.
 
@@ -152,12 +159,11 @@ class MaintenanceWriter:
     """
 
     def __init__(self, index, journal=None):
-        for attr in ("spec", "state", "plan_batch"):
-            if not hasattr(index, attr):
-                raise ValueError(
-                    "MaintenanceWriter needs a ShardedHippoIndex-style index "
-                    "(ShardSpec routing + stacked per-shard state); got "
-                    f"{type(index).__name__}")
+        if not takes_writer(index):
+            raise ValueError(
+                "MaintenanceWriter needs a ShardedHippoIndex (ShardSpec "
+                f"routing + stacked per-shard state); got "
+                f"{type(index).__name__}")
         prior = getattr(index, "staging", None)
         if prior is not None and prior.queue_depth:
             # Replacing the attached writer would detach its overlay and
@@ -428,29 +434,11 @@ class MaintenanceWriter:
 
     # -- overlay (queries never go stale) ------------------------------------
 
-    def staged_counts(self, los, his) -> np.ndarray:
-        """(Q, S) exact counts of live staged rows per (query, shard).
-
-        Two binary searches per (query, shard) on the per-shard sorted
-        staging buffers; empty predicates (lo > hi) count zero. Host-side
-        twin of ``core.index.staged_overlay_counts``.
-        """
-        los = np.asarray(los, np.float32)
-        his = np.asarray(his, np.float32)
-        out = np.zeros((los.shape[0], self.index.spec.num_shards), np.int64)
-        for s, q in self._queues.items():
-            a = q.sorted_live
-            if a.size == 0:
-                continue
-            out[:, s] = (np.searchsorted(a, his, side="right")
-                         - np.searchsorted(a, los, side="left"))
-        return np.maximum(out, 0)
-
     def device_buffers(self) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """(vals (S, B) f32, live (S, B) bool) staged rows for the fused
-        device overlay (``core.index.search_many_sharded_staged``). B is the
-        max per-shard live depth rounded to a power of two (min 8) so the
-        overlay re-traces only when the queue outgrows its bucket."""
+        """(vals (S, B) f32, live (S, B) bool) staged rows for the device
+        overlay (``core.index.search_compact_many_sharded_staged``). B is
+        the max per-shard live depth rounded to a power of two (min 8) so
+        the overlay re-traces only when the queue outgrows its bucket."""
         if self._dev_cache is not None and self._dev_cache[0] == self._version:
             return self._dev_cache[1], self._dev_cache[2]
         s_n = self.index.spec.num_shards

@@ -55,9 +55,15 @@ def build_shipdate_index(li: Lineitem, page_card: int = 50, resolution: int = 40
 
 
 def _page_select(idx: HippoIndex, lo: float, hi: float) -> np.ndarray:
-    """Hippo access path: qualifying-tuple mask (flat, aligned to storage)."""
-    res = idx.search(Predicate.between(lo, hi))
-    return np.asarray(res.qualified).reshape(-1)[: idx.table.cardinality]
+    """Hippo access path: qualifying-tuple mask (flat, aligned to storage),
+    set from every qualifying row id the search returns."""
+    pred = Predicate.between(lo, hi)
+    n = idx.count(pred)
+    ids = np.asarray(idx.search_batch(
+        [pred], top_k=1 << max(n - 1, 0).bit_length()).row_ids[0])
+    mask = np.zeros(idx.table.cardinality, bool)
+    mask[ids[ids >= 0]] = True
+    return mask
 
 
 def q6(li: Lineitem, idx: HippoIndex, date_lo: float, date_hi: float) -> float:

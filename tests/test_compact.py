@@ -1,10 +1,12 @@
-"""Compact/dense/sharded equivalence sweep for the gather search pipeline.
+"""Equivalence sweep for the one search program, unsharded and sharded.
 
-The contract under test: ``search_compact_many`` counts and row ids are
-bit-identical to ``search_many`` wherever ``truncated`` is False — across
-selectivities, shard counts, and staged-overlay states — and the engine's
-compact mode (the default) serves exactly what dense mode serves, falling
-back to the dense-cost cap on truncation but never to a wrong answer.
+The contract under test: ``search_batch`` counts and row ids equal a
+brute-force scan wherever ``truncated`` is False — across selectivities,
+slab widths, shard counts, and staged-overlay states — its
+``pages_inspected``/``entries_matched`` equal step 2 of Algorithm 1
+(``core.index._page_match``), and the engine serves exactly what the scan
+counts, falling back to the never-truncating cap on truncation but never to
+a wrong answer.
 
 Marked ``compact`` (see tests/conftest.py): the sweep compiles many distinct
 (max_selected, top_k) trace shapes, so it is split out of the fast inner
@@ -19,8 +21,8 @@ import jax.numpy as jnp
 from repro.core import histogram as hg
 from repro.core import index as hix
 from repro.core.hippo import HippoIndex
-from repro.core.partition import ShardedHippoIndex
-from repro.core.predicate import Predicate, intervals, to_bucket_bitmaps
+from repro.core.partition import ShardedHippoIndex, shard_state
+from repro.core.predicate import Predicate, to_bucket_bitmaps
 from repro.runtime.engine import QueryEngine
 from repro.runtime.writer import MaintenanceWriter
 from repro.storage.table import PagedTable
@@ -37,6 +39,36 @@ def brute_ids(table: PagedTable, lo: float, hi: float) -> np.ndarray:
     valid = table.valid[: table.num_pages].reshape(-1)
     lo, hi = max(lo, -3.4e38), min(hi, 3.4e38)
     return np.flatnonzero(valid & (keys >= lo) & (keys <= hi)).astype(np.int64)
+
+
+def brute_counts(table: PagedTable, preds, staged=()) -> np.ndarray:
+    """Per-predicate scan counts of the table, plus the staged values each
+    predicate matches (rows waiting in a writer's queues)."""
+    staged = np.asarray(staged, np.float32)
+    out = []
+    for p in preds:
+        lo, hi = p.selectivity_interval()
+        in_staged = (staged >= max(lo, -3.4e38)) & (staged <= min(hi, 3.4e38))
+        out.append(brute_ids(table, lo, hi).size + int(in_staged.sum()))
+    return np.asarray(out, np.int64)
+
+
+def step2(target, preds) -> tuple[np.ndarray, np.ndarray]:
+    """Step 2 of Algorithm 1 alone: (Q, pages) possible-qualified pages in
+    global page order (every shard's slab, in shard order) and (Q,) matched
+    entries, each shard under its own bounds."""
+    spec = getattr(target, "spec", None)
+    if spec is None:
+        qbms = to_bucket_bitmaps(preds, target.state.histogram)
+        mask, matched = hix._page_match(target.state, qbms,
+                                        target.table.num_pages)
+        return np.asarray(mask), np.asarray(matched)
+    qbms = target._query_bitmaps(preds)
+    per = [hix._page_match(shard_state(target.state.shards, s), qbms[s],
+                           spec.pages_per_shard)
+           for s in range(spec.num_shards)]
+    return (np.concatenate([np.asarray(m) for m, _ in per], axis=1),
+            sum(np.asarray(n) for _, n in per))
 
 
 def workload(rng, widths=(2.0, 20.0, 200.0), per_width=3):
@@ -67,7 +99,7 @@ def make_pair(values, num_shards):
 
 
 # ---------------------------------------------------------------------------
-# Core equivalence: compact vs dense vs sharded, swept over slab capacities
+# Core equivalence: unsharded vs sharded vs a scan, swept over slab widths
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("dist", ["sorted", "uniform"])
@@ -80,11 +112,8 @@ def test_compact_counts_and_row_ids_match_dense_where_untruncated(dist, num_shar
         values = np.sort(values)
     idx, sidx = make_pair(values, num_shards)
     preds = workload(rng)
-    qbms = to_bucket_bitmaps(preds, idx.state.histogram)
-    los, his = intervals(preds)
-    dense = hix.search_many(idx.state, qbms, idx.table.device_keys(),
-                            idx.table.device_valid(), los, his)
-    want_counts = np.asarray(dense.counts)
+    want_counts = brute_counts(idx.table, preds)
+    masks, matched = step2(idx, preds)
     want_ids = [brute_ids(idx.table, *p.selectivity_interval())[:TOP_K]
                 for p in preds]
     full = idx.table.num_pages
@@ -92,21 +121,21 @@ def test_compact_counts_and_row_ids_match_dense_where_untruncated(dist, num_shar
     assert not any(t.inspects_in_place(cap) for t in (idx, sidx)
                    for cap in (4, 32))
     for cap in (4, 32, full):
-        res = idx.search_compact_batch(preds, max_selected=cap, top_k=TOP_K)
+        res = idx.search_batch(preds, max_selected=cap, top_k=TOP_K)
         trunc = np.asarray(res.truncated)
         counts = np.asarray(res.counts)
         assert trunc.any() or cap != 4           # the narrowest cap truncates
         assert (counts[~trunc] == want_counts[~trunc]).all(), cap
         assert (counts <= want_counts).all()     # truncation only ever loses
         np.testing.assert_array_equal(np.asarray(res.pages_inspected),
-                                      np.asarray(dense.pages_inspected))
+                                      masks.sum(axis=1))
         np.testing.assert_array_equal(np.asarray(res.entries_matched),
-                                      np.asarray(dense.entries_matched))
+                                      matched)
         for q in np.flatnonzero(~trunc):
             ids = np.asarray(res.row_ids[q])
             np.testing.assert_array_equal(ids[ids >= 0], want_ids[q], (cap, q))
         # the sharded gather agrees bit-for-bit where neither truncated
-        sres = sidx.search_compact_batch(preds, max_selected=cap, top_k=TOP_K)
+        sres = sidx.search_batch(preds, max_selected=cap, top_k=TOP_K)
         strunc = np.asarray(sres.truncated)
         both = ~trunc & ~strunc
         np.testing.assert_array_equal(np.asarray(sres.counts)[both],
@@ -115,19 +144,18 @@ def test_compact_counts_and_row_ids_match_dense_where_untruncated(dist, num_shar
             np.testing.assert_array_equal(np.asarray(sres.row_ids[q]),
                                           np.asarray(res.row_ids[q]), (cap, q))
     # at the never-truncating cap nothing may be flagged
-    res = idx.search_compact_batch(preds, max_selected=full, top_k=0)
+    res = idx.search_batch(preds, max_selected=full, top_k=0)
     assert not np.asarray(res.truncated).any()
-    sres = sidx.search_compact_batch(
+    sres = sidx.search_batch(
         preds, max_selected=sidx.spec.pages_per_shard, top_k=0)
     assert not np.asarray(sres.truncated).any()
     np.testing.assert_array_equal(np.asarray(sres.counts), want_counts)
 
 
 def test_compact_through_maintenance_and_staged_overlay():
-    """Compact counts stay bit-identical to the dense path through inserts,
-    deletes+vacuum, and — on the sharded index — through the writer's staged
-    overlay (rows pending in queues count, without ever appearing in row
-    ids, exactly like the dense path's page_mask)."""
+    """Counts stay equal to a scan through inserts, deletes+vacuum, and —
+    on the sharded index — through the writer's staged overlay (rows
+    pending in queues count, without ever appearing in row ids)."""
     rng = np.random.default_rng(7)
     values = np.sort(rng.uniform(0, 1000, 1500))
     idx, sidx = make_pair(values, 2)
@@ -138,11 +166,10 @@ def test_compact_through_maintenance_and_staged_overlay():
         idx.insert(float(v))
     idx.table.delete_where(200, 260)
     idx.vacuum()
-    dense = idx.search_batch(preds)
-    res = idx.search_compact_batch(preds, max_selected=idx.table.num_pages,
-                                   top_k=TOP_K)
+    res = idx.search_batch(preds, max_selected=idx.table.num_pages,
+                           top_k=TOP_K)
     np.testing.assert_array_equal(np.asarray(res.counts),
-                                  np.asarray(dense.counts))
+                                  brute_counts(idx.table, preds))
     for q, p in enumerate(preds):
         ids = np.asarray(res.row_ids[q])
         np.testing.assert_array_equal(
@@ -154,9 +181,9 @@ def test_compact_through_maintenance_and_staged_overlay():
     for v in staged:
         writer.write(float(v))
     cap = sidx.spec.pages_per_shard
-    res = sidx.search_compact_batch(preds, max_selected=cap, top_k=TOP_K)
-    want = np.asarray(sidx.search_batch(preds).counts)      # staged-aware dense
-    np.testing.assert_array_equal(np.asarray(res.counts), want)
+    res = sidx.search_batch(preds, max_selected=cap, top_k=TOP_K)
+    np.testing.assert_array_equal(np.asarray(res.counts),
+                                  brute_counts(sidx.table, preds, staged))
     # row ids exclude staged rows: they equal the table-only brute force
     for q, p in enumerate(preds):
         ids = np.asarray(res.row_ids[q])
@@ -165,9 +192,9 @@ def test_compact_through_maintenance_and_staged_overlay():
             brute_ids(sidx.table, *p.selectivity_interval())[:TOP_K])
     # after the drain the staged rows land in pages (and so in row ids)
     writer.flush()
-    res = sidx.search_compact_batch(preds, max_selected=cap, top_k=TOP_K)
+    res = sidx.search_batch(preds, max_selected=cap, top_k=TOP_K)
     np.testing.assert_array_equal(np.asarray(res.counts),
-                                  np.asarray(sidx.search_batch(preds).counts))
+                                  brute_counts(sidx.table, preds))
     for q, p in enumerate(preds):
         ids = np.asarray(res.row_ids[q])
         np.testing.assert_array_equal(
@@ -187,8 +214,8 @@ def _slab_pages(target) -> int:
 
 def _in_place_case(name):
     """(index, predicates, expected counts) for one in-place case; expected
-    counts come from the dense path, staged-aware where a writer holds
-    rows."""
+    counts come from a scan, plus the staged rows where a writer holds
+    them."""
     rng = np.random.default_rng({"unsharded": 23, "sharded_spare": 29,
                                  "staged": 31, "maintained": 37,
                                  "empty": 41}[name])
@@ -202,23 +229,24 @@ def _in_place_case(name):
             PagedTable.from_values(np.zeros(0, np.float32),
                                    page_card=PAGE_CARD),
             resolution=64, density=0.25, hist=hist)
-        return idx, preds, np.asarray(idx.search_batch(preds).counts)
+        return idx, preds, brute_counts(idx.table, preds)
     idx, sidx = make_pair(values, 3 if name == "sharded_spare" else 2)
     if name == "unsharded":
-        return idx, preds, np.asarray(idx.search_batch(preds).counts)
+        return idx, preds, brute_counts(idx.table, preds)
     if name == "maintained":
         for v in rng.uniform(0, 1000, 20):
             idx.insert(float(v))
         idx.table.delete_where(200, 260)
         idx.vacuum()
-        return idx, preds, np.asarray(idx.search_batch(preds).counts)
+        return idx, preds, brute_counts(idx.table, preds)
+    staged = rng.uniform(0, 1000, 15) if name == "staged" else ()
     if name == "staged":
         writer = MaintenanceWriter(sidx)
-        for v in rng.uniform(0, 1000, 15):
+        for v in staged:
             writer.write(float(v))
         assert sidx.staging.staged_rows
     assert sidx.spec.pages_per_shard * 3 > sidx.table.num_pages  # spare slab
-    return sidx, preds, np.asarray(sidx.search_batch(preds).counts)
+    return sidx, preds, brute_counts(sidx.table, preds, staged)
 
 
 @pytest.mark.parametrize("backend,shrink", [("tpu", 128), ("cpu", 1),
@@ -241,8 +269,8 @@ def test_inspects_in_place_reads_the_backends_crossover(monkeypatch, backend,
                                   "maintained", "empty"])
 def test_in_place_equals_gather_dense_and_brute_force(case, shrink,
                                                       monkeypatch):
-    """In place, the compact search answers what the gather branch, the
-    dense ``search_many`` and a brute-force scan answer: counts,
+    """In place, the search answers what the gather branch, step 2 and a
+    brute-force scan answer: counts,
     ``pages_inspected``, ``entries_matched`` and the first ``TOP_K`` row
     ids. It runs at the first width ``inspects_in_place`` takes and at the
     cap, and the gather branch one width below that threshold; in place
@@ -259,19 +287,19 @@ def test_in_place_equals_gather_dense_and_brute_force(case, shrink,
                  if target.inspects_in_place(m))
     assert all(target.inspects_in_place(m)
                for m in (first, first + 1, target.gather_cap))
-    dense = target.search_batch(preds)
+    masks, matched = step2(target, preds)
     results = {}
     for m in sorted({first, target.gather_cap}):
-        res = target.search_compact_batch(preds, max_selected=m, top_k=TOP_K)
+        res = target.search_batch(preds, max_selected=m, top_k=TOP_K)
         assert not np.asarray(res.truncated).any(), m
         assert int(res.pages_gathered) == 0, m
         np.testing.assert_array_equal(np.asarray(res.counts), want_counts)
         np.testing.assert_array_equal(np.asarray(res.pages_inspected),
-                                      np.asarray(dense.pages_inspected))
+                                      masks.sum(axis=1))
         np.testing.assert_array_equal(np.asarray(res.entries_matched),
-                                      np.asarray(dense.entries_matched))
+                                      matched)
         # the batch's union over the table, and its widest part in a shard
-        union = np.asarray(dense.page_mask).any(axis=0)
+        union = masks.any(axis=0)
         per_shard = [union[s:s + pages].sum()
                      for s in range(0, union.size, max(pages, 1))]
         assert int(res.pages_selected) == union.sum()
@@ -285,7 +313,7 @@ def test_in_place_equals_gather_dense_and_brute_force(case, shrink,
         return
     below = first - 1
     assert not target.inspects_in_place(below)
-    g = target.search_compact_batch(preds, max_selected=below, top_k=TOP_K)
+    g = target.search_batch(preds, max_selected=below, top_k=TOP_K)
     trunc = np.asarray(g.truncated)
     assert (~trunc).any() and int(g.pages_gathered) > 0
     res = results[first]
@@ -300,30 +328,30 @@ def test_in_place_equals_gather_dense_and_brute_force(case, shrink,
 
 
 # ---------------------------------------------------------------------------
-# Engine compact mode: ladder, fallback, row-id payloads
+# Engine: ladder, fallback, row-id payloads
 # ---------------------------------------------------------------------------
 
 def test_engine_compact_mode_is_default_and_matches_dense():
     rng = np.random.default_rng(11)
     idx, sidx = make_pair(np.sort(rng.uniform(0, 1000, 2000)), 2)
     preds = workload(rng)
-    dense = QueryEngine(idx, batch=8, mode="dense").run_all(preds)
+    want = brute_counts(idx.table, preds)
     for target in (idx, sidx):
         engine = QueryEngine(target, batch=8)
         assert engine.mode == "compact"
-        np.testing.assert_array_equal(engine.run_all(preds), dense)
+        np.testing.assert_array_equal(engine.run_all(preds), want)
         assert engine.stats.compact_batches > 0
         assert 0 < engine.stats.selected_page_ratio <= 1.0
 
 
 def test_engine_compact_fallback_never_wrong():
     """A deliberately tiny initial bucket forces truncation: the per-query
-    fallback must keep every count bit-identical to dense mode while the
-    adaptive bucket widens for later batches."""
+    fallback must keep every count equal to a scan's while the adaptive
+    bucket widens for later batches."""
     rng = np.random.default_rng(13)
     idx, sidx = make_pair(np.sort(rng.uniform(0, 1000, 2000)), 2)
     preds = workload(rng)
-    dense = QueryEngine(idx, batch=8, mode="dense").run_all(preds)
+    dense = brute_counts(idx.table, preds)
     for target in (idx, sidx):
         engine = QueryEngine(target, batch=8, compact_bucket=1, top_k=8)
         first_bucket = engine._compact_bucket
@@ -357,14 +385,22 @@ def test_engine_row_id_payloads_match_brute_force():
 def test_engine_mode_validation():
     rng = np.random.default_rng(19)
     idx, sidx = make_pair(rng.uniform(0, 1000, 300), 2)
+    for target in (idx, sidx):
+        assert QueryEngine(target, mode="compact").mode == "compact"
     with pytest.raises(ValueError, match="mode"):
         QueryEngine(idx, mode="bogus")
-    with pytest.raises(ValueError, match="compact"):
-        QueryEngine(sidx, mode="compact", sharded=True)
     with pytest.raises(ValueError, match="top_k"):
-        QueryEngine(idx, mode="dense", top_k=4)
+        QueryEngine(idx, top_k=-1)
     with pytest.raises(ValueError, match="compact_bucket"):
         QueryEngine(idx, compact_bucket=0)
-    # explicit sharded=True still resolves to the routed dense path
-    routed = QueryEngine(sidx, sharded=True)
-    assert routed.mode == "dense" and routed.sharded
+
+
+@pytest.mark.parametrize("mode", ["dense", "auto"])
+def test_engine_refuses_the_removed_modes(mode):
+    """One query path: the full-table and auto-selected modes are gone, and
+    asking for one is refused rather than silently served compact."""
+    rng = np.random.default_rng(20)
+    idx, sidx = make_pair(rng.uniform(0, 1000, 300), 2)
+    for target in (idx, sidx):
+        with pytest.raises(ValueError, match="mode"):
+            QueryEngine(target, mode=mode)

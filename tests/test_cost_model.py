@@ -54,7 +54,7 @@ def test_query_time_estimate_matches_measured_inspection():
         width = 1e6 * sf
         lo = 5e5 - width / 2
         res = idx.search(Predicate.between(lo, lo + width))
-        measured_tuples = int(res.pages_inspected) * page_card
+        measured_tuples = int(res.pages_inspected[0]) * page_card
         est = cost.query_time_tuples(sf, h, d, card)
         # Within 2x of the model (Prob is an expectation over uniform data).
         assert measured_tuples <= 2.2 * max(est, page_card)

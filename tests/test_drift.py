@@ -1,7 +1,8 @@
 """Drift-adaptive re-summarization: a remap onto new histogram bounds must
 never change a single count — before, during (partially drained, mixed
-bounds epochs), or after — on every query path (fused dense, routed
-dispatch, compact gather, staged overlay); a refused remap must roll back
+bounds epochs), or after — through the index's search, the engine at
+its slab bucket and at the cap's fallback, and the staged overlay; a
+refused remap must roll back
 cleanly with the old bounds still serving; and the auto trigger must
 schedule and drain through the normal policies."""
 import numpy as np
@@ -54,7 +55,8 @@ def drift_preds():
 @pytest.mark.parametrize("staged", [False, True])
 def test_resummarize_counts_bit_identical(num_shards, staged):
     """Counts against brute force across selectivity x shard count x
-    staged-overlay, on the compact, fused-dense, and routed paths, before a
+    staged-overlay, on the engine, the index's search and the engine's
+    gather-and-fallback ladder, before a
     remap, after the remap alone (staged rows still queued), and after the
     rows drain."""
     rng = np.random.default_rng(3 * num_shards + staged)
@@ -78,9 +80,10 @@ def test_resummarize_counts_bit_identical(num_shards, staged):
         np.testing.assert_array_equal(engine.run_all(preds), want, err_msg=msg)
         np.testing.assert_array_equal(
             np.asarray(aidx.search_batch(preds).counts), want, err_msg=msg)
-        routed = QueryEngine(aidx, batch=8, mode="dense",
+        # a one-page slab walks the gather branch and the cap's fallback
+        narrow = QueryEngine(aidx, batch=8, compact_bucket=1,
                              drain_policy="manual", writer=engine.writer)
-        np.testing.assert_array_equal(routed.run_all(preds), want, err_msg=msg)
+        np.testing.assert_array_equal(narrow.run_all(preds), want, err_msg=msg)
 
     check_all_paths("before resummarize")
     w = engine.writer
@@ -113,9 +116,9 @@ def test_partial_resummarize_serves_mixed_epochs_exactly():
         np.asarray(aidx.search_batch(preds).counts), want)
     engine = QueryEngine(aidx, batch=8, drain_policy="manual", writer=writer)
     np.testing.assert_array_equal(engine.run_all(preds), want)
-    routed = QueryEngine(aidx, batch=8, mode="dense", drain_policy="manual",
-                         writer=writer)
-    np.testing.assert_array_equal(routed.run_all(preds), want)
+    narrow = QueryEngine(aidx, batch=8, compact_bucket=1,
+                         drain_policy="manual", writer=writer)
+    np.testing.assert_array_equal(narrow.run_all(preds), want)
     writer.flush()
     assert list(aidx.bounds_epochs) == [1, 1, 1, 1]
     np.testing.assert_array_equal(engine.run_all(preds), want)
@@ -161,17 +164,19 @@ def test_auto_resummarize_triggers_and_drains_via_policy():
     aidx = make_sidx(np.sort(rng.uniform(0, 100, 200)))
     engine = QueryEngine(aidx, batch=4, drift_threshold=0.5,
                          drift_min_observed=8)     # between_batches default
-    for v in rng.uniform(100, 115, 16):            # all beyond the old range
+    written = rng.uniform(100, 115, 16).astype(np.float32)
+    for v in written:                              # all beyond the old range
         engine.write(float(v))
     assert engine.writer.pending_resummarize_shards() == [0, 1, 2, 3]
     assert engine.stats.edge_overflow_ratio == 1.0
     preds = drift_preds()
+    # every written row counts, whether it still waits staged or has landed
+    want = brute_force(aidx.table, preds) + np.asarray(
+        [((written >= p.lo) & (written <= p.hi)).sum() for p in preds])
+    assert engine.writer.queue_depth == written.size
     while engine.writer.pending_units:
-        got = engine.run_all(preds)
-        want = (brute_force(aidx.table, preds)
-                + engine.writer.staged_counts(
-                    [p.lo for p in preds], [p.hi for p in preds]).sum(axis=1))
-        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(engine.run_all(preds), want)
+    np.testing.assert_array_equal(engine.run_all(preds), want)
     assert engine.stats.resummarizes == aidx.num_shards
     assert engine.stats.edge_overflow_ratio == 0.0   # tracker rearmed
     assert not engine.writer.pending_resummarize_shards()

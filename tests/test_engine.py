@@ -1,12 +1,13 @@
-"""Batched query engine: ``search_many`` / ``QueryEngine`` must agree
-bit-for-bit with a Python loop of single-predicate ``index.search`` calls,
-including empty-result and full-table predicates."""
+"""Batched query engine: ``search_batch`` / ``QueryEngine`` must agree
+bit-for-bit with a Python loop of single-predicate ``index.search`` calls
+and with a brute-force scan, including empty-result and full-table
+predicates."""
 import numpy as np
 import pytest
 
 from repro.core import index as hix
 from repro.core.hippo import HippoIndex
-from repro.core.predicate import (Predicate, intervals, to_bucket_bitmap,
+from repro.core.predicate import (Predicate, to_bucket_bitmap,
                                   to_bucket_bitmaps)
 from repro.runtime.engine import QueryEngine
 from repro.storage.table import PagedTable
@@ -15,6 +16,17 @@ from repro.storage.table import PagedTable
 def make_index(values, page_card=8, resolution=32, density=0.25, **kw):
     table = PagedTable.from_values(values, page_card=page_card, spare_pages=64)
     return HippoIndex.create(table, resolution=resolution, density=density, **kw)
+
+
+def brute_force(table, preds) -> np.ndarray:
+    """Per-predicate counts of live keys in range, by a scan."""
+    live = table.valid[: table.num_pages]
+    keys = table.keys[: table.num_pages]
+    out = []
+    for p in preds:
+        lo, hi = p.selectivity_interval()
+        out.append(int((live & (keys >= lo) & (keys <= hi)).sum()))
+    return np.asarray(out, np.int64)
 
 
 def workload(rng, n):
@@ -66,18 +78,17 @@ def test_search_many_matches_search_loop(dist):
     preds = workload(rng, 32)
     assert len(preds) >= 32
     qbms = to_bucket_bitmaps(preds, idx.state.histogram)
-    los, his = intervals(preds)
     res = idx.search_batch(preds)
-    many = hix.search_many(idx.state, qbms, idx.table.device_keys(),
-                           idx.table.device_valid(), los, his)
+    masks, _ = hix._page_match(idx.state, qbms, idx.table.num_pages)
+    np.testing.assert_array_equal(np.asarray(res.counts),
+                                  brute_force(idx.table, preds))
+    np.testing.assert_array_equal(np.asarray(res.pages_inspected),
+                                  np.asarray(masks).sum(axis=1))
     for q, p in enumerate(preds):
         single = idx.search(p)
-        for batched in (res, many):
-            assert int(batched.counts[q]) == int(single.count), (dist, q)
-            assert int(batched.pages_inspected[q]) == int(single.pages_inspected)
-            assert int(batched.entries_matched[q]) == int(single.entries_matched)
-            np.testing.assert_array_equal(np.asarray(batched.page_mask[q]),
-                                          np.asarray(single.page_mask))
+        assert int(res.counts[q]) == int(single.counts[0]), (dist, q)
+        assert int(res.pages_inspected[q]) == int(single.pages_inspected[0])
+        assert int(res.entries_matched[q]) == int(single.entries_matched[0])
 
 
 def test_search_many_sees_maintenance():
@@ -92,8 +103,10 @@ def test_search_many_sees_maintenance():
     preds = [Predicate.between(0, 100), Predicate.between(45, 55),
              Predicate.between(39, 41)]
     res = idx.search_batch(preds)
+    np.testing.assert_array_equal(np.asarray(res.counts),
+                                  brute_force(idx.table, preds))
     for q, p in enumerate(preds):
-        assert int(res.counts[q]) == int(idx.search(p).count)
+        assert int(res.counts[q]) == idx.count(p)
 
 
 def test_query_engine_recycles_slots_and_matches_loop():
@@ -102,8 +115,7 @@ def test_query_engine_recycles_slots_and_matches_loop():
     preds = workload(rng, 32)
     engine = QueryEngine(idx, batch=8)      # < len(preds): forces recycling
     counts = engine.run_all(preds)
-    want = np.asarray([int(idx.search(p).count) for p in preds])
-    np.testing.assert_array_equal(counts, want)
+    np.testing.assert_array_equal(counts, brute_force(idx.table, preds))
     assert engine.stats.served == len(preds)
     assert engine.stats.batches == -(-len(preds) // 8)
     assert all(t is None for t in engine.slots)
@@ -118,7 +130,7 @@ def test_query_engine_partial_batch_and_tickets():
     assert not t1.done and t1.count is None
     finished = engine.run_batch()
     assert {t.qid for t in finished} == {t1.qid, t2.qid}
-    assert t1.done and t1.count == int(idx.search(Predicate.between(0, 1000)).count)
+    assert t1.done and t1.count == idx.table.cardinality
     assert t2.done and t2.count == 0 and t2.entries_matched == 0
     assert engine.run_batch() == []         # nothing pending -> no-op
 
@@ -130,8 +142,8 @@ def test_query_engine_results_in_submission_order():
     preds = workload(rng, 10)
     tickets = [engine.submit(p) for p in preds]
     engine.drain()
-    for t, p in zip(tickets, preds):
-        assert t.count == int(idx.search(p).count)
+    for t, want in zip(tickets, brute_force(idx.table, preds)):
+        assert t.count == want
 
 
 def test_admission_is_constant_time_per_query():
@@ -171,8 +183,7 @@ def test_compact_fallback_accounted_in_occupancy_and_gather_stats():
     engine = QueryEngine(idx, batch=4, compact_bucket=1)
     preds = [Predicate.between(0, 1000), Predicate.between(100, 900)]
     counts = engine.run_all(preds)
-    want = [int(idx.search(p).count) for p in preds]
-    np.testing.assert_array_equal(counts, want)      # fallback stays exact
+    np.testing.assert_array_equal(counts, brute_force(idx.table, preds))
     st = engine.stats
     assert st.compact_fallbacks == 2
     # gather telemetry covers both dispatches: the bucket-1 primary slab,
@@ -204,18 +215,17 @@ def test_compact_bucket_in_place_is_dispatched_at_the_cap(monkeypatch, bucket,
     rng = np.random.default_rng(5)
     idx = make_index(np.sort(rng.uniform(0, 1000, 3200)))     # 400 pages
     assert idx.inspects_in_place(bucket) == in_place
-    widths, search = [], idx.search_compact_batch
+    widths, search = [], idx.search_batch
 
     def spy(preds, max_selected, top_k=0):
         widths.append(max_selected)
         return search(preds, max_selected=max_selected, top_k=top_k)
 
-    monkeypatch.setattr(idx, "search_compact_batch", spy)
+    monkeypatch.setattr(idx, "search_batch", spy)
     engine = QueryEngine(idx, batch=4, compact_bucket=bucket)
     preds = [Predicate.between(100, 101), Predicate.between(600, 601)]
     counts = engine.run_all(preds)
-    np.testing.assert_array_equal(
-        counts, np.asarray(idx.search_batch(preds).counts))
+    np.testing.assert_array_equal(counts, brute_force(idx.table, preds))
     st = engine.stats
     assert widths[0] == (idx.gather_cap if in_place else bucket)
     assert widths[1:] == [idx.gather_cap] * (len(widths) - 1)   # fallbacks
@@ -236,7 +246,7 @@ def test_writerless_noop_delete_skips_vacuum():
     n = engine.delete(0.0, 100.0)
     assert n > 0 and idx.counters.vacuums == 1       # real deletes still vacuum
     assert engine.run_all([Predicate.between(0, 1000)])[0] == \
-        int(idx.search(Predicate.between(0, 1000)).count)
+        idx.table.cardinality
 
 
 def test_table_dirty_page_counter_tracks_lifecycle():
@@ -264,14 +274,18 @@ def test_table_dirty_page_counter_tracks_lifecycle():
 
 
 def test_engine_compact_default_matches_explicit_dense():
+    """The default engine is the one path: it equals an engine given
+    ``mode="compact"`` explicitly and a scan, and every batch and query is
+    served by it."""
     rng = np.random.default_rng(9)
     idx = make_index(np.sort(rng.uniform(0, 1000, 1500)))
     preds = workload(rng, 12)
     default = QueryEngine(idx, batch=8)
     assert default.mode == "compact"
     counts = default.run_all(preds)
+    np.testing.assert_array_equal(counts, brute_force(idx.table, preds))
     np.testing.assert_array_equal(
-        counts, QueryEngine(idx, batch=8, mode="dense").run_all(preds))
+        counts, QueryEngine(idx, batch=8, mode="compact").run_all(preds))
     assert default.stats.compact_batches == default.stats.batches
     assert (default.stats.compact_hits + default.stats.compact_fallbacks
             == default.stats.served)

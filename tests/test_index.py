@@ -9,7 +9,7 @@ from repro.core import histogram as hg
 from repro.core import index as hix
 from repro.core.hippo import HippoIndex
 from repro.core.partition import ShardedHippoIndex
-from repro.core.predicate import Predicate, to_bucket_bitmap
+from repro.core.predicate import Predicate, to_bucket_bitmap, to_bucket_bitmaps
 from repro.storage.table import PagedTable
 
 
@@ -22,6 +22,13 @@ def brute_force(table, lo, hi):
     live = table.valid[: table.num_pages]
     keys = table.keys[: table.num_pages]
     return int((live & (keys >= lo) & (keys <= hi)).sum())
+
+
+def page_masks(idx, preds):
+    """Step 2 of Algorithm 1 alone: (Q, pages) possible-qualified pages."""
+    qbms = to_bucket_bitmaps(preds, idx.state.histogram)
+    return np.asarray(hix._page_match(idx.state, qbms,
+                                      idx.table.num_pages)[0])
 
 
 def test_build_structure_invariants():
@@ -85,6 +92,7 @@ def test_entries_matched_counts_live_entries_after_maintenance():
     preds = [Predicate.between(float(lo), float(lo) + w)
              for lo, w in zip(rng.uniform(0, 100, 40), rng.uniform(0, 20, 40))]
     res = idx.search_batch(preds)
+    masks = page_masks(idx, preds)
     st = idx.state
     s = idx.cfg.max_slots
     live = np.asarray(st.slot_live) & (np.arange(s) < int(st.num_slots))
@@ -97,10 +105,11 @@ def test_entries_matched_counts_live_entries_after_maintenance():
         for e in np.flatnonzero(match):
             pages[starts[e]:ends[e] + 1] = True
         assert int(res.entries_matched[q]) == int(match.sum())
-        assert (np.asarray(res.page_mask[q]) == pages).all()
+        assert int(res.pages_inspected[q]) == int(pages.sum())
+        assert (masks[q] == pages).all()
         single = idx.search(p)
-        assert int(single.entries_matched) == int(match.sum())
-        assert (np.asarray(single.page_mask) == pages).all()
+        assert int(single.entries_matched[0]) == int(match.sum())
+        assert (page_masks(idx, [p])[0] == pages).all()
 
 
 def _expansion_case(name):
@@ -232,42 +241,43 @@ def test_search_exact_vs_bruteforce(dist):
         values = rng.integers(0, 12, n).astype(float)
     idx = make_index(values)
     for lo, hi in [(0, 1000), (100, 110), (500, 500), (-5, -1), (900, 2000)]:
-        res = idx.search(Predicate.between(lo, hi))
-        assert int(res.count) == brute_force(idx.table, lo, hi), (dist, lo, hi)
+        got = idx.count(Predicate.between(lo, hi))
+        assert got == brute_force(idx.table, lo, hi), (dist, lo, hi)
 
 
 def test_search_compact_matches_dense():
+    """The search at the cap counts what a scan counts and inspects what
+    step 2 selects; an undersized slab flags truncation rather than
+    silently undercounting."""
     rng = np.random.default_rng(3)
     values = rng.uniform(0, 100, 1500)
     idx = make_index(values)
     pred = Predicate.between(10, 20)
-    dense = idx.search(pred)
-    count, inspected, truncated = idx.search_compact(pred)
-    assert int(count) == int(dense.count)
-    assert int(inspected) == int(dense.pages_inspected)
-    assert not bool(truncated)
-    # undersized capacity must flag truncation rather than silently undercount
-    _, _, trunc2 = idx.search_compact(pred, max_selected=1)
-    assert bool(trunc2)
+    res = idx.search(pred)
+    assert int(res.counts[0]) == brute_force(idx.table, 10, 20)
+    assert int(res.pages_inspected[0]) == int(page_masks(idx, [pred]).sum())
+    assert not bool(res.truncated[0])
+    trunc2 = idx.search_batch([pred], max_selected=1).truncated
+    assert bool(trunc2[0])
 
 
 def test_search_compact_truncation_flag_parity():
     """Sweep max_selected across the truncation boundary: whenever the flag
-    is clear the compact count must equal the dense count, and the flag must
-    be set exactly when capacity fell short of the pages selected."""
+    is clear the count must equal a scan's, and the flag must be set
+    exactly when capacity fell short of the pages selected."""
     rng = np.random.default_rng(6)
     values = rng.uniform(0, 100, 1200)
     idx = make_index(values)
     pred = Predicate.between(30, 45)
-    dense = idx.search(pred)
-    n_sel = int(dense.pages_inspected)
+    want = brute_force(idx.table, 30, 45)
+    n_sel = int(page_masks(idx, [pred]).sum())
     assert n_sel > 1  # the sweep below must cross the boundary
     for cap in [n_sel - 1, n_sel, idx.table.num_pages]:
-        count, inspected, truncated = idx.search_compact(pred, max_selected=cap)
-        assert int(inspected) == n_sel
-        assert bool(truncated) == (n_sel > cap)
-        if not truncated:
-            assert int(count) == int(dense.count)
+        res = idx.search_batch([pred], max_selected=cap)
+        assert int(res.pages_inspected[0]) == n_sel
+        assert bool(res.truncated[0]) == (n_sel > cap)
+        if not res.truncated[0]:
+            assert int(res.counts[0]) == want
 
 
 def test_search_compact_fill_value_never_undercounts_silently():
@@ -280,52 +290,53 @@ def test_search_compact_fill_value_never_undercounts_silently():
     values = rng.uniform(0, 100, 800)
     idx = make_index(values)
     full = Predicate.between(-1e30, 1e30)
-    n_sel = int(idx.search(full).pages_inspected)
+    n_sel = int(idx.search(full).pages_inspected[0])
     assert n_sel == idx.table.num_pages          # full-table match
     for cap in (1, 7, n_sel - 1):
-        count, inspected, truncated = idx.search_compact(full, max_selected=cap)
-        assert bool(truncated), cap
-        assert int(inspected) == n_sel, cap
+        assert not idx.inspects_in_place(cap), cap
+        res = idx.search_batch([full], max_selected=cap)
+        assert bool(res.truncated[0]), cap
+        assert int(res.pages_inspected[0]) == n_sel, cap
         # the slab holds exactly cap real pages => their tuples and no more
-        assert int(count) == int(np.sum(
+        assert int(res.counts[0]) == int(np.sum(
             idx.table.valid[:cap]
             & (idx.table.keys[:cap] >= -3.4e38)
             & (idx.table.keys[:cap] <= 3.4e38))), cap
     # at exactly n_sel the flag clears and the count is exact
-    count, _, truncated = idx.search_compact(full, max_selected=n_sel)
-    assert not bool(truncated)
-    assert int(count) == idx.table.cardinality
+    res = idx.search_batch([full], max_selected=n_sel)
+    assert not bool(res.truncated[0])
+    assert int(res.counts[0]) == idx.table.cardinality
 
 
 def test_search_compact_rejects_zero_capacity():
     """max_selected=0 would turn every slab row into a pad and silently
-    count 0 — both gather entry points must refuse it outright."""
+    count 0 — the search must refuse it outright, and a negative top_k."""
     rng = np.random.default_rng(22)
     idx = make_index(rng.uniform(0, 100, 200))
     pred = Predicate.between(0, 50)
     with pytest.raises(ValueError, match="max_selected"):
-        idx.search_compact(pred, max_selected=0)
+        idx.search_batch([pred], max_selected=0)
     with pytest.raises(ValueError, match="max_selected"):
-        idx.search_compact_batch([pred], max_selected=0)
+        idx.search_batch([pred, pred], max_selected=0, top_k=4)
     with pytest.raises(ValueError, match="top_k"):
-        idx.search_compact_batch([pred], max_selected=4, top_k=-1)
+        idx.search_batch([pred], max_selected=4, top_k=-1)
 
 
 def test_search_compact_many_matches_search_many():
-    """Quick (unmarked) batched-gather parity check; the full selectivity x
-    shards x staged sweep lives in tests/test_compact.py (-m compact)."""
+    """Quick (unmarked) batched parity check against a scan and step 2; the
+    full selectivity x shards x staged sweep lives in tests/test_compact.py
+    (-m compact)."""
     rng = np.random.default_rng(23)
     idx = make_index(np.sort(rng.uniform(0, 100, 1000)))
     preds = [Predicate.between(10, 12), Predicate.between(40, 80),
              Predicate(lo=5.0, hi=1.0), Predicate.between(-1e30, 1e30)]
-    dense = idx.search_batch(preds)
-    res = idx.search_compact_batch(preds, max_selected=idx.table.num_pages,
-                                   top_k=8)
+    res = idx.search_batch(preds, top_k=8)
     assert not np.asarray(res.truncated).any()
-    np.testing.assert_array_equal(np.asarray(res.counts),
-                                  np.asarray(dense.counts))
+    np.testing.assert_array_equal(
+        np.asarray(res.counts),
+        [brute_force(idx.table, *p.selectivity_interval()) for p in preds])
     np.testing.assert_array_equal(np.asarray(res.pages_inspected),
-                                  np.asarray(dense.pages_inspected))
+                                  page_masks(idx, preds).sum(axis=1))
     # row ids: first 8 qualifying rows of each predicate, ascending
     keys = idx.table.keys[: idx.table.num_pages].reshape(-1)
     valid = idx.table.valid[: idx.table.num_pages].reshape(-1)
@@ -342,8 +353,8 @@ def test_false_positive_filtering_is_effective():
     values = np.linspace(0, 1000, 4000)
     idx = make_index(values, resolution=64, density=0.2)
     res = idx.search(Predicate.between(10, 20))
-    assert int(res.count) == brute_force(idx.table, 10, 20)
-    assert int(res.pages_inspected) < idx.table.num_pages * 0.2
+    assert int(res.counts[0]) == brute_force(idx.table, 10, 20)
+    assert int(res.pages_inspected[0]) < idx.table.num_pages * 0.2
 
 
 def test_equality_and_open_predicates():
@@ -351,12 +362,10 @@ def test_equality_and_open_predicates():
     values = rng.uniform(0, 100, 1000)
     idx = make_index(values)
     v = float(values[123])
-    res = idx.search(Predicate.equality(v))
-    assert int(res.count) == brute_force(idx.table, v, v)
-    res = idx.search(Predicate.greater(50.0))
-    assert int(res.count) == int((values > 50.0).sum())
-    res = idx.search(Predicate.less(50.0).and_(Predicate.greater(25.0)))
-    assert int(res.count) == int(((values < 50.0) & (values > 25.0)).sum())
+    assert idx.count(Predicate.equality(v)) == brute_force(idx.table, v, v)
+    assert idx.count(Predicate.greater(50.0)) == int((values > 50.0).sum())
+    assert idx.count(Predicate.less(50.0).and_(Predicate.greater(25.0))) == \
+        int(((values < 50.0) & (values > 25.0)).sum())
 
 
 def test_density_threshold_controls_entry_count():

@@ -8,8 +8,9 @@ pytest.importorskip("hypothesis")
 
 from hypothesis import given, settings, strategies as st
 
+from repro.core import index as hix
 from repro.core.hippo import HippoIndex
-from repro.core.predicate import Predicate
+from repro.core.predicate import Predicate, to_bucket_bitmap
 from repro.storage.table import PagedTable
 
 
@@ -34,15 +35,19 @@ def test_search_always_exact(seed, n, page_card, resolution, density, bounds):
     table = PagedTable.from_values(values, page_card=page_card, spare_pages=8)
     idx = HippoIndex.create(table, resolution=resolution, density=density)
     lo, hi = min(bounds), max(bounds)
-    res = idx.search(Predicate.between(lo, hi))
-    assert int(res.count) == brute_force(table, lo, hi)
+    pred = Predicate.between(lo, hi)
+    res = idx.search(pred)
+    assert int(res.counts[0]) == brute_force(table, lo, hi)
     # Soundness: every truly-qualified page is inspected (no false negatives).
     qual_pages = (
         table.valid[: table.num_pages]
         & (table.keys[: table.num_pages] >= lo)
         & (table.keys[: table.num_pages] <= hi)
     ).any(axis=1)
-    inspected = np.asarray(res.page_mask)
+    qbm = to_bucket_bitmap(pred, idx.state.histogram)
+    inspected = np.asarray(
+        hix._page_match(idx.state, qbm[None, :], table.num_pages)[0][0])
+    assert int(inspected.sum()) == int(res.pages_inspected[0])
     assert not (qual_pages & ~inspected).any()
 
 
@@ -71,5 +76,5 @@ def test_maintenance_history_preserves_exactness(seed, ops):
             table.delete_where(float(arg) - 2.0, float(arg) + 2.0)
         else:
             idx.vacuum()
-        res = idx.search(Predicate.between(-10.0, 10.0))
-        assert int(res.count) == brute_force(table, -10.0, 10.0)
+        got = idx.count(Predicate.between(-10.0, 10.0))
+        assert got == brute_force(table, -10.0, 10.0)

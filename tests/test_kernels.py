@@ -254,9 +254,14 @@ def test_compact_inspect_matches_search_compact_many():
     res = hix.search_compact_many(idx.state, qbms, keys, valid, los, his,
                                   max_selected=table.num_pages, top_k=0)
     assert not np.asarray(res.truncated).any()
-    # rebuild the slab + selected masks exactly as the search does
-    dense = hix.search_many(idx.state, qbms, keys, valid, los, his)
-    page_mask = np.asarray(dense.page_mask)
+    # rebuild the slab + selected masks exactly as the search does: step 2
+    # gives each query's pages, step 3 (``_page_counts``) counts them
+    page_mask = np.asarray(hix._page_match(idx.state, qbms,
+                                           table.num_pages)[0])
+    np.testing.assert_array_equal(
+        np.asarray(hix._page_counts(jnp.asarray(page_mask), keys, valid,
+                                    los, his)).sum(axis=1),
+        np.asarray(res.counts))
     union = page_mask.any(axis=0)
     sel = np.flatnonzero(union)
     slab_keys = np.asarray(keys)[sel]
@@ -273,6 +278,7 @@ def test_compact_inspect_matches_search_compact_many():
 # ---------------------------------------------------------------------------
 
 def test_kernelized_filter_matches_index_search():
+    from repro.core import index as hix
     from repro.core.hippo import HippoIndex
     from repro.core.predicate import Predicate, to_bucket_bitmap
     from repro.storage.table import PagedTable
@@ -287,16 +293,18 @@ def test_kernelized_filter_matches_index_search():
     s = idx.cfg.max_slots
     live = np.asarray(idx.state.slot_live) & (np.arange(s) < int(idx.state.num_slots))
     match_kernel = np.asarray(bitmap_and_any(idx.state.bitmaps, qbm)).astype(bool) & live
-    assert match_kernel.sum() == int(res.entries_matched)
-    # inspect with the kernel too
+    assert match_kernel.sum() == int(res.entries_matched[0])
+    # inspect with the kernel too, over step 2's pages
+    page_mask = hix._page_match(idx.state, qbm[None, :], table.num_pages)[0]
+    assert int(page_mask.sum()) == int(res.pages_inspected[0])
     qual, counts = page_inspect(table.device_keys(), table.device_valid(),
-                                jnp.asarray(res.page_mask), pred.lo, pred.hi)
-    assert int(counts.sum()) == int(res.count)
+                                page_mask[0], pred.lo, pred.hi)
+    assert int(counts.sum()) == int(res.counts[0])
 
 
 def test_batch_filter_matches_search_many():
     """The fused kernel's (Q, E) match matrix agrees with the entry-match
-    step of the batched search path (entries_matched per query)."""
+    step of the batched search (entries_matched per query)."""
     from repro.core.hippo import HippoIndex
     from repro.core.predicate import Predicate, to_bucket_bitmaps
     from repro.storage.table import PagedTable

@@ -190,7 +190,8 @@ def test_learned_rebuild_favors_reservoir_resolution():
 @pytest.mark.parametrize("staged", [False, True])
 def test_learned_counts_bit_identical(num_shards, staged):
     """Counts vs brute force across selectivity x shard count x staged
-    overlay on the compact, fused-dense, and routed paths — under learned
+    overlay on the engine, the index's search and the engine's
+    gather-and-fallback ladder — under learned
     build-time bounds, then through a learned drift refit (remap drained
     alone, rows still staged), then fully drained."""
     rng = np.random.default_rng(5 * num_shards + staged)
@@ -219,9 +220,10 @@ def test_learned_counts_bit_identical(num_shards, staged):
         np.testing.assert_array_equal(engine.run_all(preds), want, err_msg=msg)
         np.testing.assert_array_equal(
             np.asarray(aidx.search_batch(preds).counts), want, err_msg=msg)
-        routed = QueryEngine(aidx, batch=8, mode="dense",
+        # a one-page slab walks the gather branch and the cap's fallback
+        narrow = QueryEngine(aidx, batch=8, compact_bucket=1,
                              drain_policy="manual", writer=engine.writer)
-        np.testing.assert_array_equal(routed.run_all(preds), want, err_msg=msg)
+        np.testing.assert_array_equal(narrow.run_all(preds), want, err_msg=msg)
 
     check_all_paths("learned build-time bounds")
     w = engine.writer

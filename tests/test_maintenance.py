@@ -32,7 +32,7 @@ def test_eager_insert_existing_and_new_pages(relocate):
     # Every subsequent query must see the inserted tuples (§5.1 correctness).
     for lo, hi in [(0, 100), (10, 20), (50, 50.5)]:
         res = idx.search(Predicate.between(lo, hi))
-        assert int(res.count) == brute_force(idx.table, lo, hi)
+        assert int(res.counts[0]) == brute_force(idx.table, lo, hi)
 
 
 def test_insert_extends_or_creates_last_entry():
@@ -67,7 +67,7 @@ def test_sorted_list_stays_sorted_under_relocation():
     # Relocation happened (num_slots grew past num_entries) yet search is exact.
     assert int(idx.state.num_slots) >= idx.num_entries
     res = idx.search(Predicate.between(0, 100))
-    assert int(res.count) == brute_force(idx.table, 0, 100)
+    assert int(res.counts[0]) == brute_force(idx.table, 0, 100)
 
 
 def test_batch_insert_matches_sequential():
@@ -85,7 +85,7 @@ def test_batch_insert_matches_sequential():
     for lo, hi in [(0, 100), (25, 30), (77, 77.5)]:
         ra = idx_a.search(Predicate.between(lo, hi))
         rb = idx_b.search(Predicate.between(lo, hi))
-        assert int(ra.count) == int(rb.count) == brute_force(idx_b.table, lo, hi)
+        assert int(ra.counts[0]) == int(rb.counts[0]) == brute_force(idx_b.table, lo, hi)
 
 
 def test_lazy_delete_correct_before_and_after_vacuum():
@@ -96,15 +96,15 @@ def test_lazy_delete_correct_before_and_after_vacuum():
     idx.table.delete_where(40, 60)
     for lo, hi in [(0, 100), (45, 55), (39, 41)]:
         res = idx.search(Predicate.between(lo, hi))
-        assert int(res.count) == brute_force(idx.table, lo, hi)
-    before_pages = int(idx.search(Predicate.between(45, 55)).pages_inspected)
+        assert int(res.counts[0]) == brute_force(idx.table, lo, hi)
+    before_pages = int(idx.search(Predicate.between(45, 55)).pages_inspected[0])
     n = idx.vacuum()
     assert n > 0
     assert not idx.table.dirty[: idx.table.num_pages].any()
     # After vacuum, bitmaps shrink => fewer possible-qualified pages.
     after = idx.search(Predicate.between(45, 55))
-    assert int(after.count) == brute_force(idx.table, 45, 55) == 0
-    assert int(after.pages_inspected) <= before_pages
+    assert int(after.counts[0]) == brute_force(idx.table, 45, 55) == 0
+    assert int(after.pages_inspected[0]) <= before_pages
 
 
 def test_vacuum_only_resummarizes_dirty_entries():
@@ -128,18 +128,18 @@ def test_insert_into_empty_index():
     idx = HippoIndex.create(table, resolution=32, density=0.25, hist=hist)
     assert idx.num_entries == 0
     assert int(idx.state.summarized_until) == -1
-    assert int(idx.search(Predicate.between(0, 100)).count) == 0
+    assert idx.count(Predicate.between(0, 100)) == 0
     vals = [5.0, 50.0, 95.0, 12.0, 13.0]
     for v in vals:
         idx.insert(v)
     assert idx.num_entries >= 1
-    assert int(idx.search(Predicate.between(0, 100)).count) == len(vals)
-    assert int(idx.search(Predicate.between(40, 60)).count) == 1
+    assert idx.count(Predicate.between(0, 100)) == len(vals)
+    assert idx.count(Predicate.between(40, 60)) == 1
     # batch insert into a fresh empty index agrees too
     t2 = PagedTable.from_values(np.zeros(0), page_card=8, spare_pages=64)
     idx2 = HippoIndex.create(t2, resolution=32, density=0.25, hist=hist)
     idx2.insert_batch(np.asarray(vals))
-    assert int(idx2.search(Predicate.between(0, 100)).count) == len(vals)
+    assert idx2.count(Predicate.between(0, 100)) == len(vals)
 
 
 def test_insert_at_max_slots_refuses_cleanly():
@@ -154,7 +154,7 @@ def test_insert_at_max_slots_refuses_cleanly():
     # refusal left table and index consistent: every query is still exact
     assert int(idx.state.num_slots) <= idx.cfg.max_slots
     for lo, hi in [(0, 99), (10, 20), (50, 50.5)]:
-        assert int(idx.search(Predicate.between(lo, hi)).count) == \
+        assert idx.count(Predicate.between(lo, hi)) == \
             brute_force(idx.table, lo, hi)
     # single insert refuses BEFORE touching the table; batch insert rolls the
     # table back to its pre-batch snapshot (atomic refuse)
@@ -164,7 +164,7 @@ def test_insert_at_max_slots_refuses_cleanly():
     with pytest.raises(RuntimeError, match="slot capacity"):
         idx.insert_batch(np.linspace(0, 99, 300))
     assert idx.table.cardinality == cardinality
-    assert int(idx.search(Predicate.between(0, 99)).count) == \
+    assert idx.count(Predicate.between(0, 99)) == \
         brute_force(idx.table, 0, 99)
 
 
@@ -176,7 +176,7 @@ def test_large_batch_insert_not_refused_at_low_occupancy():
     idx = make_index(rng.uniform(0, 100, 333), relocate_on_update=True)
     assert int(idx.state.num_slots) + 1500 > idx.cfg.max_slots  # worst case "full"
     idx.insert_batch(np.full(1500, 50.0, np.float32))
-    assert int(idx.search(Predicate.between(0, 100)).count) == \
+    assert idx.count(Predicate.between(0, 100)) == \
         brute_force(idx.table, 0, 100) == 333 + 1500
 
 

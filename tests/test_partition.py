@@ -1,12 +1,14 @@
 """Partition layer: sharded search must be count-identical to the unsharded
-index under arbitrary predicates and maintenance histories, shard-boundary
-maintenance must stay local and refuse cleanly at capacity, and the engine's
-summary-routed dispatch must agree with everything else."""
+index and a scan under arbitrary predicates and maintenance histories, its
+per-shard match statistics must sum what each shard's joint-bucket test
+selects, and shard-boundary maintenance must stay local and refuse cleanly
+at capacity."""
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
+from repro.core import bitmap as bm
 from repro.core import index as hix
 from repro.core.hippo import HippoIndex
 from repro.core.partition import (ShardedHippoIndex, ShardSpec, shard_state,
@@ -67,14 +69,45 @@ def test_sharded_counts_match_unsharded(num_shards):
     want = np.asarray(idx.search_batch(preds).counts)
     got = np.asarray(sidx.search_batch(preds).counts)
     np.testing.assert_array_equal(got, want)
-    # per-shard dispatch sums to the same counts, and pruned (q, s) pairs
-    # are exactly count-zero (the routing soundness guarantee)
-    match = sidx.shard_match_matrix(preds)
-    per = np.stack([np.asarray(sidx.search_batch_shard(s, preds).counts)
-                    for s in range(num_shards)])
-    np.testing.assert_array_equal(per.sum(axis=0), want)
+    np.testing.assert_array_equal(
+        want, [brute_force(sidx.table, *p.selectivity_interval())
+               for p in preds])
+
+
+@pytest.mark.parametrize("num_shards", [1, 3, 4])
+def test_sharded_match_stats_sum_each_shards_joint_test(num_shards):
+    """``pages_inspected``/``entries_matched`` of the one sharded program
+    equal, summed over shards, a direct joint-bucket test of every live
+    slot of each shard expanded over its slab-local page range — also after
+    inserts and a delete + vacuum reshuffled slots."""
+    rng = np.random.default_rng(40 + num_shards)
+    _, sidx = make_pair(rng.uniform(0, 1000, 1500), num_shards=num_shards,
+                        relocate_on_update=True)
+    for v in rng.uniform(0, 1000, 40):
+        sidx.insert(float(v))
+    sidx.table.delete_where(200.0, 260.0)
+    sidx.vacuum()
+    preds = workload(rng, 12)
+    res = sidx.search_batch(preds)
+    qbms = np.asarray(sidx._query_bitmaps(preds))          # (S, Q, W)
+    pps = sidx.spec.pages_per_shard
+    pages = np.zeros(len(preds), np.int64)
+    entries = np.zeros(len(preds), np.int64)
     for s in range(num_shards):
-        assert per[s][~match[:, s]].sum() == 0
+        st = shard_state(sidx.state.shards, s)
+        slots = np.arange(sidx.cfg.max_slots)
+        live = np.asarray(st.slot_live) & (slots < int(st.num_slots))
+        starts, ends = np.asarray(st.starts), np.asarray(st.ends)
+        for q in range(len(preds)):
+            match = np.asarray(bm.any_joint(st.bitmaps,
+                                            qbms[s, q][None, :])) & live
+            covered = np.zeros(pps, bool)
+            for e in np.flatnonzero(match):
+                covered[starts[e]:ends[e] + 1] = True
+            pages[q] += covered.sum()
+            entries[q] += match.sum()
+    np.testing.assert_array_equal(np.asarray(res.pages_inspected), pages)
+    np.testing.assert_array_equal(np.asarray(res.entries_matched), entries)
 
 
 @pytest.mark.slow
@@ -108,20 +141,23 @@ def test_sharded_parity_predicate_sweep(dist):
 
 
 def test_search_many_sharded_page_mask_global_order():
-    """The fused (Q, S) path returns page_mask in global page order and
-    covers every truly-qualified page (soundness)."""
+    """The sharded program returns row ids in global page order and covers
+    every truly-qualified row (soundness): with ``top_k`` at least the
+    count, the ids are exactly a scan's qualifying rows, ascending."""
     rng = np.random.default_rng(7)
     values = rng.uniform(0, 1000, 1500)
     _, sidx = make_pair(values, num_shards=3)
     pred = Predicate.between(200, 420)
-    res = sidx.search_batch([pred])
     t = sidx.table
-    qual_pages = (t.valid[: t.num_pages]
-                  & (t.keys[: t.num_pages] >= 200)
-                  & (t.keys[: t.num_pages] <= 420)).any(axis=1)
-    mask = np.asarray(res.page_mask[0])
-    assert mask.shape == (t.num_pages,)
-    assert not (qual_pages & ~mask).any()
+    qual = (t.valid[: t.num_pages] & (t.keys[: t.num_pages] >= 200)
+            & (t.keys[: t.num_pages] <= 420)).reshape(-1)
+    want = np.flatnonzero(qual)
+    top_k = 1 << int(want.size - 1).bit_length()
+    res = sidx.search_batch([pred], top_k=top_k)
+    assert int(res.counts[0]) == want.size
+    ids = np.asarray(res.row_ids[0])
+    np.testing.assert_array_equal(ids[: want.size], want)
+    assert (ids[want.size:] == -1).all()
 
 
 def test_empty_and_single_shard_layouts():
@@ -130,7 +166,7 @@ def test_empty_and_single_shard_layouts():
     idx, sidx = make_pair(values, num_shards=1)
     for lo, hi in [(0, 100), (30, 35)]:
         assert sidx.count(Predicate.between(lo, hi)) == \
-            int(idx.search(Predicate.between(lo, hi)).count)
+            idx.count(Predicate.between(lo, hi))
     # layouts with more shards than pages: trailing shards stay empty
     t = PagedTable.from_values(rng.uniform(0, 100, 20), page_card=8)
     s = ShardedHippoIndex.create(t, num_shards=8, resolution=32, density=0.25)
@@ -151,7 +187,7 @@ def test_insert_routes_to_owning_shard_and_matches_unsharded():
     for lo, hi in [(0, 100), (10, 20), (50, 50.5)]:
         want = brute_force(sidx.table, lo, hi)
         assert sidx.count(Predicate.between(lo, hi)) == want
-        assert int(idx.search(Predicate.between(lo, hi)).count) == want
+        assert idx.count(Predicate.between(lo, hi)) == want
 
 
 def test_insert_crossing_shard_boundary_stays_local():
@@ -273,64 +309,26 @@ def test_summaries_track_maintenance_as_superset():
 
 
 # ---------------------------------------------------------------------------
-# Engine sharded mode
+# Engine over a sharded index
 # ---------------------------------------------------------------------------
-
-def test_engine_sharded_mode_matches_dense_engine():
-    rng = np.random.default_rng(31)
-    idx, sidx = make_pair(np.sort(rng.uniform(0, 1000, 2000)), num_shards=4)
-    preds = workload(rng, 24)
-    dense = QueryEngine(idx, batch=8).run_all(preds)
-    # sharded=True selects dense mode's summary-routed per-shard dispatch
-    routed = QueryEngine(sidx, batch=8, sharded=True)
-    assert routed.sharded and routed.mode == "dense"
-    np.testing.assert_array_equal(routed.run_all(preds), dense)
-    # fused (Q, S) dense mode on the same sharded index agrees too
-    fused = QueryEngine(sidx, batch=8, mode="dense", sharded=False)
-    assert not fused.sharded
-    np.testing.assert_array_equal(fused.run_all(preds), dense)
-    # ... as does the default (compact gather) mode
-    compact = QueryEngine(sidx, batch=8)
-    assert compact.mode == "compact" and not compact.sharded
-    np.testing.assert_array_equal(compact.run_all(preds), dense)
-    assert routed.stats.shard_dispatches > 0
-    occ = routed.stats.shard_occupancy()
-    assert occ and all(0 < v <= 1 for v in occ.values())
-
 
 def test_engine_stats_never_count_pads_as_served_work():
     rng = np.random.default_rng(33)
     idx, sidx = make_pair(rng.uniform(0, 1000, 500), num_shards=2)
-    # dense mode: pads are the free batch slots
-    engine = QueryEngine(idx, batch=16)
-    engine.submit(Predicate.between(0, 1000))
-    engine.submit(Predicate(lo=5.0, hi=1.0))       # real (empty) query
-    assert len(engine.run_batch()) == 2
-    st = engine.stats
-    assert st.slots_filled == 2                    # pads excluded
-    assert st.pad_slots == 14
-    assert st.served == 2
-    assert st.occupancy == pytest.approx(2 / 16)
-    # sharded mode: pads are the per-shard bucket roundings actually
-    # dispatched, never the undispatched batch remainder
-    routed = QueryEngine(sidx, batch=16, sharded=True)
-    routed.submit(Predicate.between(0, 1000))
-    routed.submit(Predicate(lo=5.0, hi=1.0))
-    assert len(routed.run_batch()) == 2
-    st = routed.stats
-    assert st.served == 2
-    assert st.slots_filled == sum(st.shard_queries.values())
-    assert st.slots_filled + st.pad_slots == sum(st.shard_slots.values())
-    assert 0 < st.occupancy <= 1
+    # on either index, pads are the free batch slots of the one program
+    for target in (idx, sidx):
+        engine = QueryEngine(target, batch=16)
+        engine.submit(Predicate.between(0, 1000))
+        engine.submit(Predicate(lo=5.0, hi=1.0))   # real (empty) query
+        finished = engine.run_batch()
+        assert [t.count for t in finished] == [target.table.cardinality, 0]
+        st = engine.stats
+        assert st.slots_filled == 2                # pads excluded
+        assert st.pad_slots == 14
+        assert st.served == 2
+        assert st.occupancy == pytest.approx(2 / 16)
     # a fresh engine with nothing dispatched reports zero occupancy
     assert QueryEngine(idx, batch=4).stats.occupancy == 0.0
-
-
-def test_engine_sharded_requires_partition_surface():
-    rng = np.random.default_rng(35)
-    idx, _ = make_pair(rng.uniform(0, 1000, 100), num_shards=2)
-    with pytest.raises(ValueError, match="sharded"):
-        QueryEngine(idx, sharded=True)
 
 
 # ---------------------------------------------------------------------------
@@ -350,7 +348,8 @@ def test_placed_sharded_state_search_parity():
     preds = workload(rng, 8)
     qbms = sidx._query_bitmaps(preds)           # (S, Q, W): per-shard epochs
     los, his = intervals(preds)
-    res = hix.search_many_sharded(st.shards, qbms, k, v, los, his)
+    res = hix.search_compact_many_sharded(st.shards, qbms, k, v, los, his,
+                                          max_selected=sidx.gather_cap)
     want = np.asarray(sidx.search_batch(preds).counts)
     np.testing.assert_array_equal(np.asarray(res.counts), want)
 

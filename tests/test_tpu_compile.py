@@ -137,14 +137,11 @@ def test_kernel_compiles_for_v5e(spec, name):
 @pytest.mark.parametrize("max_selected", [64, PPS],
                          ids=["bucket64", "full_cap"])
 def test_compact_search_fits_at_sf10(spec, max_selected):
-    """The engine's first bucket and its never-truncating cap (the dense
-    fallback's width), both carrying top_k row ids."""
+    """The engine's first bucket and its never-truncating cap (the
+    fallback's width, where every bucket inspected in place runs), both
+    carrying top_k row ids."""
     _fits(hix.search_compact_many_sharded.lower(
         *_batch(spec), max_selected=max_selected, top_k=TOP_K))
-
-
-def test_dense_search_fits_at_sf10(spec):
-    _fits(hix.search_many_sharded.lower(*_batch(spec)))
 
 
 def test_predicate_conversion_fits(spec):

@@ -71,9 +71,6 @@ def _lowered(index, name):
         return hix.search_compact_many_sharded.lower(
             shards, qbms, keys, valid, los, his, max_selected=width,
             top_k=32)
-    if name == "search_many_sharded":
-        return hix.search_many_sharded.lower(shards, qbms, keys, valid, los,
-                                             his)
     return interval_bitmaps_sharded.lower(shards.bounds, los, his,
                                           jnp.ones(los.shape, bool))
 
@@ -81,7 +78,8 @@ def _lowered(index, name):
 @pytest.mark.parametrize("name,scopes", [
     ("search_compact_many_sharded", COMPACT),
     ("search_compact_many_sharded[in_place]", IN_PLACE),
-    ("search_many_sharded", STEP2 | {"hippo.inspect"}),
+    ("search_compact_many_sharded_staged[in_place]",
+     IN_PLACE | {"hippo.staged_overlay"}),
     ("search_compact_many_sharded_staged", COMPACT | {"hippo.staged_overlay"}),
     ("interval_bitmaps_sharded", {"hippo.convert"}),
 ])

@@ -113,7 +113,7 @@ def test_write_query_vacuum_query_sequence():
 
 def test_counts_exact_while_rows_still_staged():
     """The never-stale contract: queries see staged rows before any drain,
-    on both the fused dense path and the summary-routed dispatch."""
+    through the index's search and through the engine."""
     rng = np.random.default_rng(11)
     aidx = make_sidx(rng.uniform(0, 100, 200))
     writer = MaintenanceWriter(aidx)
@@ -121,12 +121,12 @@ def test_counts_exact_while_rows_still_staged():
     for v in [10.0, 10.5, 11.0, 95.0]:
         writer.write(v)
     assert writer.queue_depth == 4
-    # fused (Q, S) dense path via the index surface
+    # the one sharded program via the index surface
     assert int(aidx.search_batch([Predicate.between(-1e30, 1e30)]).counts[0]) \
         == card + 4
     assert int(aidx.search_batch([Predicate.between(10, 11)]).counts[0]) \
         == brute_force(aidx.table, 10, 11) + 3
-    # summary-routed engine dispatch (staged rows can't be pruned away)
+    # the engine's batches
     engine = QueryEngine(aidx, batch=4, drain_policy="manual", writer=writer)
     got = engine.run_all([Predicate.between(10, 11),
                           Predicate.between(-1e30, 1e30)])
@@ -303,8 +303,7 @@ def test_queries_and_maintenance_refuse_mid_swap():
     pred = Predicate.between(0, 50)
     aidx.swap_in_flight = 2
     for attempt in (lambda: aidx.search_batch([pred]),
-                    lambda: aidx.plan_batch([pred]),
-                    lambda: aidx.search_batch_shard(0, [pred]),
+                    lambda: aidx.search_batch([pred], max_selected=1, top_k=4),
                     lambda: aidx.insert(1.0),
                     lambda: aidx.insert_batch(np.asarray([1.0])),
                     lambda: aidx.vacuum(),
@@ -389,19 +388,34 @@ def test_noop_delete_keeps_device_caches():
     assert not t._dev_shard_stale                   # cache survived the no-op
 
 
-def test_routed_overlay_reads_the_attached_writer():
-    """The routed dispatch must take the overlay from ``index.staging`` (the
-    single source of truth), so a sync-policy engine on an index with a
-    staged writer still returns exact counts."""
+@pytest.mark.parametrize("case", ["sync_engine", "superseded_writer"])
+def test_overlay_reads_the_attached_writer(case):
+    """The overlay comes from ``index.staging`` (the single source of
+    truth), not from the engine's writer handle: a sync-policy engine, which
+    holds no writer, and an engine whose writer was superseded by a newer
+    one both count the rows the attached writer holds staged."""
     rng = np.random.default_rng(61)
     aidx = make_sidx(rng.uniform(0, 100, 200))
-    writer = MaintenanceWriter(aidx)
+    if case == "sync_engine":
+        writer = MaintenanceWriter(aidx)
+        engine = QueryEngine(aidx, batch=4, drain_policy="sync")
+        assert engine.writer is None
+    else:
+        engine = QueryEngine(aidx, batch=4, drain_policy="manual")
+        writer = MaintenanceWriter(aidx)        # supersedes engine.writer
+        assert engine.writer is not None and engine.writer is not writer
+        assert aidx.staging is writer
     writer.write(42.0)
-    sync_engine = QueryEngine(aidx, batch=4, drain_policy="sync")
-    assert sync_engine.writer is None
-    got = sync_engine.run_all([Predicate.between(-1e30, 1e30)])
-    assert got[0] == brute_force(aidx.table, -1e30, 1e30) + 1
+    writer.write(43.0)
+    got = engine.run_all([Predicate.between(-1e30, 1e30),
+                          Predicate.between(42.5, 50.0)])
+    np.testing.assert_array_equal(
+        got, [brute_force(aidx.table, -1e30, 1e30) + 2,
+              brute_force(aidx.table, 42.5, 50.0) + 1])
+    assert writer.queue_depth == 2              # nothing drained them
     writer.flush()
+    assert engine.run_all([Predicate.between(42.5, 50.0)])[0] == \
+        brute_force(aidx.table, 42.5, 50.0)
 
 
 def test_engine_rejects_writer_bound_elsewhere():
