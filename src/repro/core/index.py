@@ -225,7 +225,8 @@ def _page_owners(ls: jnp.ndarray, table: jnp.ndarray, num_pages: int
 
     ``ls`` (S,) holds the logical starts, INT32_MAX-padded, strictly
     ascending where live (each entry summarizes at least one page);
-    ``table`` (C, S) holds one column per logical entry. Returns ``pos`` =
+    ``table`` (C, S) holds one column per logical entry, in int32 or
+    uint32: either holds every start exactly. Returns ``pos`` =
     ``searchsorted(ls, arange(num_pages), side="right") - 1`` (-1: no entry)
     and ``table[:, pos]`` (zeros where ``pos`` is -1), with no search and no
     gather of every page.
@@ -234,8 +235,11 @@ def _page_owners(ls: jnp.ndarray, table: jnp.ndarray, num_pages: int
     its owner by counting starts at or before it, first over every
     ``_BLOCK``-th start, then within that group. The block's pages hold at
     most ``_BLOCK - 1`` further starts, so all its owners lie in the
-    ``_BLOCK`` entries from there: one contiguous slice of ``ls`` and of
-    ``table`` per block, and a dense compare of each page against them.
+    ``_BLOCK`` entries from there. The starts, their successors and
+    ``table`` are stacked into one (2 + C, S) array, so each block takes
+    one contiguous slice of it (a single gather of windows, where a slice
+    of each 1-D row compiles to a loop over the blocks on a TPU), and a
+    dense compare of each page against the window's starts.
     """
     s, c = ls.shape[0], table.shape[0]
     b = min(_BLOCK, s)
@@ -247,14 +251,17 @@ def _page_owners(ls: jnp.ndarray, table: jnp.ndarray, num_pages: int
                     - 1, 0)
     head = g * b + (groups[g] <= first[:, None]).sum(1, dtype=jnp.int32) - 1
     base = jnp.clip(head, 0, s - b)                            # (NB,)
-    cut = jax.vmap(lambda a, o: jax.lax.dynamic_slice_in_dim(a, o, b, axis=-1),
-                   in_axes=(None, 0))
-    here = cut(ls, base)[:, None, :]                           # (NB, 1, B)
-    after = cut(jnp.append(ls, _INT32_MAX), base + 1)[:, None, :]
+    stacked = jnp.concatenate(
+        [jnp.stack([ls, jnp.append(ls[1:], _INT32_MAX)]).astype(table.dtype),
+         table])                                               # (2+C, S)
+    win = jax.vmap(lambda o: jax.lax.dynamic_slice_in_dim(stacked, o, b,
+                                                          axis=1))(base)
+    here = win[:, None, 0].astype(jnp.int32)                   # (NB, 1, B)
+    after = win[:, None, 1].astype(jnp.int32)                  # successors
     pages = (first[:, None] + jnp.arange(b, dtype=jnp.int32))[:, :, None]
     pos = base[:, None] + (here <= pages).sum(2, dtype=jnp.int32) - 1
     owner = (here <= pages) & (after > pages)                  # (NB, B, B)
-    rows = jnp.where(owner[:, None], cut(table, base)[:, :, None, :], 0
+    rows = jnp.where(owner[:, None], win[:, 2:, None, :], 0
                      ).sum(3, dtype=table.dtype)               # (NB, C, B)
     rows = rows.transpose(1, 0, 2).reshape(c, nb * b)
     return pos.reshape(-1)[:num_pages], rows[:, :num_pages]

@@ -142,6 +142,17 @@ def _expansion_case(name):
                 idx.insert(float(v))
             idx.table.delete_where(20.0, 30.0)
             idx.vacuum()
+        if name == "gap":
+            # three live pages, each of 8 values in 8 of the 32 buckets (an
+            # entry that holds one reaches D), a page whose rows never go
+            # live, then two live pages: the next entry opens after the
+            # dead page, which no entry owns
+            live = np.repeat([True] * 3 + [False] + [True] * 2, 8)
+            values = np.tile(np.linspace(1, 99, 8, dtype=np.float32), 6)
+            page_of = (idx.table.tail + np.arange(live.size)) // 8
+            idx.table.append(values, live)
+            idx.state = hix.insert_appended(
+                idx.cfg, idx.state, *hix.pad_rows(values, page_of, live))
         state = idx.state
         pages = idx.table.num_pages
         if name == "slack":
@@ -172,7 +183,7 @@ def _owner_reference(state, qbms, pages):
 
 
 @pytest.mark.parametrize("case", ["fresh", "relocated", "empty", "slack",
-                                  "sharded", "dense"])
+                                  "sharded", "dense", "gap"])
 def test_page_expansion_equals_the_binary_search(case):
     """The block-wise expansion of the sorted starts gives every page the
     logical position a binary search gives it and that entry's row, and
@@ -208,6 +219,15 @@ def test_page_expansion_equals_the_binary_search(case):
         assert shards.starts.shape[1] < int(shards.num_entries[0]) + hix._BLOCK
     if case == "dense":
         assert int(shards.num_entries[0]) == pages
+    if case == "gap":
+        dead = pages - 3                  # the appended page with no live row
+        e = np.asarray(shards.sorted_order[0])[:int(shards.num_entries[0])]
+        starts = np.asarray(shards.starts[0])[e]
+        ends = np.asarray(shards.ends[0])[e]
+        assert dead + 1 in starts and dead - 1 in ends    # a gap of one page
+        assert not ((starts <= dead) & (ends >= dead)).any()
+        assert not np.asarray(masks[0, :, dead]).any()
+        assert np.asarray(pos[0, dead]) >= 0    # past an entry, not before all
 
 
 def test_entry_bitmap_matches_page_contents():

@@ -11,6 +11,7 @@ Nothing runs, so nothing here says anything about results or times.
 The topology is described inside a fixture (never at import), so under
 pytest-xdist only the worker given this file loads the TPU library.
 """
+import re
 from functools import partial
 
 import pytest
@@ -142,6 +143,19 @@ def test_compact_search_fits_at_sf10(spec, max_selected):
     carrying top_k row ids."""
     _fits(hix.search_compact_many_sharded.lower(
         *_batch(spec), max_selected=max_selected, top_k=TOP_K))
+
+
+def test_page_expansion_compiles_to_no_loop_at_sf10(spec):
+    """Step 2's page expansion fetches each block's window of starts,
+    successors and entry columns with one gather: no ``while`` of the cap
+    program's optimized HLO is named under ``hippo.page_expand`` (a
+    vmapped slice of a 1-D row compiles to a loop over the blocks)."""
+    hlo = hix.search_compact_many_sharded.lower(
+        *_batch(spec), max_selected=PPS, top_k=TOP_K).compile().as_text()
+    loops = [line for line in hlo.splitlines()
+             if re.search(r"\bwhile\(", line)]
+    assert loops                     # the row ids' search still loops
+    assert not [line for line in loops if "hippo.page_expand" in line]
 
 
 def test_predicate_conversion_fits(spec):
